@@ -1,9 +1,10 @@
 #!/usr/bin/env python
-"""Multichip smoke: REAL sharded numbers on the 8-device CPU sim.
+"""Multichip smoke on the CPU rig: an 8-device VIRTUAL CPU mesh.
 
-What MULTICHIP_r0*.json scores (previously just ``dryrun_multichip
-ok``): the three acceptance properties of GSPMD sharded training &
-serving, measured, not dry-run —
+It checks layouts, parity and collectives; it holds no chip and times
+nothing (the same layouts on real chips are ``chip_smoke.py``'s
+``multichip`` leg). The three acceptance properties of GSPMD sharded
+training & serving —
 
 * **sharded fit == single-device fit**: the same model/seed/data trained
   on a ``data x fsdp`` mesh produces the same loss curve as one device
@@ -25,7 +26,7 @@ Run directly (``python scripts/check_multichip.py`` — self-provisions
 the 8-device virtual CPU platform in a child process) or from the test
 suite (``tests/test_multichip.py`` runs it under the ``multichip``
 marker). ``__graft_entry__.dryrun_multichip`` prints the same metrics
-line, so the driver's MULTICHIP tail carries real numbers.
+line.
 """
 
 import json
@@ -336,7 +337,8 @@ def check() -> int:
 
 
 def main() -> int:
-    # self-provision the virtual multichip platform: XLA only honors
+    # self-provision the virtual multichip platform (a CPU rig — forced
+    # to JAX_PLATFORMS=cpu, never a chip): XLA only honors
     # --xla_force_host_platform_device_count before the backend
     # initializes, so the real checks always run in a child process
     # with the env forced (same bootstrap as __graft_entry__)

@@ -88,9 +88,29 @@ def test_pallas_conv_supported_matrix():
     assert not pallas_conv_supported((3, 3), (1, 1), (2, 2))
 
 
+def _as_if_on_tpu(monkeypatch):
+    """``on_tpu()`` true, kernels interpreted: what every ``auto``
+    resolver sees on the chip, minus Mosaic."""
+    import zoo_tpu.ops.pallas as zp
+    import zoo_tpu.ops.pallas.conv as zconv
+    monkeypatch.setattr(zp, "on_tpu", lambda: True)
+    # the conv module binds its helpers at import (``_on_tpu`` is the
+    # name the PR 18 rule consulted; it may no longer exist)
+    monkeypatch.setattr(zconv, "_on_tpu", lambda: True, raising=False)
+    monkeypatch.setattr(zconv, "_resolve_interpret",
+                        lambda interpret: True)
+    assert zp.on_tpu()
+
+
 def test_resolve_conv_impl_dispatch(monkeypatch):
-    # auto off-TPU -> the XLA reference (bit-identical, no interpret tax)
+    # auto -> the XLA reference, off a TPU and on one: fit calls this
+    # seam, and the kernel cannot be differentiated
     assert resolve_conv_impl(kernel=(3, 3)) == "reference"
+    with monkeypatch.context() as m:
+        _as_if_on_tpu(m)
+        for kernel in ((1, 1), (3, 3)):
+            assert resolve_conv_impl(kernel=kernel) == "reference"
+            assert resolve_conv_impl("auto", kernel=kernel) == "reference"
     # env knob overrides auto at the single dispatch point
     monkeypatch.setenv("ZOO_CONV_IMPL", "pallas")
     assert resolve_conv_impl(kernel=(3, 3)) == "pallas"
@@ -103,3 +123,63 @@ def test_resolve_conv_impl_dispatch(monkeypatch):
         resolve_conv_impl("pallas", kernel=(5, 5))
     with pytest.raises(ValueError):
         resolve_conv_impl("no-such-impl", kernel=(1, 1))
+
+
+def test_conv_layer_trains_with_on_tpu_true(monkeypatch):
+    """The conv seam regression (PR 18 -> PR 21): with ``on_tpu()``
+    true, ``auto`` handed ``fit`` the Pallas conv, which has no vjp, so
+    no conv net could take a step on a TPU. A small Conv2D +
+    BatchNormalization model must fit, and ``jax.grad`` through the
+    layer must match the XLA conv."""
+    import jax
+
+    from zoo_tpu.pipeline.api.keras import Sequential
+    from zoo_tpu.pipeline.api.keras.layers import (
+        BatchNormalization,
+        Convolution2D,
+        Dense,
+        Flatten,
+    )
+
+    _as_if_on_tpu(monkeypatch)
+    rs = np.random.RandomState(0)
+    x = rs.randn(16, 8, 8, 4).astype(np.float32)
+    y = rs.randint(0, 3, 16).astype(np.int32)
+    m = Sequential()
+    conv = Convolution2D(8, 3, 3, border_mode="same", dim_ordering="tf",
+                         input_shape=(8, 8, 4))
+    m.add(conv)
+    m.add(BatchNormalization(dim_ordering="tf"))
+    m.add(Flatten())
+    m.add(Dense(3))
+    m.compile(optimizer="sgd",
+              loss="sparse_categorical_crossentropy_from_logits")
+    loss = m.fit(x, y, batch_size=8, nb_epoch=1, verbose=0)["loss"]
+    assert np.isfinite(loss).all()
+
+    # grad through the layer == grad through lax.conv on its weights
+    params = conv.build(jax.random.PRNGKey(0), (None, 8, 8, 4))
+    xj = jnp.asarray(x)
+
+    def through_layer(p):
+        return jnp.sum(conv.call(p, xj) ** 2)
+
+    def through_lax(p):
+        out = jax.lax.conv_general_dilated(
+            xj, p["W"], (1, 1), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        if "b" in p:
+            out = out + p["b"]
+        return jnp.sum(out ** 2)
+
+    got = jax.grad(through_layer)(params)
+    want = jax.grad(through_lax)(params)
+    for name in want:
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(want[name]),
+                                   rtol=1e-5, atol=1e-4)
+    # the kernel itself still cannot be differentiated: if this starts
+    # passing, auto's rule can be revisited (ROADMAP S6)
+    with pytest.raises(Exception, match="[Ll]inearization|differentiat"):
+        jax.grad(lambda w: jnp.sum(conv2d(xj, w, impl="pallas",
+                                          interpret=True)))(params["W"])

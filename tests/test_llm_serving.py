@@ -779,8 +779,8 @@ def test_gqa_cache_layout(paged):
     cfg, model = paged
     import jax.numpy as jnp
     assert cfg.n_kv_head < cfg.n_head
-    expect = (cfg.n_block, model.num_blocks, model.block_size,
-              cfg.n_kv_head, cfg.head_dim)
+    expect = (cfg.n_block, model.num_blocks, cfg.n_kv_head,
+              model.block_size, cfg.head_dim)
     assert model._kc.shape == expect
     assert model._vc.shape == expect
     assert model._kc.dtype == jnp.float32
@@ -1280,7 +1280,7 @@ def test_kv_dtype_resolution_and_bytes_model():
     assert i8._kc.dtype == jnp.int8
     assert bf16._kc.dtype == jnp.bfloat16
     assert i8._cache["ks"].shape == (c.n_block, i8.num_blocks,
-                                     i8.block_size, c.n_kv_head)
+                                     c.n_kv_head, i8.block_size)
 
 
 def test_spec_parses_kv_and_prefix_cache():

@@ -20,12 +20,13 @@ def _dense_ref(q, kc, vc, bt, pos):
     """cache[block_table] gather + per-row position mask — the exact
     math model._prefill_attend runs on the dense anchor path."""
     S, C, H, D = q.shape
-    nb, bs, n_kv, _ = kc.shape
+    nb, n_kv, bs, _ = kc.shape
     W = bt.shape[1]
     ctx = W * bs
     group = H // n_kv
-    keys = kc[bt].reshape(S, ctx, n_kv, D)
-    vals = vc[bt].reshape(S, ctx, n_kv, D)
+    # (S, W, n_kv, bs, D) -> token-major (S, ctx, n_kv, D)
+    keys = kc[bt].transpose(0, 1, 3, 2, 4).reshape(S, ctx, n_kv, D)
+    vals = vc[bt].transpose(0, 1, 3, 2, 4).reshape(S, ctx, n_kv, D)
     qg = q.reshape(S, C, n_kv, group, D)
     s = jnp.einsum("sckgd,stkd->sckgt", qg, keys).astype(
         jnp.float32) / jnp.sqrt(float(D))
@@ -40,8 +41,8 @@ def _case(S=2, C=5, H=4, n_kv=2, D=16, nb=12, bs=4, W=4, seed=0,
           starts=None):
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(S, C, H, D).astype(np.float32))
-    kc = jnp.asarray(rs.randn(nb, bs, n_kv, D).astype(np.float32))
-    vc = jnp.asarray(rs.randn(nb, bs, n_kv, D).astype(np.float32))
+    kc = jnp.asarray(rs.randn(nb, n_kv, bs, D).astype(np.float32))
+    vc = jnp.asarray(rs.randn(nb, n_kv, bs, D).astype(np.float32))
     bt = jnp.asarray(rs.randint(1, nb, (S, W)).astype(np.int32))
     if starts is None:
         starts = rs.randint(0, W * bs - C, (S,))
@@ -84,8 +85,8 @@ def test_kernel_int8_dequant_matches_dense_widen():
     rs = np.random.RandomState(21)
     S, C, H, n_kv, D, nb, bs, W = 2, 4, 4, 2, 16, 10, 4, 4
     q = jnp.asarray(rs.randn(S, C, H, D).astype(np.float32))
-    kc = rs.randn(nb, bs, n_kv, D).astype(np.float32)
-    vc = rs.randn(nb, bs, n_kv, D).astype(np.float32)
+    kc = rs.randn(nb, n_kv, bs, D).astype(np.float32)
+    vc = rs.randn(nb, n_kv, bs, D).astype(np.float32)
     ks = np.asarray(absmax_scale(kc, axis=-1))
     vs = np.asarray(absmax_scale(vc, axis=-1))
     kq = narrow_int8(kc, ks[..., None])
@@ -108,12 +109,12 @@ def test_kernel_argument_validation():
     q, kc, vc, bt, pos = _case()
     with pytest.raises(ValueError, match="travel together"):
         paged_flash_prefill(q, kc, vc, bt, pos,
-                            k_scale=jnp.zeros((12, 4, 2)),
+                            k_scale=jnp.zeros((12, 2, 4)),
                             interpret=True)
     with pytest.raises(ValueError, match="scale shape"):
         paged_flash_prefill(q, kc, vc, bt, pos,
-                            k_scale=jnp.zeros((12, 4, 9)),
-                            v_scale=jnp.zeros((12, 4, 9)),
+                            k_scale=jnp.zeros((12, 9, 4)),
+                            v_scale=jnp.zeros((12, 9, 4)),
                             interpret=True)
     with pytest.raises(ValueError, match="positions shape"):
         paged_flash_prefill(q, kc, vc, bt, pos[:, :2], interpret=True)

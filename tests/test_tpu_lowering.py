@@ -1,0 +1,189 @@
+"""Every exported Pallas kernel compiles for the chip — checked without one.
+
+libtpu ships the TPU compiler, so a compile-only v5e target exists in
+the CPU sandbox: ``jax.experimental.topologies`` describes the devices
+of a ``v5e:2x2`` host, and ``jit(...).lower(...).compile()`` against
+them runs the same XLA + Mosaic pipeline the chip run does
+(``interpret=False``). Lowering is not running — numerics and VMEM
+behaviour are ``chip_smoke.py``'s to check on the device — but a
+BlockSpec the TPU lowering refuses, or an op Mosaic has not
+implemented, fails here, where the interpreter would have passed it.
+
+The shapes are the smoke's own (``chip_smoke.kernel_cases(FULL, ...)``),
+so this file and the chip run cannot drift apart. If the topology
+cannot be created the tests fail; they do not skip.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+MOSAIC_CALL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def _no_mesh_context():
+    """``fused_apply_adam`` takes its jnp path under a multi-device
+    runtime context; a context left over from another test file must
+    not turn this into a test of the fallback."""
+    from zoo_tpu.orca import stop_orca_context
+    stop_orca_context()
+
+
+def _compile_for(fn, args, sharding):
+    avals = [jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=s)
+             for a, s in zip(args, sharding)]
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def _compiled(kernel):
+    """``kernel(q, kc, vc, bt, pos[, k_scale, v_scale])``, compiled."""
+    def call(q, kc, vc, bt, pos, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return kernel(q, kc, vc, bt, pos, interpret=False, **kw)
+    return call
+
+
+_CASES = chip_smoke.kernel_cases(chip_smoke.FULL, interpret=False)
+
+
+@pytest.mark.parametrize("case", _CASES, ids=[c.name for c in _CASES])
+def test_kernel_compiles_for_v5e(v5e, case):
+    args = case.make()
+    one = SingleDeviceSharding(v5e[0])
+    hlo = _compile_for(case.fn, args, [one] * len(args))
+    assert MOSAIC_CALL in hlo, \
+        f"{case.name}: no Mosaic kernel in the compiled executable"
+
+
+def test_every_exported_kernel_is_covered():
+    """The case list names each kernel ``zoo_tpu.ops.pallas`` exports
+    (the other exports are jnp helpers and dispatch rules)."""
+    kernels = {"flash_attention", "paged_flash_decode",
+               "paged_flash_prefill", "quantized_matmul",
+               "fused_quantized_matmul", "conv2d", "conv2d_int8",
+               "fused_apply_sgd", "fused_apply_adam", "fused_bottleneck"}
+    import zoo_tpu.ops.pallas as zp
+    assert kernels <= set(zp.__all__)
+    covered = {c.name.split("[")[0] for c in _CASES}
+    assert covered == kernels, covered ^ kernels
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_paged_kernels_compile_under_two_way_shard_map(v5e, kv):
+    """The tp=2 serving layout: cache and query heads split over two
+    devices on the kv-head axis, each device running the kernel on its
+    own heads (``PagedLlamaModel._paged_attend`` / ``_prefill_attend``)."""
+    from zoo_tpu.ops.pallas import paged_flash_decode, paged_flash_prefill
+
+    mesh = Mesh(np.array(v5e[:2]), ("model",))
+    cases = {c.name: c for c in _CASES}
+    heads = P(None, "model", None, None)
+    scale = P(None, "model", None)
+
+    def sharded(kernel, q_spec, pos_spec, n_scales):
+        return jax.shard_map(
+            _compiled(kernel), mesh=mesh,
+            in_specs=(q_spec, heads, heads, P(None, None), pos_spec)
+            + (scale,) * n_scales,
+            out_specs=q_spec, check_vma=False)
+
+    def shardings(specs):
+        return [NamedSharding(mesh, s) for s in specs]
+
+    # decode: the case feeds (S, 1, H, D); the kernel takes (S, H, D)
+    q, kc, vc, bt, pos, *sc = cases[f"paged_flash_decode[{kv}]"].make()
+    q_spec = P(None, "model", None)
+    hlo = _compile_for(
+        sharded(paged_flash_decode, q_spec, P(None), len(sc)),
+        [q[:, 0], kc, vc, bt, pos[:, 0], *sc],
+        shardings((q_spec, heads, heads, P(None, None), P(None))
+                  + (scale,) * len(sc)))
+    assert MOSAIC_CALL in hlo
+
+    q_spec = P(None, None, "model", None)
+    for shape in ("chunk", "verify"):
+        args = cases[f"paged_flash_prefill[{shape},{kv}]"].make()
+        hlo = _compile_for(
+            sharded(paged_flash_prefill, q_spec, P(None, None),
+                    len(args) - 5),
+            args,
+            shardings((q_spec, heads, heads, P(None, None),
+                       P(None, None)) + (scale,) * (len(args) - 5)))
+        assert MOSAIC_CALL in hlo
+
+
+def test_serving_geometry_other_block_sizes_compile(v5e):
+    """Block sizes 8 and 32 beside the smoke's 16, all three KV dtypes:
+    an int8 block of 8 or 16 rows sits under the (32, 128) int8 tile."""
+    from zoo_tpu.ops.pallas import paged_flash_decode, paged_flash_prefill
+
+    one = SingleDeviceSharding(v5e[0])
+    S, H, n_kv, D, nb, W, C = 8, 12, 4, 64, 64, 16, 5
+    for bs in (8, 32):
+        for dt in (jnp.float32, jnp.bfloat16, jnp.int8):
+            cache = jnp.zeros((nb, n_kv, bs, D), dt)
+            sc = [jnp.zeros((nb, n_kv, bs), jnp.float32)] * 2 \
+                if dt == jnp.int8 else []
+            bt = jnp.zeros((S, W), jnp.int32)
+            for kernel, q, pos in (
+                    (paged_flash_decode, jnp.zeros((S, H, D)),
+                     jnp.zeros((S,), int)),
+                    (paged_flash_prefill, jnp.zeros((S, C, H, D)),
+                     jnp.zeros((S, C), int))):
+                args = [q, cache, cache, bt, pos, *sc]
+                assert MOSAIC_CALL in _compile_for(
+                    _compiled(kernel), args, [one] * len(args)), \
+                    (kernel.__name__, bs, dt)
+
+
+def test_flash_attention_compiles_on_a_mesh(v5e, monkeypatch):
+    """Under a multi-device jit GSPMD refuses a bare Mosaic kernel;
+    ``dot_product_attention`` places it with shard_map (batch rows over
+    the data axes, heads over ``model``) — the layout of a tp=2 bucket
+    prefill and of a Llama ``fit`` at S >= 512 on several chips."""
+    import zoo_tpu.ops.pallas as zp
+    from zoo_tpu.ops.attention import dot_product_attention
+    from zoo_tpu.parallel import build_mesh
+
+    # compile, do not interpret, although this process sits on a CPU
+    monkeypatch.setattr(zp, "resolve_interpret", lambda i: False)
+    # (the package re-exports the function under the module's name)
+    monkeypatch.setattr(sys.modules["zoo_tpu.ops.pallas.flash_attention"],
+                        "_resolve_interpret", lambda i: False)
+    mesh = build_mesh(list(v5e), axis_sizes={"data": 2, "model": 2})
+    spec = P("data", "model", None, None)
+    args = [jnp.zeros((4, 12, 512, 64), jnp.bfloat16),
+            jnp.zeros((4, 4, 512, 64), jnp.bfloat16),
+            jnp.zeros((4, 4, 512, 64), jnp.bfloat16)]
+    shardings = [NamedSharding(mesh, spec)] * 3
+
+    def attend(mesh):
+        return lambda q, k, v: dot_product_attention(
+            q, k, v, causal=True, impl="flash", mesh=mesh)
+
+    assert MOSAIC_CALL in _compile_for(attend(mesh), args, shardings)
+    # and this is what the placement is for
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        _compile_for(attend(None), args, shardings)

@@ -563,8 +563,9 @@ _k("ZOO_PALLAS_FORCE_INTERPRET", "bool", False,
    "tests of TPU kernels)", "docs/parallelism.md")
 _KN = "docs/kernels.md"
 _k("ZOO_CONV_IMPL", "str", "auto",
-   "conv2d backend: `auto` (implicit-GEMM Pallas kernel on TPU for "
-   "supported shapes, XLA reference elsewhere), `pallas`, `reference`",
+   "conv2d backend: `auto` (the XLA reference conv on every platform "
+   "— the implicit-GEMM kernel cannot be differentiated), `pallas` "
+   "(the kernel, forward only), `reference`",
    _KN)
 _k("ZOO_INT8_MATMUL", "str", "auto",
    "int8 GEMM backend: `auto`/`fused` (one-kernel quantize+dot+"

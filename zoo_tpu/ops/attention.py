@@ -18,13 +18,50 @@ import jax
 import jax.numpy as jnp
 
 
+def _flash_attend(q, k, v, causal, scale, mesh):
+    """The Pallas flash kernel, placed on the mesh by hand when it has
+    to be. A Mosaic kernel has no partitioning rule — under a
+    multi-device jit GSPMD refuses it ("Mosaic kernels cannot be
+    automatically partitioned") — so every device runs the kernel on
+    its own batch rows and heads under ``shard_map``: attention mixes
+    neither, so no collective is needed. ``mesh=None`` means the
+    runtime context's mesh, and that one is only consulted when the
+    kernel really is compiled by Mosaic; interpreted (the CPU rig) it
+    is plain HLO that GSPMD partitions by itself."""
+    from functools import partial
+
+    from zoo_tpu.ops.pallas import flash_attention, resolve_interpret
+    attend = partial(flash_attention, causal=causal, scale=scale)
+    if mesh is None and not resolve_interpret(None):
+        from zoo_tpu.common.context import get_runtime_context
+        ctx = get_runtime_context(required=False)
+        mesh = ctx.mesh if ctx is not None else None
+    if mesh is None or mesh.size == 1:
+        return attend(q, k, v)
+    from jax.sharding import PartitionSpec as P
+
+    from zoo_tpu.parallel.mesh import data_axes
+    rows = data_axes(mesh)
+    heads = "model" if mesh.shape.get("model", 1) > 1 else None
+    used = set(rows) | ({heads} - {None})
+    if any(size > 1 for axis, size in mesh.shape.items()
+           if axis not in used):
+        # seq / pipe / expert layouts own their attention elsewhere
+        # (ring attention, the stage worker's own shard_map)
+        return attend(q, k, v)
+    spec = P(rows or None, heads, None, None)
+    return jax.shard_map(attend, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           mask: Optional[jnp.ndarray] = None,
                           causal: bool = False,
                           dropout_p: float = 0.0,
                           dropout_rng=None,
                           scale: Optional[float] = None,
-                          impl: str = "auto") -> jnp.ndarray:
+                          impl: str = "auto",
+                          mesh=None) -> jnp.ndarray:
     """Scaled dot-product attention over (B, H, T, D) tensors.
 
     ``mask``: optional (B, 1, 1, T) or (B, 1, T, T) additive-style boolean
@@ -39,6 +76,10 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     GQA: ``k``/``v`` may carry fewer heads than ``q`` (``H_q % H_kv ==
     0``). The flash kernel consumes the unrepeated kv heads natively;
     the dense path broadcasts the groups here.
+
+    ``mesh``: the mesh the operands are sharded over, when the caller
+    has one of its own (tensor-parallel serving); default the runtime
+    context's. Only the flash path needs it (:func:`_flash_attend`).
     """
     flash_ok = mask is None and dropout_p == 0.0
     if impl == "auto":
@@ -58,8 +99,7 @@ def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             raise ValueError("flash attention supports causal masking only "
                              "(no arbitrary mask / dropout); use the dense "
                              "impl for those")
-        from zoo_tpu.ops.pallas import flash_attention
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return _flash_attend(q, k, v, causal, scale, mesh)
     if k.shape[1] != q.shape[1]:  # GQA on the dense path: broadcast
         if q.shape[1] % k.shape[1]:
             raise ValueError(f"q heads ({q.shape[1]}) must be a multiple "

@@ -16,9 +16,9 @@ exact 1x1/3x3 shapes ``bench_conv_roofline`` measures to implicit GEMM
   taps into one f32/int32 register accumulator feeding the same MXU
   call.
 
-:func:`resolve_conv_impl` is the single selection rule (flash-style:
-Pallas on TPU, ``lax.conv`` reference off-TPU, ``ZOO_CONV_IMPL``
-override) used by the Keras conv layers and the int8 conv path, so
+:func:`resolve_conv_impl` is the single selection rule (the XLA
+``lax.conv`` reference unless ``impl``/``ZOO_CONV_IMPL`` asks for
+``pallas``) used by the Keras conv layers and the int8 conv path, so
 float/int8 and impl selection compose.
 
 Every kernel runs off-TPU under Pallas interpret mode
@@ -38,7 +38,6 @@ from jax.experimental import pallas as pl
 from zoo_tpu.common import knobs
 from zoo_tpu.ops.pallas import LANES as _LANES
 from zoo_tpu.ops.pallas import SUBLANES as _SUBLANES
-from zoo_tpu.ops.pallas import on_tpu as _on_tpu
 from zoo_tpu.ops.pallas import pad_dim as _pad_dim
 from zoo_tpu.ops.pallas import resolve_interpret as _resolve_interpret
 
@@ -73,9 +72,12 @@ def resolve_conv_impl(impl: Optional[str] = None, *,
     """The one conv dispatch rule → ``"pallas"`` or ``"reference"``.
 
     ``impl=None`` reads ``ZOO_CONV_IMPL`` (``auto`` | ``pallas`` |
-    ``reference``). ``auto`` picks the Pallas implicit-GEMM kernel on
-    TPU for supported shapes and the XLA reference conv everywhere
-    else; an explicit ``pallas`` on an unsupported shape fails loudly
+    ``reference``). ``auto`` is the XLA reference conv on every
+    platform: the conv layers call this seam from ``fit``, and the
+    implicit-GEMM kernel has no ``custom_vjp`` — ``jax.grad`` through
+    it fails — and no device measurement on either side of the choice
+    yet (ROADMAP S6 decides). The kernel stays reachable by an
+    explicit ``pallas``, which on an unsupported shape fails loudly
     rather than silently falling back."""
     impl = impl or knobs.value("ZOO_CONV_IMPL")
     if impl not in ("auto", "pallas", "reference"):
@@ -90,9 +92,7 @@ def resolve_conv_impl(impl: Optional[str] = None, *,
                 "is outside the implicit-GEMM kernel's envelope "
                 "(1x1 any stride, 3x3 stride 1)")
         return "pallas"
-    if impl == "reference":
-        return "reference"
-    return "pallas" if (supported and _on_tpu()) else "reference"
+    return "reference"
 
 
 def _spatial_pads(h: int, w: int, kh: int, kw: int,
@@ -136,7 +136,10 @@ def _conv_kernel_q(x_ref, w_ref, xs_ref, ws_ref, o_ref, *, taps, oh, ow):
         acc += jax.lax.dot_general(
             xt, w_ref[t], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
-    y = acc.astype(jnp.float32) * xs_ref[:1, :1] * ws_ref[:1, :]
+    # both scales as (1, block_n) rows: sublane-only broadcasts, in
+    # the reference's order (x scale, then w scale) so the result is
+    # bit-equal to it
+    y = acc.astype(jnp.float32) * xs_ref[0, :1, :] * ws_ref[:1, :]
     o_ref[...] = y.reshape(1, oh, ow, -1).astype(o_ref.dtype)
 
 
@@ -179,14 +182,19 @@ def _conv2d_pallas(x, w, strides, padding, interpret, *,
         x = x.astype(jnp.int8)
         kernel = functools.partial(_conv_kernel_q, taps=taps,
                                    oh=oh, ow=ow)
+        # per-image scale in a whole (8, block_n) tile per image, like
+        # ``ws``: a one-row block of an (n, 128) array is not a shape
+        # the TPU lowering can slice
         xs = jnp.broadcast_to(
-            x_scale.reshape(n, 1).astype(jnp.float32), (n, _LANES))
+            x_scale.reshape(n, 1, 1).astype(jnp.float32),
+            (n, _SUBLANES, block_n))
         ws = jnp.broadcast_to(
             _pad_dim(w_scale.reshape(o).astype(jnp.float32), 0,
                      block_n)[None, :], (_SUBLANES, op))
         extra_in = [xs, ws]
         extra_specs = [
-            pl.BlockSpec((1, _LANES), lambda ni, j: (ni, 0)),
+            pl.BlockSpec((1, _SUBLANES, block_n),
+                         lambda ni, j: (ni, 0, 0)),
             pl.BlockSpec((_SUBLANES, block_n), lambda ni, j: (0, j)),
         ]
         out_dtype = out_dtype or jnp.float32

@@ -52,22 +52,61 @@ from zoo_tpu.ops.pallas import LANES as _LANES
 from zoo_tpu.ops.pallas import resolve_interpret as _resolve_interpret
 
 
+def attend_block(h, q, k, v, k_scale, v_scale, start, limit, scale,
+                 m_scr, l_scr, a_scr):
+    """One kv head's share of one cache block, folded into the online
+    softmax carried in VMEM scratch (shared by the paged decode and
+    prefill kernels). ``q`` (rows, D) against ``k``/``v`` (block, D);
+    column ``c`` of the block is cache index ``start + c`` and a row
+    attends it iff that is ``<= limit`` (a scalar position, or a
+    (rows, 1) column of per-row positions). An int8 block comes with
+    its (1, block) scale rows: it is widened in register and the scales
+    land on the (rows, block) score tile — K's on the scores, V's on
+    the probabilities, both row broadcasts with no relayout — so HBM
+    moves the int8 bytes and the math stays f32."""
+    if k_scale is not None:
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+    s_ = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # (rows, block)
+    if k_scale is not None:
+        s_ = s_ * k_scale
+    col = start + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
+    mask = col <= limit
+    s_ = jnp.where(mask, s_, -jnp.inf)
+    m_prev = m_scr[h][:, :1]                              # (rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+    safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    p = jnp.exp(jnp.where(mask, s_ - safe, -jnp.inf))
+    corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe), 0.0)
+    l_new = corr * l_scr[h][:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
+    a_scr[h] = a_scr[h] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    # full-lane stores: every lane of a row carries the value
+    m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+    l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+
 def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
             n_kv, block_size, bps, scale, quantized):
-    """One (slot, kv-head, split) program; the innermost grid axis walks
-    the split's ``bps`` table entries with the online-softmax carry in
-    VMEM scratch. ``quantized`` adds two per-(block, row) scale refs
-    after ``v_ref`` and the int8 K/V stream is widened IN REGISTER —
-    HBM moves half the bytes, the math runs in f32 exactly like the
-    dense fallback's gather-then-widen."""
+    """One (slot, split) program; the innermost grid axis walks the
+    split's ``bps`` table entries with the online-softmax carry in VMEM
+    scratch. Each entry's block arrives with ALL its kv heads —
+    ``(n_kv, block_size, D)``, the cache's own minor dims, which is
+    what the TPU lowering can slice — and the heads are walked by a
+    static loop. ``quantized`` adds two per-(block, kv-head, row) scale
+    refs after ``v_ref`` (see :func:`attend_block`)."""
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
     acc_ref, m_ref, l_ref, m_scr, l_scr, a_scr = rest
-    sh = pl.program_id(0)
+    s = pl.program_id(0)
     split = pl.program_id(1)
     j = pl.program_id(2)
-    s = sh // n_kv
     pos = pos_ref[s]
     start = (split * bps + j) * block_size
 
@@ -81,38 +120,18 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     # the index map clamps dead entries to block 0) no fresh DMA either
     @pl.when(start <= pos)
     def _step():
-        q = q_ref[0, 0]                       # (group, D)
-        k = k_ref[0, :, 0, :]                 # (block, D)
-        v = v_ref[0, :, 0, :]
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        s_ = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (group, block)
-        col = start + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-        mask = col <= pos
-        s_ = jnp.where(mask, s_, -jnp.inf)
-        m_prev = m_scr[:, :1]                 # (group, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(jnp.where(mask, s_ - safe, -jnp.inf))
-        corr = jnp.where(jnp.isfinite(m_prev),
-                         jnp.exp(m_prev - safe), 0.0)
-        l_scr[:, :1] = corr * l_scr[:, :1] + \
-            jnp.sum(p, axis=-1, keepdims=True)
-        a_scr[...] = a_scr[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:, :1] = m_new
+        for h in range(n_kv):
+            attend_block(
+                h, q_ref[0, h], k_ref[0, h], v_ref[0, h],
+                ks_ref[0, h:h + 1, :] if quantized else None,
+                vs_ref[0, h:h + 1, :] if quantized else None,
+                start, pos, scale, m_scr, l_scr, a_scr)
 
     @pl.when(j == bps - 1)
     def _finish():
-        acc_ref[0, 0, 0] = a_scr[...].astype(acc_ref.dtype)
-        m_ref[0, 0, 0] = jnp.broadcast_to(m_scr[:, :1],
-                                          m_ref.shape[3:])
-        l_ref[0, 0, 0] = jnp.broadcast_to(l_scr[:, :1],
-                                          l_ref.shape[3:])
+        acc_ref[0, 0] = a_scr[...].astype(acc_ref.dtype)
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
 
 
 def resolve_num_splits(table_width: int,  # zoo-lint: config-parse
@@ -140,19 +159,21 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     """Single-query paged attention for one decode tick.
 
     ``q``: (S, H, D) — one query per slot; ``k_cache``/``v_cache``:
-    (num_blocks, block_size, H_kv, D); ``block_tables``: (S, W) int32;
-    ``positions``: (S,) int32 — the cache index the slot's incoming
-    token was written at (tokens ``0..position`` are attended).
-    Returns (S, H, D) in ``q``'s dtype.
+    (num_blocks, H_kv, block_size, D) — ``(block_size, D)`` are the
+    minor dims so one block of every kv head is a slab the TPU can DMA
+    and index by head; ``block_tables``: (S, W) int32; ``positions``:
+    (S,) int32 — the cache index the slot's incoming token was written
+    at (tokens ``0..position`` are attended). Returns (S, H, D) in
+    ``q``'s dtype.
 
-    An int8 cache passes ``k_scale``/``v_scale`` — per-(block, row,
-    kv-head) absmax scales, shape (num_blocks, block_size, H_kv) — and
-    each block stream is dequantized in VMEM right after the DMA, so
-    the HBM roofline sees int8 bytes while the softmax math stays f32
-    (a bf16 cache needs no scales; the matmuls widen it natively).
+    An int8 cache passes ``k_scale``/``v_scale`` — per-(block, kv-head,
+    row) absmax scales, shape (num_blocks, H_kv, block_size) — and each
+    block stream is dequantized in VMEM right after the DMA, so the HBM
+    roofline sees int8 bytes while the softmax math stays f32 (a bf16
+    cache needs no scales; the matmuls widen it natively).
     """
     S, H, D = q.shape
-    n_blocks, block_size, n_kv, _ = k_cache.shape
+    n_blocks, n_kv, block_size, _ = k_cache.shape
     quantized = k_scale is not None
     if quantized and v_scale is None or not quantized \
             and v_scale is not None:
@@ -172,84 +193,74 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     bt = block_tables.astype(jnp.int32)
     pos = positions.astype(jnp.int32)
 
-    def _entry(sh, sp, j, bt_ref, pos_ref):
+    def _entry(s, sp, j, bt_ref, pos_ref):
         # dead entries (whole block past the live length) are clamped to
         # block 0 so the pipeline re-fetches the already-resident trash
         # block instead of streaming a block the kernel will skip
         idx = sp * bps + j
-        s = sh // n_kv
         live = idx * block_size <= pos_ref[s]
         return jnp.where(live, bt_ref[s, idx], 0)
+
+    def _kv_map(s, sp, j, bt_ref, pos_ref):
+        return _entry(s, sp, j, bt_ref, pos_ref), 0, 0, 0
+
+    def _out_map(s, sp, j, bt_ref, pos_ref):
+        return s, sp, 0, 0, 0
 
     kernel = functools.partial(
         _kernel, n_kv=n_kv, block_size=block_size, bps=bps, scale=scale,
         quantized=quantized)
+    kv_spec = pl.BlockSpec((1, n_kv, block_size, D), _kv_map)
     in_specs = [
-        pl.BlockSpec((1, 1, group, D),
-                     lambda sh, sp, j, bt_ref, pos_ref:
-                     (sh // n_kv, sh % n_kv, 0, 0)),
-        pl.BlockSpec((1, block_size, 1, D),
-                     lambda sh, sp, j, bt_ref, pos_ref:
-                     (_entry(sh, sp, j, bt_ref, pos_ref), 0,
-                      sh % n_kv, 0)),
-        pl.BlockSpec((1, block_size, 1, D),
-                     lambda sh, sp, j, bt_ref, pos_ref:
-                     (_entry(sh, sp, j, bt_ref, pos_ref), 0,
-                      sh % n_kv, 0)),
+        pl.BlockSpec((1, n_kv, group, D),
+                     lambda s, sp, j, bt_ref, pos_ref: (s, 0, 0, 0)),
+        kv_spec, kv_spec,
     ]
     operands = [q4, k_cache, v_cache]
     if quantized:
         # the scale rows ride the exact same block-table routing as
         # their K/V block (dead entries clamp to the trash block too)
         for s_arr in (k_scale, v_scale):
-            if s_arr.shape != (n_blocks, block_size, n_kv):
+            if s_arr.shape != (n_blocks, n_kv, block_size):
                 raise ValueError(
                     f"scale shape {s_arr.shape} != "
-                    f"{(n_blocks, block_size, n_kv)}")
+                    f"{(n_blocks, n_kv, block_size)}")
             in_specs.append(pl.BlockSpec(
-                (1, block_size, 1),
-                lambda sh, sp, j, bt_ref, pos_ref:
-                (_entry(sh, sp, j, bt_ref, pos_ref), 0, sh % n_kv)))
+                (1, n_kv, block_size),
+                lambda s, sp, j, bt_ref, pos_ref:
+                (_entry(s, sp, j, bt_ref, pos_ref), 0, 0)))
             operands.append(s_arr.astype(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S * n_kv, splits, bps),
+        grid=(S, splits, bps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, 1, group, D),
-                         lambda sh, sp, j, bt_ref, pos_ref:
-                         (sh // n_kv, sh % n_kv, sp, 0, 0)),
-            pl.BlockSpec((1, 1, 1, group, _LANES),
-                         lambda sh, sp, j, bt_ref, pos_ref:
-                         (sh // n_kv, sh % n_kv, sp, 0, 0)),
-            pl.BlockSpec((1, 1, 1, group, _LANES),
-                         lambda sh, sp, j, bt_ref, pos_ref:
-                         (sh // n_kv, sh % n_kv, sp, 0, 0)),
+            pl.BlockSpec((1, 1, n_kv, group, D), _out_map),
+            pl.BlockSpec((1, 1, n_kv, group, _LANES), _out_map),
+            pl.BlockSpec((1, 1, n_kv, group, _LANES), _out_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((n_kv, group, _LANES), jnp.float32),
+            pltpu.VMEM((n_kv, group, _LANES), jnp.float32),
+            pltpu.VMEM((n_kv, group, D), jnp.float32),
         ],
     )
-    # (slot*kv_head, split) programs are independent — mark them
-    # parallel so Mosaic can spread them over cores (megacore); only
-    # the innermost block walk carries the VMEM softmax state and must
-    # stay sequential. Without this the whole grid serializes and the
+    # (slot, split) programs are independent — mark them parallel so
+    # Mosaic can spread them over cores (megacore); only the innermost
+    # block walk carries the VMEM softmax state and must stay
+    # sequential. Without this the whole grid serializes and the
     # split-KV axis adds epilogue cost without its parallelism.
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         out_shape=[
-            jax.ShapeDtypeStruct((S, n_kv, splits, group, D),
+            jax.ShapeDtypeStruct((S, splits, n_kv, group, D),
                                  jnp.float32),
-            jax.ShapeDtypeStruct((S, n_kv, splits, group, _LANES),
+            jax.ShapeDtypeStruct((S, splits, n_kv, group, _LANES),
                                  jnp.float32),
-            jax.ShapeDtypeStruct((S, n_kv, splits, group, _LANES),
+            jax.ShapeDtypeStruct((S, splits, n_kv, group, _LANES),
                                  jnp.float32),
         ],
         interpret=interpret,
@@ -257,12 +268,12 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     # split-KV epilogue: merge the per-split partial softmaxes with the
     # log-sum-exp correction (dead splits carry m=-inf/l=0 and drop out)
-    m0 = m[..., 0]                                  # (S, n_kv, splits, G)
+    m0 = m[..., 0]                                  # (S, splits, n_kv, G)
     l0 = l[..., 0]
-    m_max = jnp.max(m0, axis=2, keepdims=True)
+    m_max = jnp.max(m0, axis=1, keepdims=True)
     m_safe = jnp.where(jnp.isfinite(m_max), m_max, 0.0)
     alpha = jnp.where(jnp.isfinite(m0), jnp.exp(m0 - m_safe), 0.0)
-    l_tot = jnp.sum(alpha * l0, axis=2)             # (S, n_kv, G)
-    o = jnp.sum(alpha[..., None] * acc, axis=2) / \
+    l_tot = jnp.sum(alpha * l0, axis=1)             # (S, n_kv, G)
+    o = jnp.sum(alpha[..., None] * acc, axis=1) / \
         jnp.where(l_tot == 0.0, 1.0, l_tot)[..., None]
     return o.astype(q.dtype).reshape(S, H, D)

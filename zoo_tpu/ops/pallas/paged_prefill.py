@@ -48,20 +48,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from zoo_tpu.ops.pallas import LANES as _LANES
+from zoo_tpu.ops.pallas import SUBLANES as _SUBLANES
+from zoo_tpu.ops.pallas import pad_dim as _pad_dim
 from zoo_tpu.ops.pallas import resolve_interpret as _resolve_interpret
+from zoo_tpu.ops.pallas.paged_decode import attend_block
 
 
-def _kernel(bt_ref, pos_sref, q_ref, pos_ref, k_ref, v_ref, *rest,
-            n_kv, block_size, group, width, scale, quantized):
-    """One (sequence*kv-head, table-entry) program; the innermost grid
-    axis walks the table with the online-softmax carry in VMEM scratch.
-    Rows = chunk positions x the kv head's query group."""
+def _kernel(bt_ref, last_ref, q_ref, pos_ref, k_ref, v_ref, *rest,
+            n_kv, block_size, width, scale, quantized):
+    """One (sequence, table-entry) program; the innermost grid axis
+    walks the table with the online-softmax carry in VMEM scratch. Each
+    entry's block arrives with ALL its kv heads — ``(n_kv, block_size,
+    D)``, the cache's own minor dims — and the heads are walked by a
+    static loop. Rows = chunk positions x the kv head's query group,
+    flattened (and padded to the sublane tile) by the wrapper, with
+    each row's cache position riding a lane-broadcast carrier."""
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
     out_ref, m_scr, l_scr, a_scr = rest
+    s = pl.program_id(0)
     j = pl.program_id(1)
-    C = pos_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -73,44 +80,22 @@ def _kernel(bt_ref, pos_sref, q_ref, pos_ref, k_ref, v_ref, *rest,
     # nondecreasing per chunk, so a block wholly past the LAST row's
     # position is dead for every row — skip (the index map already
     # clamped its DMA to the resident trash block)
-    pos_row = pos_ref[0, :]                                   # (C,)
-    # (C*group, 1) per-row positions: row r covers chunk index r//group
-    prow = jnp.broadcast_to(pos_row[:, None],
-                            (C, group)).reshape(C * group, 1)
     start = j * block_size
 
-    @pl.when(start <= pos_row[C - 1])
+    @pl.when(start <= last_ref[s])
     def _step():
-        q = q_ref[0, 0].reshape(C * group, q_ref.shape[-1])
-        k = k_ref[0, :, 0, :]                                 # (block, D)
-        v = v_ref[0, :, 0, :]
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        s_ = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (rows, block)
-        col = start + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-        mask = col <= prow
-        s_ = jnp.where(mask, s_, -jnp.inf)
-        m_prev = m_scr[:, :1]                            # (rows, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(jnp.where(mask, s_ - safe, -jnp.inf))
-        corr = jnp.where(jnp.isfinite(m_prev),
-                         jnp.exp(m_prev - safe), 0.0)
-        l_scr[:, :1] = corr * l_scr[:, :1] + \
-            jnp.sum(p, axis=-1, keepdims=True)
-        a_scr[...] = a_scr[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:, :1] = m_new
+        prow = pos_ref[0][:, :1]                              # (rows, 1)
+        for h in range(n_kv):
+            attend_block(
+                h, q_ref[0, h], k_ref[0, h], v_ref[0, h],
+                ks_ref[0, h:h + 1, :] if quantized else None,
+                vs_ref[0, h:h + 1, :] if quantized else None,
+                start, prow, scale, m_scr, l_scr, a_scr)
 
     @pl.when(j == width - 1)
     def _finish():
-        l = l_scr[:, :1]
-        out = a_scr[...] / jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = out.reshape(out_ref.shape[2:]).astype(
+        l = l_scr[...][:, :, :1]
+        out_ref[0] = (a_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
             out_ref.dtype)
 
 
@@ -126,17 +111,17 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     ``q``: (S, C, H, D) — C query rows per sequence (a prefill chunk,
     or a verify pass's k+1 candidate rows); ``k_cache``/``v_cache``:
-    (num_blocks, block_size, H_kv, D); ``block_tables``: (S, W) int32;
+    (num_blocks, H_kv, block_size, D); ``block_tables``: (S, W) int32;
     ``positions``: (S, C) int32 — the cache index each row's token was
     written at, NONDECREASING per sequence (row r attends every column
     ``<= positions[s, r]``, which covers causal-within-chunk plus the
     resident prefix). Returns (S, C, H, D) in ``q``'s dtype.
 
-    An int8 cache passes ``k_scale``/``v_scale`` (per-(block, row,
-    kv-head) absmax, shape (num_blocks, block_size, H_kv)); each block
+    An int8 cache passes ``k_scale``/``v_scale`` (per-(block, kv-head,
+    row) absmax, shape (num_blocks, H_kv, block_size)); each block
     stream is widened in VMEM right after the DMA."""
     S, C, H, D = q.shape
-    n_blocks, block_size, n_kv, _ = k_cache.shape
+    n_blocks, n_kv, block_size, _ = k_cache.shape
     quantized = k_scale is not None
     if quantized and v_scale is None or not quantized \
             and v_scale is not None:
@@ -153,79 +138,81 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
         scale = 1.0 / float(D) ** 0.5
     interpret = _resolve_interpret(interpret)
 
-    # (S, n_kv, C, group, D): one program streams a kv head's blocks
-    # against its C*group query rows
+    # (S, n_kv, C*group, D): a kv head's C*group query rows are one
+    # matmul operand. The flatten happens HERE (an XLA reshape), not in
+    # the kernel, and the row count is padded to the sublane tile —
+    # pad rows sit at position 0, attend one column and are sliced off.
+    rows = C * group
     q5 = q.reshape(S, C, n_kv, group, D).transpose(0, 2, 1, 3, 4)
+    q4 = _pad_dim(q5.reshape(S, n_kv, rows, D), 2, _SUBLANES)
+    rows_p = q4.shape[2]
     bt = block_tables.astype(jnp.int32)
     pos = positions.astype(jnp.int32)
+    # per-ROW positions in a lane-broadcast carrier (the flash kernel's
+    # lse idiom): row r covers chunk index r // group
+    prow = jnp.broadcast_to(
+        _pad_dim(jnp.repeat(pos, group, axis=1), 1, _SUBLANES)[..., None],
+        (S, rows_p, _LANES))
+    last = pos[:, C - 1]
 
-    def _entry(sk, j, bt_ref, pos_ref):
+    def _entry(s, j, bt_ref, last_ref):
         # dead entries (whole block past the last row's position) clamp
         # to block 0 so the pipeline re-fetches the resident trash
         # block instead of streaming a block the kernel will skip
-        s = sk // n_kv
-        live = j * block_size <= pos_ref[s, C - 1]
+        live = j * block_size <= last_ref[s]
         return jnp.where(live, bt_ref[s, j], 0)
 
+    def _q_map(s, j, bt_ref, last_ref):
+        return s, 0, 0, 0
+
+    def _kv_map(s, j, bt_ref, last_ref):
+        return _entry(s, j, bt_ref, last_ref), 0, 0, 0
+
     kernel = functools.partial(
-        _kernel, n_kv=n_kv, block_size=block_size, group=group,
-        width=W, scale=scale, quantized=quantized)
+        _kernel, n_kv=n_kv, block_size=block_size, width=W, scale=scale,
+        quantized=quantized)
+    kv_spec = pl.BlockSpec((1, n_kv, block_size, D), _kv_map)
     in_specs = [
-        pl.BlockSpec((1, 1, C, group, D),
-                     lambda sk, j, bt_ref, pos_ref:
-                     (sk // n_kv, sk % n_kv, 0, 0, 0)),
-        # the positions again as a VMEM operand: the kernel needs the
-        # (C,) row vector for masking, and SMEM scalar-prefetch reads
-        # are scalar-only
-        pl.BlockSpec((1, C),
-                     lambda sk, j, bt_ref, pos_ref: (sk // n_kv, 0)),
-        pl.BlockSpec((1, block_size, 1, D),
-                     lambda sk, j, bt_ref, pos_ref:
-                     (_entry(sk, j, bt_ref, pos_ref), 0, sk % n_kv, 0)),
-        pl.BlockSpec((1, block_size, 1, D),
-                     lambda sk, j, bt_ref, pos_ref:
-                     (_entry(sk, j, bt_ref, pos_ref), 0, sk % n_kv, 0)),
+        pl.BlockSpec((1, n_kv, rows_p, D), _q_map),
+        pl.BlockSpec((1, rows_p, _LANES),
+                     lambda s, j, bt_ref, last_ref: (s, 0, 0)),
+        kv_spec, kv_spec,
     ]
-    operands = [q5, pos, k_cache, v_cache]
+    operands = [q4, prow, k_cache, v_cache]
     if quantized:
         for s_arr in (k_scale, v_scale):
-            if s_arr.shape != (n_blocks, block_size, n_kv):
+            if s_arr.shape != (n_blocks, n_kv, block_size):
                 raise ValueError(
                     f"scale shape {s_arr.shape} != "
-                    f"{(n_blocks, block_size, n_kv)}")
+                    f"{(n_blocks, n_kv, block_size)}")
             in_specs.append(pl.BlockSpec(
-                (1, block_size, 1),
-                lambda sk, j, bt_ref, pos_ref:
-                (_entry(sk, j, bt_ref, pos_ref), 0, sk % n_kv)))
+                (1, n_kv, block_size),
+                lambda s, j, bt_ref, last_ref:
+                (_entry(s, j, bt_ref, last_ref), 0, 0)))
             operands.append(s_arr.astype(jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S * n_kv, W),
+        grid=(S, W),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, C, group, D),
-                         lambda sk, j, bt_ref, pos_ref:
-                         (sk // n_kv, sk % n_kv, 0, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, n_kv, rows_p, D), _q_map)],
         scratch_shapes=[
-            pltpu.VMEM((C * group, _LANES), jnp.float32),
-            pltpu.VMEM((C * group, _LANES), jnp.float32),
-            pltpu.VMEM((C * group, D), jnp.float32),
+            pltpu.VMEM((n_kv, rows_p, _LANES), jnp.float32),
+            pltpu.VMEM((n_kv, rows_p, _LANES), jnp.float32),
+            pltpu.VMEM((n_kv, rows_p, D), jnp.float32),
         ],
     )
-    # (sequence*kv_head) programs are independent — parallel over
-    # cores; the table walk carries the VMEM softmax state and must
-    # stay sequential
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
+    # sequence programs are independent — parallel over cores; the
+    # table walk carries the VMEM softmax state and must stay
+    # sequential
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         out_shape=[
-            jax.ShapeDtypeStruct((S, n_kv, C, group, D), q.dtype),
+            jax.ShapeDtypeStruct((S, n_kv, rows_p, D), q.dtype),
         ],
         interpret=interpret,
-    )(bt, pos, *operands)
+    )(bt, last, *operands)
+    out = out[:, :, :rows].reshape(S, n_kv, C, group, D)
     return out.transpose(0, 2, 1, 3, 4).reshape(S, C, H, D)

@@ -19,8 +19,9 @@ the *supervision* capability:
   localhost port, ranks via ``ZOO_*`` env) for dev/test of multi-host
   code paths.
 * CLI: ``python -m zoo_tpu.orca.bootstrap --nproc 4 train.py ...`` —
-  supervised multi-process launch, the torchrun/spark-submit analogue
-  (on a real pod, ``scripts/run_tpu_pod.sh`` runs one of these per host).
+  the same CPU rig from the command line: supervised workers forced to
+  ``JAX_PLATFORMS=cpu`` (it never opens a TPU; on a real pod,
+  ``scripts/run_tpu_pod.sh`` starts the one process per host).
 
 ``init_orca_context(cluster_mode="tpu")`` picks the rank/coordinator up
 from the ``ZOO_COORDINATOR_ADDRESS`` / ``ZOO_NUM_PROCESSES`` /
@@ -511,13 +512,16 @@ def launch_local_cluster(nproc: int, script: str,
                          env: Optional[Dict[str, str]] = None,
                          heartbeat_timeout: Optional[float] = None
                          ) -> ProcessMonitor:
-    """Boot an ``nproc``-process JAX CPU cluster running ``script`` on
-    this machine (the reference's local RayContext). Each worker gets
-    ``ZOO_COORDINATOR_ADDRESS`` / ``ZOO_NUM_PROCESSES`` /
-    ``ZOO_PROCESS_ID`` plus a forced-CPU JAX platform with
-    ``local_devices_per_proc`` virtual devices, so
-    ``init_orca_context(cluster_mode="tpu")`` forms the same process mesh
-    it would on a pod.
+    """The CPU test rig for multi-process jobs: boot an ``nproc``-process
+    JAX **CPU** cluster running ``script`` on this machine (the
+    reference's local RayContext). Every worker is forced to
+    ``JAX_PLATFORMS=cpu`` with ``local_devices_per_proc`` virtual
+    devices — it never opens a TPU, so it is not a way to run on chips
+    (one process drives all chips of a host; serving seats get one chip
+    each from :class:`zoo_tpu.serving.ha.ReplicaGroup`). Each worker
+    also gets ``ZOO_COORDINATOR_ADDRESS`` / ``ZOO_NUM_PROCESSES`` /
+    ``ZOO_PROCESS_ID``, so ``init_orca_context(cluster_mode="tpu")``
+    forms the same process mesh it would on a pod.
 
     ``heartbeat_timeout``: enable hung-worker detection — each worker is
     handed a heartbeat file (``ZOO_HEARTBEAT_FILE``; stamped by the
@@ -639,11 +643,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     ap = argparse.ArgumentParser(
         prog="python -m zoo_tpu.orca.bootstrap",
-        description="Supervised multi-process launcher (reference: "
-                    "RayContext/spark-submit role)")
+        description="CPU test rig: supervised multi-process launcher "
+                    "whose workers are forced to JAX_PLATFORMS=cpu "
+                    "with virtual devices (reference: RayContext/"
+                    "spark-submit role). It never opens a TPU.")
     ap.add_argument("--nproc", type=int, default=1)
     ap.add_argument("--max-restarts", type=int, default=0)
-    ap.add_argument("--devices-per-proc", type=int, default=1)
+    ap.add_argument("--devices-per-proc", type=int, default=1,
+                    help="virtual CPU devices per worker")
     ap.add_argument("--log-dir", default=None)
     ap.add_argument("--elastic-min-workers", type=int, default=0,
                     help="enable scale-down elastic mode: on permanent "

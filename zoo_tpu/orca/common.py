@@ -74,17 +74,6 @@ class OrcaContext(ZooContext):
 _DIST_INITIALIZED = False
 
 
-def _dist_already_initialized() -> bool:
-    try:
-        import jax
-        if hasattr(jax.distributed, "is_initialized"):
-            return bool(jax.distributed.is_initialized())
-        from jax._src import distributed as _d
-        return _d.global_state.client is not None
-    except Exception:
-        return False
-
-
 def _maybe_init_distributed(cluster_mode: str, num_nodes: int = 1):  # zoo-lint: config-parse
     """Initialize jax.distributed for multi-host pods. If the launcher (or
     user code) initialized it already, that wins. A failed initialize is
@@ -96,7 +85,7 @@ def _maybe_init_distributed(cluster_mode: str, num_nodes: int = 1):  # zoo-lint:
         return
     import jax
 
-    if _dist_already_initialized():
+    if jax.distributed.is_initialized():
         _DIST_INITIALIZED = True
         return
     try:
@@ -165,6 +154,9 @@ def init_orca_context(cluster_mode: str = "local",
                        "context")
         return existing
 
+    from zoo_tpu.common.compile_cache import ensure_compile_cache
+    cache_dir = ensure_compile_cache()  # before the first jit
+
     _maybe_init_distributed(cluster_mode, num_nodes)
 
     # supervised workers (zoo_tpu.orca.bootstrap with hung-worker
@@ -225,9 +217,9 @@ def init_orca_context(cluster_mode: str = "local",
     )
     _set_runtime_context(ctx)
     atexit.register(stop_orca_context)
-    logger.info("Orca context: mode=%s platform=%s devices=%d mesh=%s",
-                cluster_mode, ctx.platform, ctx.num_devices,
-                dict(mesh.shape))
+    logger.info("Orca context: mode=%s platform=%s devices=%d mesh=%s "
+                "compile_cache=%s", cluster_mode, ctx.platform,
+                ctx.num_devices, dict(mesh.shape), cache_dir)
     return ctx
 
 

@@ -411,7 +411,11 @@ class StagingBufferPool:
                     to_probe.append(b)
         try:
             aliased = any(_buffer_aliased_on_device(b) for b in to_probe)
-        except Exception:  # no devices / weird backend: stay off
+        except Exception as e:  # noqa: BLE001 — the feed must start
+            # either way, but a probe that cannot run on this backend
+            # is something to see, not to swallow
+            logger.warning("staging buffers disabled: the alias probe "
+                           "failed on this backend: %r", e)
             return None
         if aliased:
             logger.info("staging buffers disabled: jax.device_put "

@@ -104,8 +104,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh,
     # into every replica, which then redundantly computes all of it)
     daxes = data_axes(mesh)
     mb_spec = P(None, daxes if daxes else None)
-    from zoo_tpu.parallel.compat import shard_map
-    fn = shard_map(worker, mesh=mesh, in_specs=(specs, mb_spec),
-                   out_specs=mb_spec)
+    fn = jax.shard_map(worker, mesh=mesh, in_specs=(specs, mb_spec),
+                       out_specs=mb_spec, check_vma=False)
     ys = fn(stage_params, mbs)
     return ys.reshape(B, *ys.shape[2:])

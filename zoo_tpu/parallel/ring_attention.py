@@ -103,9 +103,8 @@ def ring_attention(mesh: Mesh, q, k, v, *, causal: bool = False,
         raise ValueError(f"q heads ({q.shape[1]}) must be a multiple of "
                          f"kv heads ({k.shape[1]})")
     spec = P(None, None, seq_axis, None)
-    from zoo_tpu.parallel.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=seq_axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=False)
+        check_vma=False)
     return fn(q, k, v)

@@ -153,6 +153,64 @@ def resolve_model_spec(spec: str, batch_size: int = 8
     return im, None
 
 
+# One process per chip: libtpu gives a chip to the first process that
+# opens it, and a second one fails or hangs. A seat that loads jax is
+# therefore handed exactly one chip through the variables libtpu
+# honours — the chip it may see, and a one-chip process topology so it
+# does not wait for peers.
+_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+_ONE_CHIP_ENV = {"TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                 "TPU_PROCESS_BOUNDS": "1,1,1",
+                 "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+
+
+def _host_chips(env: Dict[str, str]) -> List[str]:
+    """The chips a supervisor with this environment may hand out: its
+    own ``TPU_VISIBLE_CHIPS`` allotment when it has one, else every TPU
+    device node of the host. Empty on a machine without a TPU."""
+    allot = env.get(_CHIPS_ENV, "")
+    if allot.strip():
+        return [c.strip() for c in allot.split(",") if c.strip()]
+    import glob
+    nodes = glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*")
+    return [str(i) for i in range(len(nodes))]
+
+
+def _seats_need_chips(model: str, env: Dict[str, str]) -> bool:
+    """True when each seat of ``model`` will open a TPU: the spec loads
+    jax (anything but the jax-free ``synthetic:``/``synthllm:`` specs)
+    and the seat environment does not hold jax to another platform
+    (``JAX_PLATFORMS=cpu``, the test rig)."""
+    from zoo_tpu.serving.llm.spec import SYNTH_LLM_PREFIX
+    if all(p.startswith((SYNTHETIC_PREFIX, SYNTH_LLM_PREFIX))
+           for p in model.split("+")):
+        return False
+    platforms = env.get("JAX_PLATFORMS", "").strip().lower()
+    return not platforms or "tpu" in platforms.split(",")
+
+
+def seat_chip_envs(model: str, num_replicas: int,
+                   env: Dict[str, str]) -> List[Dict[str, str]]:
+    """Per-seat chip assignment: one environment patch per seat, empty
+    when the seats open no TPU (jax-free specs, the CPU rig, a host
+    with no chip). Seat ``i`` gets chip ``i`` of the visible ones, and
+    keeps it across respawn because the seat's environment is fixed at
+    construction. More jax seats than chips is refused here, by count
+    — the alternative is a seat that hangs at its first device call."""
+    chips = _host_chips(env) if _seats_need_chips(model, env) else []
+    if not chips:
+        return [{} for _ in range(num_replicas)]
+    if num_replicas > len(chips):
+        raise ValueError(
+            f"{num_replicas} replica seats of {model!r} each need a TPU "
+            f"chip of their own, but only {len(chips)} chip(s) are "
+            f"visible here ({','.join(chips)}); a chip belongs to one "
+            "process — lower num_replicas, or span chips inside one "
+            "seat with tp=N")
+    return [{_CHIPS_ENV: chips[i], **_ONE_CHIP_ENV}
+            for i in range(num_replicas)]
+
+
 def _free_ports(n: int) -> List[int]:
     """n distinct free ports, all bound while drawing so no duplicates."""
     import socket as _socket
@@ -185,7 +243,14 @@ class ReplicaGroup:
 
     ``max_restarts`` is the per-replica respawn budget
     (:class:`ProcessMonitor` semantics); ``heartbeat_timeout`` enables
-    hung-replica detection on top of crash detection."""
+    hung-replica detection on top of crash detection.
+
+    On a TPU host every seat that loads jax (``llama:*``, model files)
+    is given one chip of its own through its environment
+    (:func:`seat_chip_envs`) and keeps it across respawn; asking for
+    more such seats than the host has chips is an error here, not a
+    hang later. This supervisor never imports jax, so it holds no chip
+    itself."""
 
     def __init__(self, model: str, num_replicas: int = 3,
                  host: str = "127.0.0.1",
@@ -239,11 +304,14 @@ class ReplicaGroup:
 
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
+        base_env = dict(os.environ)
+        base_env.update(env or {})
+        chip_envs = seat_chip_envs(model, self.num_replicas, base_env)
         workers = []
         for i, (port, mport) in enumerate(zip(self.ports,
                                               self.metrics_ports)):
-            wenv = dict(os.environ)
-            wenv.update(env or {})
+            wenv = dict(base_env)
+            wenv.update(chip_envs[i])
             wenv["PYTHONPATH"] = root + os.pathsep + \
                 wenv.get("PYTHONPATH", "")
             if self.roles is not None:

@@ -2018,6 +2018,9 @@ class LLMEngine:
                # tensor-parallel ways the model spans (1 = replicated
                # single-device weights — the pre-mesh layout)
                "tp": getattr(self.model, "tp", 1),
+               # the device the model's cache lives on, as jax reports
+               # it (None for the jax-free synthetic model)
+               "device": getattr(self.model, "device_info", None),
                "prefill_chunk": self._chunk,
                "decode_attention_impl": getattr(
                    self.model, "decode_attention_impl", "host"),

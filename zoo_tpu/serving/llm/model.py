@@ -45,8 +45,10 @@ Inactive slots point their block table at the reserved trash block 0
 and are masked by position, so the executable has no liveness branch.
 
 The cache lives here as two device arrays
-``(n_layer, num_blocks, block_size, n_kv_head, head_dim)``, donated
-through every prefill/decode call so XLA updates them in place. The
+``(n_layer, num_blocks, n_kv_head, block_size, head_dim)`` —
+``(block_size, head_dim)`` minor, so one table entry is a slab the TPU
+kernels DMA whole and index by head — donated through every
+prefill/decode call so XLA updates them in place. The
 sampled token batch of a decode tick is likewise returned as a DEVICE
 array that :meth:`decode_step` accepts back as the next tick's input —
 the engine's overlapped pipeline chains ticks without a host round
@@ -113,11 +115,12 @@ _host_transfer = counter(
 def resolve_decode_impl(impl: Optional[str] = "auto") -> str:
     """Concrete decode-attention kernel for this process.
 
-    ``"auto"`` (default) picks the paged flash-decode Pallas kernel on
-    TPU hardware (``pallas.on_tpu()`` — device_kind probe, so an
-    experimentally-named platform is not silently demoted) and the
+    ``"auto"`` (default) picks the paged flash-decode Pallas kernel
+    when jax's backend is the TPU (``pallas.on_tpu()``) and the
     dense-gather reference off TPU, where the kernel would run under
-    the slow interpreter. ``ZOO_LLM_DECODE_IMPL`` force-overrides for
+    the slow interpreter. The pick is recorded
+    (``decode_attention_impl``, ``llm_stats``) so a run can assert
+    it. ``ZOO_LLM_DECODE_IMPL`` force-overrides for
     A/B runs and for asserting token identity on CPU
     (``dense`` / ``flash``)."""
     if impl in (None, "auto"):
@@ -314,7 +317,7 @@ class PagedLlamaModel:
         if self.spec_k < 0:
             raise ValueError("spec_k must be >= 0 (0 = off)")
         # KV storage dtype (docs/llm_serving.md): f32 (reference), bf16
-        # (half the bytes), int8 + per-(block,row,kv-head) absmax
+        # (half the bytes), int8 + per-(block,kv-head,row) absmax
         # scales (half again). Both the requested and resolved values
         # are recorded so an `auto` pick is visible in stats/bench.
         self.kv_cache_dtype_requested = kv_dtype if kv_dtype not in (
@@ -353,8 +356,8 @@ class PagedLlamaModel:
         # every executable (f32, tiny: max_context x head_dim/2)
         self._cos, self._sin = rope_frequencies(
             c.head_dim, self.max_context, c.rope_theta)
-        shape = (c.n_block, self.num_blocks, self.block_size,
-                 c.n_kv_head, c.head_dim)
+        shape = (c.n_block, self.num_blocks, c.n_kv_head,
+                 self.block_size, c.head_dim)
         cache_np = {"f32": jnp.float32, "bf16": jnp.bfloat16,
                     "int8": jnp.int8}[self.kv_cache_dtype]
         self._cache = {"k": jnp.zeros(shape, cache_np),
@@ -362,8 +365,8 @@ class PagedLlamaModel:
         if self.kv_cache_dtype == "int8":
             # absmax scale per written cache ROW, stored block-indexed
             # right beside the K/V blocks (the block table routes both)
-            sshape = (c.n_block, self.num_blocks, self.block_size,
-                      c.n_kv_head)
+            sshape = (c.n_block, self.num_blocks, c.n_kv_head,
+                      self.block_size)
             self._cache["ks"] = jnp.zeros(sshape, jnp.float32)
             self._cache["vs"] = jnp.zeros(sshape, jnp.float32)
         # HBM bytes ONE cached token costs (K+V rows over every layer,
@@ -418,9 +421,9 @@ class PagedLlamaModel:
             # carry the same head axis and shard with their blocks
             # (docs/multichip.md: the tp=N layout quantization keeps)
             kv_sh = NamedSharding(
-                self.mesh, P(None, None, None, "model", None))
+                self.mesh, P(None, None, "model", None, None))
             scale_sh = NamedSharding(
-                self.mesh, P(None, None, None, "model"))
+                self.mesh, P(None, None, "model", None))
             cache_sh = {"k": kv_sh, "v": kv_sh}
             if self.kv_cache_dtype == "int8":
                 cache_sh["ks"] = cache_sh["vs"] = scale_sh
@@ -451,6 +454,15 @@ class PagedLlamaModel:
                 self._copy_block_fn, donate_argnums=(0,),
                 in_shardings=(cache_sh, rep, rep),
                 out_shardings=cache_sh)
+
+        # where the paged cache (and so the executables) actually lives,
+        # as jax reports it — ``llm_stats`` publishes this, so a replica
+        # that came up on the host CPU, or a tp=N cache that landed on
+        # one device, is visible from outside the process
+        devs = sorted(self._cache["k"].devices(), key=lambda d: d.id)
+        self.device_info = {"platform": devs[0].platform,
+                            "kind": devs[0].device_kind,
+                            "ids": [int(d.id) for d in devs]}
 
     # test/debug views of the cache arrays (the canonical home is the
     # donated ``self._cache`` pytree)
@@ -491,13 +503,15 @@ class PagedLlamaModel:
         row is written once and never requantized, so bucketed, chunked
         and decode-appended writes of the same token are bit-identical
         cache bytes); bf16 narrows; f32 passes through."""
+        # (blk, :, off) — the advanced indices straddle the kv-head
+        # slice, so the indexed view is (..., n_kv, D): x's own shape
         if self.kv_cache_dtype == "int8":
             s = absmax_scale(x, axis=-1, keepdims=True, xp=jnp)
-            cachel = cachel.at[blk, off].set(
+            cachel = cachel.at[blk, :, off].set(
                 narrow_int8(x, s, xp=jnp))
-            scalel = scalel.at[blk, off].set(s[..., 0])
+            scalel = scalel.at[blk, :, off].set(s[..., 0])
             return cachel, scalel
-        return cachel.at[blk, off].set(x.astype(cachel.dtype)), scalel
+        return cachel.at[blk, :, off].set(x.astype(cachel.dtype)), scalel
 
     def _layer_ys(self, kcl, vcl, ksl, vsl):
         ys = (kcl, vcl)
@@ -506,13 +520,15 @@ class PagedLlamaModel:
         return ys
 
     def _widen_gather(self, cachel, scalel, idx):
-        """Gather cache blocks by table ``idx`` and widen to f32 (int8
-        rows times their scales; bf16/f32 a plain cast) — the dense
+        """Gather cache blocks by table ``idx`` (B, W), widen to f32
+        (int8 rows times their scales; bf16/f32 a plain cast) and lay
+        the result out token-major, (B, W * block, n_kv, D) — the dense
         reference for exactly what the flash kernel does in VMEM."""
-        g = cachel[idx].astype(jnp.float32)
+        g = cachel[idx].astype(jnp.float32)      # (B, W, n_kv, block, D)
         if scalel is not None:
             g = g * scalel[idx][..., None]
-        return g
+        B, W, n_kv, bs, D = g.shape
+        return g.transpose(0, 1, 3, 2, 4).reshape(B, W * bs, n_kv, D)
 
     def _copy_block_fn(self, cache, src, dst):
         """Block ``src`` -> ``dst`` across every layer (K, V and scale
@@ -543,10 +559,34 @@ class PagedLlamaModel:
                 else params["head"])
         return h @ head.astype(h.dtype)
 
+    def _on_model_axis(self, kernel, q, q_spec, kcl, vcl, ksl, vsl,
+                       block_tables, positions, pos_spec, scale):
+        """tp: run a paged kernel under ``shard_map`` over the mesh's
+        ``model`` axis — each device streams ITS kv heads' cache shard
+        (and, under int8, their scale rows) against the query heads of
+        those groups. Attention is head-local, so the only
+        communication after the kernel is the row-parallel ``wo``
+        matmul GSPMD already inserts."""
+        from jax.sharding import PartitionSpec as P
+
+        kv = P(None, "model", None, None)
+        scales = () if ksl is None else (ksl, vsl)
+
+        def local(q_, k_, v_, bt_, pos_, *sc):
+            kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+            return kernel(q_, k_, v_, bt_, pos_, scale=scale, **kw)
+
+        return jax.shard_map(
+            local, mesh=self.mesh,
+            in_specs=(q_spec, kv, kv, P(None, None), pos_spec)
+            + (P(None, "model", None),) * len(scales),
+            out_specs=q_spec, check_vma=False,
+        )(q, kcl, vcl, block_tables, positions, *scales)
+
     def _paged_attend(self, q, kcl, vcl, ksl, vsl, block_tables,
                       positions):
         """Single-query attention over the paged cache: (S, H, D) q
-        against the (blocks, block, n_kv, D) layer cache, routed by the
+        against the (blocks, n_kv, block, D) layer cache, routed by the
         block tables and masked to each slot's live length. Dispatches
         to the paged flash-decode Pallas kernel or the dense-gather
         reference per ``decode_attention_impl``; an int8 cache hands
@@ -563,50 +603,18 @@ class PagedLlamaModel:
                     q, kcl, vcl, block_tables, positions,
                     k_scale=ksl, v_scale=vsl,
                     scale=scale).reshape(S, c.n_head * c.head_dim)
-            # tp: each device runs the kernel over ITS kv heads' cache
-            # shard and the query heads of those groups — attention is
-            # head-local, so the only post-kernel communication is the
-            # row-parallel wo matmul GSPMD already inserts. Scale rows
-            # shard on the same kv-head axis as their blocks.
             from jax.sharding import PartitionSpec as P
-
-            from zoo_tpu.parallel.compat import shard_map
-            if ksl is None:
-                out = shard_map(
-                    lambda q_, k_, v_, bt_, pos_: paged_flash_decode(
-                        q_, k_, v_, bt_, pos_, scale=scale),
-                    mesh=self.mesh,
-                    in_specs=(P(None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None), P(None)),
-                    out_specs=P(None, "model", None),
-                )(q, kcl, vcl, block_tables, positions)
-            else:
-                out = shard_map(
-                    lambda q_, k_, v_, ks_, vs_, bt_, pos_:
-                    paged_flash_decode(
-                        q_, k_, v_, bt_, pos_, k_scale=ks_,
-                        v_scale=vs_, scale=scale),
-                    mesh=self.mesh,
-                    in_specs=(P(None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None, "model"),
-                              P(None, None, "model"),
-                              P(None, None), P(None)),
-                    out_specs=P(None, "model", None),
-                )(q, kcl, vcl, ksl, vsl, block_tables, positions)
+            out = self._on_model_axis(
+                paged_flash_decode, q, P(None, "model", None), kcl, vcl,
+                ksl, vsl, block_tables, positions, P(None), scale)
             return out.reshape(S, c.n_head * c.head_dim)
         # dense-gather reference: materialize cache[block_table], widen
         # and mask — the PR 7 path, kept as the off-TPU fallback and
         # the token-identity anchor for the kernel
         ctx = self.max_blocks_per_seq * self.block_size
         live = jnp.arange(ctx)[None, :] <= positions[:, None]  # (S, ctx)
-        keys = self._widen_gather(kcl, ksl, block_tables).reshape(
-            S, ctx, c.n_kv_head, c.head_dim)
-        vals = self._widen_gather(vcl, vsl, block_tables).reshape(
-            S, ctx, c.n_kv_head, c.head_dim)
+        keys = self._widen_gather(kcl, ksl, block_tables)
+        vals = self._widen_gather(vcl, vsl, block_tables)
         return self._masked_gather_attention(q, keys, vals, live)
 
     def _prefill_attend(self, q, kcl, vcl, ksl, vsl, block_tables,
@@ -634,46 +642,19 @@ class PagedLlamaModel:
                     q, kcl, vcl, block_tables, positions,
                     k_scale=ksl, v_scale=vsl, scale=scale)
                 return out.reshape(B, R, c.n_head * c.head_dim)
-            # tp: each device streams ITS kv heads' cache shard against
-            # the query heads of those groups — attention is
-            # head-local, same layout argument as the decode kernel
             from jax.sharding import PartitionSpec as P
-
-            from zoo_tpu.parallel.compat import shard_map
-            if ksl is None:
-                out = shard_map(
-                    lambda q_, k_, v_, bt_, pos_: paged_flash_prefill(
-                        q_, k_, v_, bt_, pos_, scale=scale),
-                    mesh=self.mesh,
-                    in_specs=(P(None, None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None), P(None, None)),
-                    out_specs=P(None, None, "model", None),
-                )(q, kcl, vcl, block_tables, positions)
-            else:
-                out = shard_map(
-                    lambda q_, k_, v_, ks_, vs_, bt_, pos_:
-                    paged_flash_prefill(
-                        q_, k_, v_, bt_, pos_, k_scale=ks_,
-                        v_scale=vs_, scale=scale),
-                    mesh=self.mesh,
-                    in_specs=(P(None, None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None, "model", None),
-                              P(None, None, "model"),
-                              P(None, None, "model"),
-                              P(None, None), P(None, None)),
-                    out_specs=P(None, None, "model", None),
-                )(q, kcl, vcl, ksl, vsl, block_tables, positions)
+            out = self._on_model_axis(
+                paged_flash_prefill, q, P(None, None, "model", None), kcl,
+                vcl, ksl, vsl, block_tables, positions, P(None, None),
+                scale)
             return out.reshape(B, R, c.n_head * c.head_dim)
         # dense anchor: materialize cache[block_table] per sequence,
         # widen, broadcast over the rows, and run the shared masked
         # attention body — exactly what the kernel streams in VMEM
         ctx = self.max_blocks_per_seq * self.block_size
         kv = (B, ctx, c.n_kv_head, c.head_dim)
-        keys = self._widen_gather(kcl, ksl, block_tables).reshape(kv)
-        vals = self._widen_gather(vcl, vsl, block_tables).reshape(kv)
+        keys = self._widen_gather(kcl, ksl, block_tables)
+        vals = self._widen_gather(vcl, vsl, block_tables)
         keys = jnp.broadcast_to(keys[:, None], (B, R) + kv[1:]).reshape(
             (B * R,) + kv[1:])
         vals = jnp.broadcast_to(vals[:, None], (B, R) + kv[1:]).reshape(
@@ -775,7 +756,8 @@ class PagedLlamaModel:
             q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
             k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
             v = v.transpose(0, 2, 1, 3)
-            a = dot_product_attention(q, k, v, causal=True, impl=impl)
+            a = dot_product_attention(q, k, v, causal=True, impl=impl,
+                                      mesh=self.mesh)
             a = a.transpose(0, 2, 1, 3).reshape(1, L,
                                                 c.n_head * c.head_dim)
             h = h + a @ p["wo"]

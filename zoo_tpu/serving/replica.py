@@ -36,6 +36,10 @@ def serve_replica(ns) -> int:
     )
 
     start_heartbeat_thread()  # no-op unless the supervisor set the env
+    # before any model load can jit: every seat of a group compiles
+    # into (and loads from) the one cache of the checkout
+    from zoo_tpu.common.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     # black box first: the recorder opens its spill file (when the
     # supervisor armed $ZOO_OBS_POSTMORTEM_DIR) before the model load —
     # a boot crash leaves remains too. The SIGTERM crash handler is

@@ -84,6 +84,10 @@ def main(argv=None) -> int:
                          "(ZOO_MODEL_ENC_MODE env; default cbc)")
     ns = ap.parse_args(argv)
 
+    # before the model load can jit; spawned replicas inherit the place
+    from zoo_tpu.common.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
+
     if ns.config:
         cfg = _load_config(ns.config)
         ns.model = ns.model or cfg.get("modelPath") or cfg.get("path")

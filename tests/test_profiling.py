@@ -104,85 +104,3 @@ def test_eval_phase_and_save_strips_profiler(tmp_path):
     assert getattr(loaded, "_profiler", None) is None
     assert m._profiler is not None  # original untouched
 
-
-# -- hand-built XSpace wire-format helpers (shared by the xplane tests) --
-
-def _varint(v):
-    out = b""
-    while True:
-        b7 = v & 0x7F
-        v >>= 7
-        if v:
-            out += bytes([b7 | 0x80])
-        else:
-            return out + bytes([b7])
-
-
-def _field(n, payload):
-    return _varint((n << 3) | 2) + _varint(len(payload)) + payload
-
-
-def _vfield(n, v):
-    return _varint(n << 3) + _varint(v)
-
-
-def _meta(mid, name):
-    return _field(4, _vfield(1, mid) + _field(2, _vfield(1, mid)
-                                              + _field(2, name)))
-
-
-def test_xplane_parser_roundtrip(tmp_path):
-    """device_op_times on a hand-built XSpace: one TPU plane, two events
-    with durations carried via the device_duration_ps stat."""
-    from zoo_tpu.common.xplane import device_op_times, op_breakdown
-
-    ev_meta = _meta(7, b"%fusion.1 = f32[2]{0} fusion(...), kind=kLoop")
-    stat_meta = _field(5, _vfield(1, 2) + _field(2, _vfield(1, 2) + _field(
-        2, b"device_duration_ps")))
-    stat = _field(4, _vfield(1, 2) + _vfield(3, 5_000_000))  # 5 us
-    event = _field(4, _vfield(1, 7) + stat)
-    line = _field(3, _field(2, b"XLA Ops") + event + event)
-    plane = _field(1, _field(2, b"/device:TPU:0") + ev_meta + stat_meta
-                   + line)
-    p = tmp_path / "t.xplane.pb"
-    p.write_bytes(plane)
-
-    times = device_op_times(str(p))
-    (name, (ms, cnt)), = times.items()
-    assert "fusion.1" in name and cnt == 2
-    assert abs(ms - 0.01) < 1e-9
-    rows = op_breakdown(str(p))
-    assert rows[0][0] == "fusion/kLoop" and rows[0][2] == 2
-
-
-def test_xplane_parser_skips_step_and_module_lines(tmp_path):
-    """Real device planes carry Steps / XLA Modules lines whose events
-    span whole training steps; only the XLA Ops line may feed the op
-    breakdown (the round-3 parser summed everything and reported
-    step-length 'ops' named by their step number)."""
-    from zoo_tpu.common.xplane import device_op_times, op_breakdown
-
-    op_meta = _meta(7, b"%convolution.5 = f32[2]{0} convolution(...)")
-    step_meta = _meta(9, b"17")  # steps are named by their number
-    wrap_meta = _meta(11, b"%while.6 = while(...)")
-    op_event = _field(4, _vfield(1, 7) + _vfield(3, 2_000_000))    # 2 us
-    step_event = _field(4, _vfield(1, 9) + _vfield(3, 900_000_000))
-    wrap_event = _field(4, _vfield(1, 11) + _vfield(3, 800_000_000))
-    ops_line = _field(3, _field(2, b"XLA Ops") + op_event + op_event
-                      + wrap_event)
-    steps_line = _field(3, _field(2, b"Steps") + step_event)
-    mod_line = _field(3, _field(2, b"XLA Modules") + step_event)
-    plane = _field(1, _field(2, b"/device:TPU:0") + op_meta + step_meta
-                   + wrap_meta + ops_line + steps_line + mod_line)
-    p = tmp_path / "t.xplane.pb"
-    p.write_bytes(plane)
-
-    times = device_op_times(str(p))
-    names = set(times)
-    assert any("convolution.5" in n for n in names)
-    assert not any(n == "17" for n in names), names  # Steps excluded
-    # the while wrapper rides the XLA Ops line but must not dominate
-    # the breakdown (its children are counted individually)
-    rows = op_breakdown(str(p))
-    assert rows[0][0] == "convolution" and rows[0][2] == 2, rows
-    assert not any(r[0].startswith("while") for r in rows)

@@ -33,6 +33,11 @@ DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 def ensure_compile_cache() -> str:  # zoo-lint: config-parse
     """Point jax's persistent compile cache at its one place and return
     that directory. Call before the first jit of the process."""
+    # every compile and cache load from here on is a `jit.compile` span
+    # in the ring (docs/observability.md): what set-up cost. (Only once
+    # jax is imported; LLMEngine and KerasNet.compile ask again.)
+    from zoo_tpu.obs.tracing import watch_compiles
+    watch_compiles()
     placed = os.environ.get(CACHE_DIR_ENV)
     if placed:
         return placed

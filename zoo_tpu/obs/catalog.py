@@ -68,6 +68,9 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "zoo_step_phase_seconds": ("histogram", ("phase",)),
     "zoo_mesh_axis_size": ("gauge", ("axis",)),
     "zoo_mesh_collective_bytes_total": ("counter", ("op",)),
+    # -- compiles (obs.tracing.watch_compiles) ------------------------------
+    "zoo_jit_compiles_total": ("counter", ()),
+    "zoo_jit_compile_seconds_total": ("counter", ()),
     # -- serving (single server) -------------------------------------------
     "zoo_serving_queue_depth": ("gauge", ()),
     "zoo_serving_batch_occupancy": ("histogram", ()),

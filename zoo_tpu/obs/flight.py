@@ -43,7 +43,7 @@ import time
 from typing import Dict, List, Optional
 
 from zoo_tpu.obs.metrics import counter, get_registry
-from zoo_tpu.obs.tracing import active_spans, iter_jsonl
+from zoo_tpu.obs.tracing import active_spans, iter_jsonl, recent_spans
 
 __all__ = [
     "FlightRecorder", "flight_recorder", "record_event",
@@ -70,6 +70,19 @@ def _config_snapshot() -> Dict[str, str]:
     operator needs to know about how the dead process was configured."""
     return {k: v for k, v in sorted(os.environ.items())
             if k.startswith(("ZOO_", "JAX_", "XLA_"))}
+
+
+RECENT_SPANS = 2048
+
+
+def _recent_spans_tail() -> List[dict]:
+    """The tail of the span ring as JSON: ``ago_s`` is how long before
+    this dump the span STARTED (the ring's clock is ``perf_counter``,
+    which means nothing outside the process)."""
+    now = time.perf_counter()
+    return [{"name": name, "ago_s": now - t0, "dur_s": dur, "tid": tid,
+             "attrs": attrs}
+            for name, t0, dur, tid, attrs in recent_spans()[-RECENT_SPANS:]]
 
 
 class FlightRecorder:
@@ -155,7 +168,9 @@ class FlightRecorder:
 
     def snapshot_bundle(self, reason: str) -> Dict:
         """The postmortem payload: ring + metrics + config + open spans
-        + last SLO verdict. Also what the wire ``op=debug_dump`` serves
+        + the last ``RECENT_SPANS`` finished spans of the always-on
+        span ring (a wedged replica's last ticks, phase by phase) +
+        last SLO verdict. Also what the wire ``op=debug_dump`` serves
         live."""
         try:
             metrics = get_registry().snapshot()
@@ -174,6 +189,7 @@ class FlightRecorder:
                 "metrics": metrics,
                 "config": _config_snapshot(),
                 "active_spans": active_spans(),
+                "recent_spans": _recent_spans_tail(),
                 "slo": slo}
 
     def dump(self, reason: str,  # zoo-lint: config-parse

@@ -34,7 +34,7 @@ from zoo_tpu.obs.tracing import iter_jsonl
 
 __all__ = [
     "load_events", "group_traces", "build_timeline", "merge_timeline",
-    "to_chrome_trace", "render_text",
+    "to_chrome_trace", "render_text", "ttft_breakdown",
 ]
 
 
@@ -200,4 +200,33 @@ def render_text(timeline: List[dict]) -> str:
         flag = "" if e.get("ok", True) else "  !err"
         lines.append(f"+{off:10.2f}ms  {dur}  {e['name']:<28s} "
                      f"[{src}]{flag}{attrs}")
+    parts = ttft_breakdown(timeline)
+    if parts is not None:
+        lines.append(
+            f"engine ttft {sum(parts[:2]) * 1e3:.2f}ms = queue wait "
+            f"{parts[0] * 1e3:.2f}ms + prefill {parts[1] * 1e3:.2f}ms "
+            f"({parts[2]} chunk(s))")
     return "\n".join(lines)
+
+
+def ttft_breakdown(timeline: List[dict]) -> Optional[tuple]:
+    """``(queue_wait_s, prefill_total_s, chunks)`` of the attempt that
+    produced the request's first token: the engine's ``llm.queue_wait``
+    (created -> admitted) and ``llm.prefill_total`` (admitted -> first
+    token) of the same process — whether a slow first token queued for
+    a slot or waited on its prompt. None when the trace holds no
+    ``llm.prefill_total`` (no first token, or an engine predating the
+    spans)."""
+    done = [e for e in timeline if e["name"] == "llm.prefill_total"]
+    if not done:
+        return None
+    first = done[0]
+    waits = [e for e in timeline if e["name"] == "llm.queue_wait"
+             and e.get("file") == first.get("file")
+             and e.get("pid") == first.get("pid")
+             and e.get("ts", 0.0) <= first.get("ts", 0.0)]
+    if not waits:
+        return None
+    return (float(waits[-1]["dur_s"] or 0.0),
+            float(first["dur_s"] or 0.0),
+            int((first.get("attrs") or {}).get("chunks", 0)))

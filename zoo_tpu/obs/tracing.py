@@ -15,9 +15,26 @@ every process over the same JAX coordination-service KV store that
 ``rebalance_shards`` uses, so all hosts' trace files stitch on the
 shared id.
 
-Tracing is off until a sink exists: call :func:`trace_to` or set
-``$ZOO_TRACE_DIR``. A disabled :func:`span` costs one global check and a
-no-op context manager — safe to leave in hot paths.
+The JSONL file is off until a sink exists: call :func:`trace_to` or set
+``$ZOO_TRACE_DIR``. Two cheaper sinks are ALWAYS on, so the program
+times itself with no configuration (docs/observability.md "The span
+ring"):
+
+* a process-wide bounded ring of the last ``RING_CAPACITY`` finished
+  spans, ``(name, t0, dur_s, thread_id, attrs_or_None)`` with ``t0`` on
+  ``time.perf_counter()`` — read it with :func:`recent_spans`; the
+  flight recorder dumps its tail into every postmortem bundle and the
+  benchmark's ``program_spans`` readers turn it into per-layer metrics;
+* while jax is imported, ``jax.profiler.TraceAnnotation("zoo:" +
+  name)`` — a no-op outside a profiler session, and inside one the span
+  lies in the ``.xplane.pb`` on the profiler's clock beside the
+  device's operations.
+
+A :func:`span` with no JSONL sink costs two clock reads, one ring append
+and the no-op annotation (under 3 us; ``tests/test_obs_spans.py`` holds
+it under 20) — safe to leave in hot paths. :func:`watch_compiles` puts
+every XLA compile and lowering into the same ring as ``jit.compile`` /
+``jit.lower`` spans.
 
 Request-scoped tracing (docs/observability.md): a serving client mints
 one trace id per logical request and it rides the wire; the server
@@ -33,15 +50,18 @@ scheduler thread, the batcher) where thread-local nesting cannot apply.
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
 import logging
 import os
 import socket
+import sys
 import threading
 import time
 import uuid
-from typing import Iterator, List, Optional
+from typing import Deque, Dict, Iterator, List, Optional
 
 from zoo_tpu.obs.coordination import coordination_client
 
@@ -52,6 +72,7 @@ __all__ = [
     "trace_context", "ambient_trace_id", "current_span_id",
     "new_trace_id", "emit_span", "emit_event", "active_spans",
     "iter_jsonl", "trace_file_path",
+    "recent_spans", "ring_state", "watch_compiles", "RING_CAPACITY",
 ]
 
 logger = logging.getLogger(__name__)
@@ -70,6 +91,39 @@ _tls = threading.local()  # .stack: span-id stack per thread
 # tracing is off), so the disabled hot path never touches it.
 _live_spans: dict = {}
 _live_lock = threading.Lock()
+
+# the always-on ring: finished spans only, appended at span END (so the
+# ring is ordered by end time). deque.append and next(count) are single
+# C calls — no lock, no allocation beyond the tuple.
+RING_CAPACITY = 65536
+_ring: Deque[tuple] = collections.deque(maxlen=RING_CAPACITY)
+_written = itertools.count()
+_get_ident = threading.get_ident
+_perf_counter = time.perf_counter
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, once jax is in
+
+
+def _record(name: str, t0: float, dur_s: float, attrs: Optional[dict]):
+    _ring.append((name, t0, dur_s, _get_ident(), attrs))
+    next(_written)
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation("zoo:" + name)``, or None while jax
+    has not been imported by anyone (``obs/`` itself never imports
+    it)."""
+    global _TraceAnnotation
+    cls = _TraceAnnotation
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None),
+                      "TraceAnnotation", None)
+        if cls is None:     # not imported (or mid-import on a thread)
+            return None
+        _TraceAnnotation = cls
+    ann = cls("zoo:" + name)
+    ann.__enter__()
+    return ann
 
 
 class _TraceLog:
@@ -261,41 +315,72 @@ def _stack() -> List[str]:
     return st
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs) -> Iterator[Optional[str]]:
-    """Timed, nested trace region; yields the span id (None when tracing
-    is off). Exceptions propagate; the end event records ``ok: false``."""
-    sink = _active_sink()
-    if sink is None:
-        yield None
-        return
-    sid = uuid.uuid4().hex[:16]
-    st = _stack()
-    parent = st[-1] if st else None
-    ev = {"ev": "B", "name": name, "trace": current_trace_id(),
-          "span": sid, "parent": parent, "pid": os.getpid(),
-          "ts": time.time()}
-    if attrs:
-        ev["attrs"] = attrs
-    sink.write(ev)
-    with _live_lock:
-        _live_spans[sid] = ev
-    st.append(sid)
-    t0 = time.perf_counter()
-    ok = True
-    try:
-        yield sid
-    except BaseException:
-        ok = False
-        raise
-    finally:
-        st.pop()
+class span:
+    """Timed, nested trace region: ``with span("ckpt.save", step=3) as
+    sid``. Always lands in the ring and (jax imported) under a ``zoo:``
+    profiler annotation; with a JSONL sink it also writes the B/E event
+    pair and yields the span id (None without a sink). Exceptions
+    propagate; the end event records ``ok: false``. Attributes known
+    only at the end are added with :meth:`note` before the block
+    exits."""
+
+    __slots__ = ("name", "attrs", "_t0", "_ann", "_sink", "_ev")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs or None
+        self._sink = None
+
+    def note(self, **attrs):
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+
+    def __enter__(self) -> Optional[str]:
+        sid = None
+        sink = _active_sink()
+        if sink is not None:
+            sid = self._begin(sink)
+        self._ann = _annotation(self.name)
+        self._t0 = _perf_counter()
+        return sid
+
+    def __exit__(self, exc_type, exc, tb):
+        t0 = self._t0
+        dur = _perf_counter() - t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _record(self.name, t0, dur, self.attrs)
+        if self._sink is not None:
+            self._end(dur, exc_type is None)
+        return False
+
+    # -- the JSONL sink (only with a trace dir) ----------------------------
+    def _begin(self, sink: "_TraceLog") -> str:
+        sid = uuid.uuid4().hex[:16]
+        st = _stack()
+        ev = {"ev": "B", "name": self.name, "trace": current_trace_id(),
+              "span": sid, "parent": st[-1] if st else None,
+              "pid": os.getpid(), "ts": time.time()}
+        if self.attrs:
+            ev["attrs"] = dict(self.attrs)
+        sink.write(ev)
         with _live_lock:
-            _live_spans.pop(sid, None)
-        sink.write({"ev": "E", "name": name,
-                    "trace": ev["trace"], "span": sid,
-                    "ts": time.time(),
-                    "dur_s": time.perf_counter() - t0, "ok": ok})
+            _live_spans[sid] = ev
+        st.append(sid)
+        self._sink, self._ev = sink, ev
+        return sid
+
+    def _end(self, dur: float, ok: bool):
+        sink, ev = self._sink, self._ev
+        self._sink = None
+        _stack().pop()
+        with _live_lock:
+            _live_spans.pop(ev["span"], None)
+        sink.write({"ev": "E", "name": self.name, "trace": ev["trace"],
+                    "span": ev["span"], "ts": time.time(),
+                    "dur_s": dur, "ok": ok})
 
 
 def active_spans() -> List[dict]:
@@ -309,13 +394,18 @@ def emit_span(name: str, ts: float, dur_s: float,
               trace: Optional[str] = None,
               parent: Optional[str] = None, ok: bool = True,
               span_id: Optional[str] = None,
+              t0: Optional[float] = None,
               **attrs) -> Optional[str]:
     """Write one COMPLETE ("X") span event: started at wall ``ts``,
     lasted ``dur_s``. For recorders that time a region themselves on
     behalf of a specific request (the engine's scheduler working a
     stream, a client attempt thread) where a nested :func:`span` cannot
-    carry the right identity. ``trace=None`` falls back to the active
-    trace id. Returns the span id (None while tracing is off)."""
+    carry the right identity. ``t0`` is the same start on
+    ``time.perf_counter()``: with it the span also lands in the ring
+    (a call without it is JSONL-only). ``trace=None`` falls back to
+    the active trace id. Returns the span id (None without a sink)."""
+    if t0 is not None:
+        _record(name, t0, float(dur_s), attrs or None)
     sink = _active_sink()
     if sink is None:
         return None
@@ -347,6 +437,88 @@ def emit_event(name: str, trace: Optional[str] = None,
         ev["attrs"] = attrs
     sink.write(ev)
     return sid
+
+
+# ------------------------------------------------------------- the ring
+
+def recent_spans(name: Optional[str] = None, since: Optional[float] = None,
+                 until: Optional[float] = None) -> List[tuple]:
+    """The ring's spans ``(name, t0, dur_s, thread_id, attrs_or_None)``
+    whose START lies in ``[since, until)`` (``perf_counter``), oldest
+    end first; ``name`` keeps one span name. Check :func:`ring_state`
+    before trusting a window: spans older than the ring's reach are
+    simply gone."""
+    out = list(_ring)       # one C call: a consistent copy under the GIL
+    if name is not None:
+        out = [r for r in out if r[0] == name]
+    if since is not None:
+        out = [r for r in out if r[1] >= since]
+    if until is not None:
+        out = [r for r in out if r[1] < until]
+    return out
+
+
+def ring_state() -> Dict[str, object]:
+    """``capacity``, ``written`` (spans ever recorded) and ``oldest_t0``
+    (start of the oldest span still held; None when empty). The ring
+    has dropped spans iff ``written > capacity``; what it dropped ENDED
+    before the oldest held span did."""
+    try:
+        oldest = _ring[0][1]
+    except IndexError:
+        oldest = None
+    return {"capacity": RING_CAPACITY,
+            # count's repr ("count(41)") is the one read of its value
+            # that neither advances it nor is deprecated (copy/pickle)
+            "written": int(repr(_written)[6:-1]),
+            "oldest_t0": oldest}
+
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+    "/jax/core/compile/jaxpr_trace_duration": "jit.lower",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+}
+_compile_watch_lock = threading.Lock()
+_compile_watched = False
+
+
+def watch_compiles() -> bool:
+    """Idempotent: from now on every XLA backend compile (or persistent-
+    cache load) is a ring span ``jit.compile`` and every trace/lowering
+    a ``jit.lower``, each with attr ``fun`` (``jit(epoch_fn)``) and
+    ``t0 = now - duration``; compiles also bump
+    ``zoo_jit_compiles_total`` / ``zoo_jit_compile_seconds_total``.
+    "Which step recompiled, and what did it cost" for serving and
+    training alike. Does nothing (and may be called again) until jax
+    has been imported: a jax-free process has nothing to compile."""
+    global _compile_watched
+    with _compile_watch_lock:
+        if _compile_watched or "jax" not in sys.modules:
+            return False
+        import jax.monitoring
+        from zoo_tpu.obs.metrics import counter
+        compiles = counter(
+            "zoo_jit_compiles_total",
+            "XLA backend compiles (or persistent-cache loads) this "
+            "process ran")
+        seconds = counter(
+            "zoo_jit_compile_seconds_total",
+            "Wall seconds spent in XLA backend compiles / cache loads")
+
+        def on_duration(event: str, duration: float, **kwargs):
+            name = _COMPILE_EVENTS.get(event)
+            if name is None:
+                return
+            _record(name, _perf_counter() - duration, float(duration),
+                    {"fun": kwargs.get("fun_name")})
+            if name == "jit.compile":
+                compiles.inc()
+                seconds.inc(float(duration))
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        _compile_watched = True
+        return True
 
 
 def iter_jsonl(path: str) -> Iterator[dict]:

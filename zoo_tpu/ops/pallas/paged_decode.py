@@ -264,6 +264,7 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
                                  jnp.float32),
         ],
         interpret=interpret,
+        name="zoo_paged_decode",
     )(bt, pos, *operands)
 
     # split-KV epilogue: merge the per-split partial softmaxes with the

@@ -213,6 +213,7 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
             jax.ShapeDtypeStruct((S, n_kv, rows_p, D), q.dtype),
         ],
         interpret=interpret,
+        name="zoo_paged_prefill",
     )(bt, last, *operands)
     out = out[:, :, :rows].reshape(S, n_kv, C, group, D)
     return out.transpose(0, 2, 1, 3, 4).reshape(S, C, H, D)

@@ -26,6 +26,7 @@ import numpy as np
 
 from zoo_tpu.common.context import get_runtime_context
 from zoo_tpu.obs.metrics import counter as _obs_counter
+from zoo_tpu.obs.tracing import emit_span, span, watch_compiles
 from zoo_tpu.pipeline.api.keras.engine.base import KTensor, Layer
 from zoo_tpu.pipeline.api.keras.engine import data_utils
 from zoo_tpu.pipeline.api.keras.metrics import Metric, get_metric
@@ -167,6 +168,7 @@ class KerasNet:
         inherit unchanged)."""
         if dtype_policy not in ("float32", "mixed_bfloat16"):
             raise ValueError(f"unknown dtype_policy: {dtype_policy}")
+        watch_compiles()    # a recompile inside fit gets a name and a cost
         from zoo_tpu.common import knobs as _knobs
         plan = plan or _knobs.value("ZOO_PLAN")
         if plan != "auto":
@@ -705,6 +707,7 @@ class KerasNet:
                 "inference-only; re-load the float checkpoint to train")
         if self.loss_fn is None:
             raise RuntimeError("call compile() before fit()")
+        t_fit = time.perf_counter()
         xs, ys = data_utils.to_xy_arrays(x, y, feature_cols, label_cols)
         xs = self._adapt_inputs(xs)
         if ys is None:
@@ -965,11 +968,10 @@ class KerasNet:
             from zoo_tpu.orca.data.ingest import StagingBufferPool
             staging_pool = StagingBufferPool.maybe_create(
                 arrs, rows=group * local_bs)
-        for epoch in range(nb_epoch):
-            t0 = time.perf_counter()  # monotonic: NTP-step-proof Throughput
-            loss_sum, n_steps = None, 0
-            gb["loss"], gb["steps"] = 0.0, 0  # per-epoch loss baselines
-            gb["bad0"] = gb["bad"]
+        def _launch_epoch(epoch):
+            """Dispatch every step of one epoch (the whole-epoch
+            executable, or the staged superbatch loop)."""
+            nonlocal params, opt_state, rng, loss_sum, n_steps
             if use_epoch:
                 kk = n // local_bs
                 # mesh identity in the key: the built closure bakes the
@@ -1154,26 +1156,10 @@ class KerasNet:
                                 _guard_boundary(epoch)
                 finally:
                     batches.close()
-            if guard is not None:
-                _guard_boundary(epoch, final=True)
-                # skipped steps contributed 0 to the sanitized loss sum;
-                # keep them out of the mean too
-                denom = max(n_steps - max(0, gb["bad"] - gb["bad0"]), 1)
-            else:
-                denom = max(n_steps, 1)
-            if guard is not None and loss_sum is None:
-                # a mid-epoch rollback wiped every step of this epoch:
-                # the epoch effectively did not run and there is no
-                # honest loss to report. Raise the typed error the
-                # Estimator's retry perimeter turns into "restore the
-                # verified checkpoint and retrain the lost epoch" —
-                # the guard ladder's designed endWhen semantics
-                from zoo_tpu.orca.learn.guard import EpochRolledBack
-                raise EpochRolledBack(
-                    f"{self.name}: guard rollback wiped every step of "
-                    f"epoch {epoch + 1}; retrain it from the restored "
-                    "checkpoint")
-            epoch_loss = float(np.asarray(loss_sum)) / denom
+
+        def _epoch_host(epoch, epoch_loss, t0):
+            """The host's work between two epochs: summaries,
+            validation, plateau, printing."""
             from zoo_tpu.common.context import ZooContext
             if ZooContext.debug_nans and not np.isfinite(epoch_loss):
                 raise FloatingPointError(
@@ -1237,7 +1223,52 @@ class KerasNet:
                 print(f"Epoch {epoch + 1}/{nb_epoch} - loss: "
                       f"{epoch_loss:.4f}" +
                       "".join(f" - {k}: {v:.4f}" for k, v in extra.items()))
-        self.params = jax.device_get(params) if mesh is None else params
+
+        # placement, building or fetching the step, staging: recorded
+        # after the fact (ring and JSONL, no profiler annotation) so the
+        # 270 lines above need no span to unwind on a bad argument
+        d_setup = time.perf_counter() - t_fit
+        emit_span("fit.setup", time.time() - d_setup, d_setup, t0=t_fit)
+        for epoch in range(nb_epoch):
+            with span("fit.epoch"):
+                t0 = time.perf_counter()  # monotonic: NTP-proof Throughput
+                loss_sum, n_steps = None, 0
+                gb["loss"], gb["steps"] = 0.0, 0  # per-epoch baselines
+                gb["bad0"] = gb["bad"]
+                with span("fit.epoch.launch"):
+                    _launch_epoch(epoch)
+                # the epoch's one block on the device: its loss sum comes
+                # to the host (with a guard, the guard's counters first)
+                with span("fit.epoch.loss_sync"):
+                    if guard is not None:
+                        _guard_boundary(epoch, final=True)
+                        # skipped steps contributed 0 to the sanitized
+                        # loss sum; keep them out of the mean too
+                        denom = max(
+                            n_steps - max(0, gb["bad"] - gb["bad0"]), 1)
+                    else:
+                        denom = max(n_steps, 1)
+                    if guard is not None and loss_sum is None:
+                        # a mid-epoch rollback wiped every step of this
+                        # epoch: the epoch effectively did not run and
+                        # there is no honest loss to report. Raise the
+                        # typed error the Estimator's retry perimeter
+                        # turns into "restore the verified checkpoint and
+                        # retrain the lost epoch" — the guard ladder's
+                        # designed endWhen semantics
+                        from zoo_tpu.orca.learn.guard import (
+                            EpochRolledBack,
+                        )
+                        raise EpochRolledBack(
+                            f"{self.name}: guard rollback wiped every "
+                            f"step of epoch {epoch + 1}; retrain it from "
+                            "the restored checkpoint")
+                    epoch_loss = float(np.asarray(loss_sum)) / denom
+                with span("fit.epoch.host"):
+                    _epoch_host(epoch, epoch_loss, t0)
+        with span("fit.params_to_host"):
+            self.params = jax.device_get(params) if mesh is None \
+                else params
         if guard is not None:
             opt_state = opt_state[0]  # shed the guard counters
         self._opt_state = opt_state

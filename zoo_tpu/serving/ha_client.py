@@ -605,7 +605,8 @@ class HAServingClient:
                 def att_span(outcome: str, ok: bool):
                     emit_span("client.attempt", t0w,
                               time.perf_counter() - t0, trace=tid,
-                              parent=root_sid, ok=ok, outcome=outcome,
+                              parent=root_sid, ok=ok, t0=t0,
+                              outcome=outcome,
                               endpoint=f"{ep.host}:{ep.port}",
                               hedge=is_hedge,
                               resume_from=att["resume_from"])
@@ -892,7 +893,8 @@ class HAServingClient:
             exc = sys.exc_info()[1]
             emit_span("client.generate", t_req_wall,
                       time.perf_counter() - t_req, trace=tid,
-                      span_id=root_sid, ok=exc is None, rid=rid,
+                      span_id=root_sid, ok=exc is None, t0=t_req,
+                      rid=rid,
                       tokens=received, attempts=len(attempts),
                       hedged=hedged)
 
@@ -1159,7 +1161,7 @@ class HAServingClient:
         def root_span(outcome: str, ok: bool):
             emit_span("client.rpc", t_req_wall,
                       time.perf_counter() - t_req, trace=tid,
-                      span_id=root_sid, ok=ok, op="predict",
+                      span_id=root_sid, ok=ok, t0=t_req, op="predict",
                       outcome=outcome, rid=msg.get("id"))
 
         try:
@@ -1219,7 +1221,7 @@ class HAServingClient:
                                   time.perf_counter() - t0,
                                   trace=msg["trace"],
                                   parent=msg.get("pspan"), ok=ok,
-                                  outcome=outcome,
+                                  t0=t0, outcome=outcome,
                                   endpoint=f"{ep.host}:{ep.port}")
 
                 try:

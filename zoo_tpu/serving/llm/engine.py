@@ -77,6 +77,7 @@ scheduler tests run against a pure-python fake without importing jax.
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import queue as _queue
 import threading
@@ -88,7 +89,12 @@ import numpy as np
 
 from zoo_tpu.obs.flight import record_event
 from zoo_tpu.obs.metrics import counter, gauge, histogram
-from zoo_tpu.obs.tracing import emit_event, emit_span
+from zoo_tpu.obs.tracing import (
+    emit_event,
+    emit_span,
+    span,
+    watch_compiles,
+)
 from zoo_tpu.serving.llm.kv_cache import (
     BlockAllocator,
     prefix_block_hashes,
@@ -212,6 +218,19 @@ _tenant_slots = gauge(
     "zoo_tenant_decode_slots",
     "Decode slots held per tenant right now", labels=("tenant",))
 
+#: The scheduler thread's LEAF spans (docs/observability.md "The span
+#: catalogue"): disjoint, and together they cover one pass of the loop,
+#: so a device-idle gap under the scheduler always has one name. The
+#: ``llm.model.*`` spans nest inside ``prefill`` / ``dispatch``; the
+#: two ``llm.readback.*`` spans are the readback thread's under
+#: overlap and the scheduler's own on the synchronous path.
+TICK_LEAF_SPANS = (
+    "llm.tick.lock_wait", "llm.tick.sweep_admit", "llm.tick.prefill",
+    "llm.tick.grow_build", "llm.tick.inflight_wait", "llm.tick.dispatch",
+    "llm.tick.idle", "llm.tick.reseed",
+    "llm.readback.device", "llm.readback.apply",
+)
+
 
 class AdmissionError(RuntimeError):
     """Retryable door rejection (waiting queue full, or the tenant's
@@ -327,6 +346,7 @@ class GenHandle:
         self.last_token_at: Optional[float] = None
         self.admitted_at: Optional[float] = None
         self.preempts = 0
+        self.prefill_chunks = 0   # prefill ticks this stream was fed in
         self.cancelled = threading.Event()
         self._cond = threading.Condition()
         self._subs = 0  # live server-side stream loops on this handle
@@ -394,7 +414,8 @@ class GenHandle:
                      tenant=self.tenant or None, error=error)
         emit_span("llm.stream", self.created_wall, now - self.created,
                   trace=self.trace_id, parent=self.parent_span,
-                  ok=outcome == "ok", rid=self.id, outcome=outcome,
+                  ok=outcome == "ok", t0=self.created, rid=self.id,
+                  outcome=outcome,
                   tokens=len(self.tokens), preempts=self.preempts,
                   tenant=self.tenant or None)
 
@@ -491,6 +512,7 @@ class LLMEngine:
                  tenancy=None):
         if mode not in ("continuous", "oneshot"):
             raise ValueError(f"unknown scheduling mode {mode!r}")
+        watch_compiles()    # no-op for the jax-free synthetic model
         self.model = model
         self.mode = mode
         # disaggregated serving (docs/disaggregated_serving.md): the
@@ -1002,6 +1024,7 @@ class LLMEngine:
             self._admit_counter += 1
             h.admit_seq = self._admit_counter
             h.admitted_at = time.perf_counter()
+            self._span_queue_wait(h, len(prompt))
             self._note_served(h, len(prompt) - h.cache_hit_tokens)
             emit_event("llm.admit", trace=h.trace_id,
                        parent=h.parent_span, rid=h.id,
@@ -1022,6 +1045,21 @@ class LLMEngine:
         if self.tenancy.enabled:
             self._preempt_for_class()
         self._publish()
+
+    @staticmethod
+    def _span_queue_wait(h: GenHandle, prompt_tokens: int):
+        """``llm.queue_wait``: created -> this admission, while the
+        stream still owes its first token — with ``llm.prefill_total``
+        the two halves of its TTFT. (A stream preempted before its
+        first token is re-admitted and records again, from ``created``:
+        the LAST record is the one that sums to the TTFT.)"""
+        if h.first_token_at is None:
+            wait = h.admitted_at - h.created
+            emit_span("llm.queue_wait", time.time() - wait, wait,
+                      trace=h.trace_id, parent=h.parent_span,
+                      t0=h.created, rid=h.id,
+                      prompt_tokens=int(prompt_tokens),
+                      preempts=h.preempts)
 
     def _note_served(self, h: GenHandle, n: int):
         """Charge ``n`` tokens of service to the stream's tenant — the
@@ -1127,7 +1165,15 @@ class LLMEngine:
         slot.use_host = True
         emit_event("llm.first_token", trace=h.trace_id,
                    parent=h.parent_span, rid=h.id)
+        owed = h.first_token_at is None
         h.push(first)
+        if owed and h.admitted_at is not None:
+            took = h.first_token_at - h.admitted_at
+            emit_span("llm.prefill_total", time.time() - took, took,
+                      trace=h.trace_id, parent=h.parent_span,
+                      t0=h.admitted_at, rid=h.id,
+                      prompt_tokens=int(prompt_len),
+                      chunks=h.prefill_chunks, preempts=h.preempts)
         h.gen_count += 1
         h.sched_count += 1
         self._generated += 1
@@ -1276,6 +1322,7 @@ class LLMEngine:
         self._admit_counter += 1
         h.admit_seq = self._admit_counter
         h.admitted_at = time.perf_counter()
+        self._span_queue_wait(h, len(prompt))
         self._handoffs_in += 1
         emit_event("llm.admit", trace=h.trace_id,
                    parent=h.parent_span, rid=h.id,
@@ -1379,9 +1426,10 @@ class LLMEngine:
             dur = time.perf_counter() - t0
             _tick_seconds.labels(phase="prefill").observe(dur)
             _tokens.labels(kind="prefill").inc(take)
+            h.prefill_chunks += 1
             emit_span("llm.prefill", t0_wall, dur, trace=h.trace_id,
-                      parent=h.parent_span, rid=h.id, start=int(start),
-                      tokens=int(take), total=int(n))
+                      parent=h.parent_span, t0=t0, rid=h.id,
+                      start=int(start), tokens=int(take), total=int(n))
             results.append((slot, h, epoch, start, take, n, tok, None))
         return results
 
@@ -1406,14 +1454,16 @@ class LLMEngine:
         """One tick of prompt feeding: long prompts advance a chunk per
         tick while every live stream keeps decoding — the anti-stall
         the chunk executable exists for. Lock is held only around the
-        claim and the apply, never across the device."""
+        claim and the apply, never across the device. Returns the
+        number of prompt chunks it ran."""
         with self._lock:
             work = self._select_prefill()
         if not work:
-            return
+            return 0
         results = self._run_prefill(work)
         with self._lock:
             self._apply_prefill(results)
+        return len(work)
 
     def _table_row(self, blocks: Sequence[int]) -> np.ndarray:
         row = np.zeros((self.model.max_blocks_per_seq,), np.int32)
@@ -1746,16 +1796,18 @@ class LLMEngine:
         """The SYNCHRONOUS verify tick (overlap-off runs, oneshot
         baseline, white-box tests): build, dispatch, block on
         readback, apply inline."""
-        with self._lock:
+        with self._held("llm.tick.grow_build"):
             built = self._build_spec_tick()
         if built is None:
             return False
         tokens, tables, positions, lanes, snapshot = built
         t0 = time.perf_counter()
         try:
-            batch = self.model.verify_step(tokens, tables, positions,
-                                           lanes)
-            arr = self.model.read_tokens(batch)
+            with span("llm.tick.dispatch"):
+                batch = self.model.verify_step(tokens, tables,
+                                               positions, lanes)
+            with span("llm.readback.device"):
+                arr = self.model.read_tokens(batch)
         except Exception as e:  # noqa: BLE001 — lost verify lanes end
             # their streams loudly, same contract as a decode tick
             with self._lock:
@@ -1763,12 +1815,13 @@ class LLMEngine:
                                   in snapshot], e)
             return True
         t1 = time.perf_counter()
+        self._span_tick_decode(t0, t1)
         _tick_seconds.labels(phase="decode").observe(t1 - t0)
         self._note_busy(t0, t1)
         self._decode_steps += 1
         _steps.inc()
         self._tick_flight()
-        with self._lock:
+        with self._applying():
             self._apply_spec(snapshot, np.asarray(arr))
         _tick_seconds.labels(phase="readback").observe(
             time.perf_counter() - t1)
@@ -1778,7 +1831,7 @@ class LLMEngine:
         """The SYNCHRONOUS tick (request-level baseline, overlap-off
         runs, and white-box tests): host-fed lanes, blocking readback,
         apply inline."""
-        with self._lock:
+        with self._held("llm.tick.grow_build"):
             built = self._build_tick(device_chain=False)
         if built is None:
             return False
@@ -1786,11 +1839,15 @@ class LLMEngine:
         t0 = time.perf_counter()
         try:
             if hasattr(self.model, "decode_step"):
-                batch = self.model.decode_step(None, host, use, tables,
-                                               positions, lanes)
-                arr = self.model.read_tokens(batch)
+                with span("llm.tick.dispatch"):
+                    batch = self.model.decode_step(
+                        None, host, use, tables, positions, lanes)
+                with span("llm.readback.device"):
+                    arr = self.model.read_tokens(batch)
             else:
-                arr = self.model.decode(host, tables, positions, lanes)
+                with span("llm.tick.dispatch"):
+                    arr = self.model.decode(host, tables, positions,
+                                            lanes)
         except Exception as e:  # noqa: BLE001 — same contract as the
             # overlap pipeline: lost tokens end their streams loudly
             # instead of leaving a silent hole + wedged slot
@@ -1798,16 +1855,69 @@ class LLMEngine:
                 self._fail_lanes(snapshot, e)
             return True
         t1 = time.perf_counter()
+        self._span_tick_decode(t0, t1)
         _tick_seconds.labels(phase="decode").observe(t1 - t0)
         self._note_busy(t0, t1)
         self._decode_steps += 1
         _steps.inc()
         self._tick_flight()
-        with self._lock:
+        with self._applying():
             self._apply_tokens(snapshot, arr)
         _tick_seconds.labels(phase="readback").observe(
             time.perf_counter() - t1)
         return True
+
+    # -- the loop's own spans (docs/observability.md) ----------------------
+    @contextlib.contextmanager
+    def _held(self, leaf: str):
+        """``with self._lock`` for the scheduler loop, the wait for the
+        lock (``llm.tick.lock_wait``) timed apart from what runs under
+        it (``leaf``): a scheduler stalled behind the readback thread's
+        apply reads differently from one doing its own work."""
+        with span("llm.tick.lock_wait"):
+            self._lock.acquire()
+        try:
+            with span(leaf):
+                yield
+        finally:
+            self._lock.release()
+
+    @contextlib.contextmanager
+    def _applying(self):
+        """``with self._lock`` round the landing of a ready batch, as
+        one ``llm.readback.apply`` span whose ``lock_wait_s`` says how
+        much of it was the wait for the scheduler to let go."""
+        sp = span("llm.readback.apply")
+        with sp:
+            t = time.perf_counter()
+            with self._lock:
+                sp.note(lock_wait_s=time.perf_counter() - t)
+                yield
+
+    def _timed_prefill_tick(self):
+        """The loop's ``_prefill_tick()`` as its ``llm.tick.prefill``
+        leaf (its two short lock holds included), ``chunks`` = prompt
+        chunks run."""
+        sp = span("llm.tick.prefill")
+        with sp:
+            sp.note(chunks=self._prefill_tick())
+
+    @staticmethod
+    def _span_tick_decode(t_dispatch: float, t_ready: float):
+        """``llm.tick.decode``: one tick from its dispatch to its
+        tokens on the host — the value the ``decode`` phase of
+        ``zoo_llm_tick_seconds`` buckets, unbucketed."""
+        dur = t_ready - t_dispatch
+        emit_span("llm.tick.decode", time.time() - dur, dur,
+                  t0=t_dispatch)
+
+    def _span_tick_schedule(self, t0: float, dur: float):
+        """``llm.tick.schedule``: the host's scheduling work of one
+        pass (sweep/admit + grow/build, prefill excluded) — what the
+        ``schedule`` phase of ``zoo_llm_tick_seconds`` buckets."""
+        _tick_seconds.labels(phase="schedule").observe(dur)
+        emit_span("llm.tick.schedule",
+                  time.time() - (time.perf_counter() - t0), dur, t0=t0)
 
     # -- overlap pipeline --------------------------------------------------
     def _note_busy(self, t_start: float, t_ready: float):
@@ -1842,7 +1952,8 @@ class LLMEngine:
                 return
             kind, batch, snapshot, t_dispatch = item
             try:
-                arr = self.model.read_tokens(batch)
+                with span("llm.readback.device"):
+                    arr = self.model.read_tokens(batch)
             except Exception as e:  # noqa: BLE001 — these lanes'
                 # tokens are gone (and the donated-cache chain may be
                 # poisoned): end the streams loudly and tell the
@@ -1855,10 +1966,11 @@ class LLMEngine:
                 self._wake.set()
                 continue
             t_ready = time.perf_counter()
+            self._span_tick_decode(t_dispatch, t_ready)
             _tick_seconds.labels(phase="decode").observe(
                 t_ready - t_dispatch)
             self._note_busy(t_dispatch, t_ready)
-            with self._lock:
+            with self._applying():
                 if kind == "spec":
                     self._apply_spec(snapshot, np.asarray(arr))
                 else:
@@ -1885,7 +1997,7 @@ class LLMEngine:
         prev_batch = None
         try:
             while not self._stop.is_set():
-                with self._lock:
+                with span("llm.tick.lock_wait"), self._lock:
                     broken = self._chain_broken
                 if broken:
                     # drain the pipeline first — every still-in-flight
@@ -1894,48 +2006,51 @@ class LLMEngine:
                     # then re-seed the SURVIVING decode slots (streams
                     # never in a failed batch) from their last APPLIED
                     # token and restart the device chain from host state
-                    grabbed = 0
-                    while grabbed < 2 and not self._stop.is_set():
-                        if self._inflight.acquire(timeout=0.5):
-                            grabbed += 1
-                    with self._lock:
-                        self._chain_broken = False
-                        for slot in self._slots:
-                            if slot.handle is not None and \
-                                    slot.phase == "decode":
-                                slot.use_host = True
-                                slot.host_token = slot.last_token
-                    prev_batch = None
-                    for _ in range(grabbed):
-                        self._inflight.release()
+                    with span("llm.tick.reseed"):
+                        grabbed = 0
+                        while grabbed < 2 and not self._stop.is_set():
+                            if self._inflight.acquire(timeout=0.5):
+                                grabbed += 1
+                        with self._lock:
+                            self._chain_broken = False
+                            for slot in self._slots:
+                                if slot.handle is not None and \
+                                        slot.phase == "decode":
+                                    slot.use_host = True
+                                    slot.host_token = slot.last_token
+                        prev_batch = None
+                        for _ in range(grabbed):
+                            self._inflight.release()
                     if self._stop.is_set():
                         return
                 t0 = time.perf_counter()
-                with self._lock:
+                with self._held("llm.tick.sweep_admit"):
                     self._sweep()
                     self._admit()
                 t1 = time.perf_counter()
                 # device prefill runs UNLOCKED: submissions and token
                 # readback keep flowing while a long prompt feeds
-                self._prefill_tick()
+                self._timed_prefill_tick()
                 t2 = time.perf_counter()
-                with self._lock:
+                with self._held("llm.tick.grow_build"):
                     self._grow_or_preempt()
                     built = self._build_spec_tick() if self._spec \
                         else self._build_tick(device_chain=True)
-                _tick_seconds.labels(phase="schedule").observe(
-                    (t1 - t0) + (time.perf_counter() - t2))
+                self._span_tick_schedule(
+                    t0, (t1 - t0) + (time.perf_counter() - t2))
                 if built is None:
                     # no decodable lane: break the device token chain
                     # (every post-idle admission is host-fed anyway)
                     prev_batch = None
-                    self._wake.wait(0.005)
+                    with span("llm.tick.idle"):
+                        self._wake.wait(0.005)
                     self._wake.clear()
                     continue
                 # bound the pipeline depth: at most 2 ticks in flight
-                while not self._inflight.acquire(timeout=0.5):
-                    if self._stop.is_set():
-                        return
+                with span("llm.tick.inflight_wait"):
+                    while not self._inflight.acquire(timeout=0.5):
+                        if self._stop.is_set():
+                            return
                 t_d = time.perf_counter()
                 if self._spec:
                     # verify batches are host-fed (the accept length
@@ -1952,8 +2067,9 @@ class LLMEngine:
                     # mid-pass form a second in-flight batch.
                     tokens, tables, positions, lanes, snapshot = built
                     try:
-                        batch = self.model.verify_step(
-                            tokens, tables, positions, lanes)
+                        with span("llm.tick.dispatch"):
+                            batch = self.model.verify_step(
+                                tokens, tables, positions, lanes)
                     except Exception as e:  # noqa: BLE001
                         with self._lock:
                             self._fail_lanes([(i, h, ep) for i, h, ep,
@@ -1965,8 +2081,10 @@ class LLMEngine:
                     continue
                 host, use, tables, positions, lanes, snapshot = built
                 try:
-                    prev_batch = self.model.decode_step(
-                        prev_batch, host, use, tables, positions, lanes)
+                    with span("llm.tick.dispatch"):
+                        prev_batch = self.model.decode_step(
+                            prev_batch, host, use, tables, positions,
+                            lanes)
                 except Exception as e:  # noqa: BLE001 — consuming a
                     # poisoned prev batch / cache raises here; fail the
                     # built lanes loudly and re-seed instead of letting
@@ -1984,16 +2102,16 @@ class LLMEngine:
     def _loop_sync(self):
         while not self._stop.is_set():
             t0 = time.perf_counter()
-            with self._lock:
+            with self._held("llm.tick.sweep_admit"):
                 self._sweep()
                 self._admit()
             t1 = time.perf_counter()
-            self._prefill_tick()
+            self._timed_prefill_tick()
             t2 = time.perf_counter()
-            with self._lock:
+            with self._held("llm.tick.grow_build"):
                 self._grow_or_preempt()
-            _tick_seconds.labels(phase="schedule").observe(
-                (t1 - t0) + (time.perf_counter() - t2))
+            self._span_tick_schedule(
+                t0, (t1 - t0) + (time.perf_counter() - t2))
             progressed = self._spec_tick() if self._spec \
                 else self._decode_tick()
             if not progressed:
@@ -2001,7 +2119,8 @@ class LLMEngine:
                 # KV-gated (head cannot be admitted yet): without the
                 # sleep that state busy-spins a core. submit() sets
                 # _wake, so a fresh request still admits immediately.
-                self._wake.wait(0.005)
+                with span("llm.tick.idle"):
+                    self._wake.wait(0.005)
                 self._wake.clear()
 
     def _loop(self):

@@ -90,6 +90,7 @@ from zoo_tpu.models.llm.llama import (
 )
 from zoo_tpu.common.knobs import value as knob_value
 from zoo_tpu.obs.metrics import counter
+from zoo_tpu.obs.tracing import span
 from zoo_tpu.ops.attention import dot_product_attention
 from zoo_tpu.util.quantize import absmax_scale, narrow_int8
 
@@ -237,6 +238,7 @@ def _slot_keys(seeds: jnp.ndarray, token_index: jnp.ndarray):
     return jax.vmap(jax.random.fold_in)(base, token_index)
 
 
+@jax.named_scope("zoo.sample")
 def _sample_row(logits, temp, topk, topp, seed, token_index):
     """Single-row sampling for the prefill executables' first generated
     token: same greedy ``lax.cond`` fast path as the decode batch."""
@@ -250,6 +252,7 @@ def _sample_row(logits, temp, topk, topp, seed, token_index):
         lambda _: jnp.argmax(logits).astype(jnp.int32), None)
 
 
+@jax.named_scope("zoo.sample")
 def _sample_tokens(logits, temps, topks, topps, seeds, token_index):
     """(S, vocab) logits -> (S,) int32 ids, all lanes independent.
 
@@ -496,6 +499,7 @@ class PagedLlamaModel:
             cache["ks"], cache["vs"] = ys[2], ys[3]
         return cache
 
+    @jax.named_scope("zoo.kv_append")
     def _append_rows(self, cachel, scalel, blk, off, x):
         """Write f32 K or V rows ``x`` (..., n_kv, D) through the block
         table at (blk, off), quantizing per the cache dtype: int8 rows
@@ -538,6 +542,7 @@ class PagedLlamaModel:
                 for name, arr in cache.items()}
 
     # -- compiled bodies ---------------------------------------------------
+    @jax.named_scope("zoo.attn_proj")
     def _attn_proj(self, p, x):
         """Shared q/k/v projection + head split for every executable."""
         c = self.cfg
@@ -546,12 +551,14 @@ class PagedLlamaModel:
         v = (x @ p["wv"]).reshape(*x.shape[:-1], c.n_kv_head, c.head_dim)
         return q, k, v
 
+    @jax.named_scope("zoo.mlp")
     def _mlp(self, p, h):
         c = self.cfg
         x = _rms_norm(h, p["mlp_norm"], c.rms_eps)
         return h + (jax.nn.silu(x @ p["w_gate"])
                     * (x @ p["w_up"])) @ p["w_down"]
 
+    @jax.named_scope("zoo.lm_head")
     def _lm_head(self, params, h):
         c = self.cfg
         h = _rms_norm(h, params["final_norm"], c.rms_eps)
@@ -583,6 +590,7 @@ class PagedLlamaModel:
             out_specs=q_spec, check_vma=False,
         )(q, kcl, vcl, block_tables, positions, *scales)
 
+    @jax.named_scope("zoo.paged_attend")
     def _paged_attend(self, q, kcl, vcl, ksl, vsl, block_tables,
                       positions):
         """Single-query attention over the paged cache: (S, H, D) q
@@ -617,6 +625,7 @@ class PagedLlamaModel:
         vals = self._widen_gather(vcl, vsl, block_tables)
         return self._masked_gather_attention(q, keys, vals, live)
 
+    @jax.named_scope("zoo.paged_attend")
     def _prefill_attend(self, q, kcl, vcl, ksl, vsl, block_tables,
                         positions):
         """Chunk-of-rows attention over the resident paged cache:
@@ -935,20 +944,25 @@ class PagedLlamaModel:
         n = int(chunk.shape[0])
         if n < 1 or n > C:
             raise ValueError(f"chunk of {n} tokens (chunk size {C})")
-        ids = np.zeros((1, C), np.int32)
-        ids[0, :n] = chunk
-        bt = np.asarray(block_table_row, np.int32)
-        if bt.shape != (self.max_blocks_per_seq,):
-            raise ValueError("block_table_row has the wrong width")
-        t, k, p, s = self._sampling_tuple(sampling)
-        with self._lock:
-            tok, self._cache = self._prefill_chunked(
-                self.params, self._cache, jnp.asarray(ids),
-                jnp.int32(start), jnp.int32(total_len), jnp.asarray(bt),
-                jnp.float32(t), jnp.int32(k), jnp.float32(p),
-                jnp.uint32(s))
-            out = int(tok)
-        _host_transfer.labels(kind="prefill").inc(4)
+        with span("llm.model.prefill_chunk"):
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :n] = chunk
+            bt = np.asarray(block_table_row, np.int32)
+            if bt.shape != (self.max_blocks_per_seq,):
+                raise ValueError("block_table_row has the wrong width")
+            t, k, p, s = self._sampling_tuple(sampling)
+            with self._lock:
+                with span("llm.model.prefill_launch"):
+                    tok, self._cache = self._prefill_chunked(
+                        self.params, self._cache, jnp.asarray(ids),
+                        jnp.int32(start), jnp.int32(total_len),
+                        jnp.asarray(bt), jnp.float32(t), jnp.int32(k),
+                        jnp.float32(p), jnp.uint32(s))
+                # the scheduler thread blocks here until the chunk has
+                # run: the one host sync of the prefill path
+                with span("llm.model.prefill_sync"):
+                    out = int(tok)
+            _host_transfer.labels(kind="prefill").inc(4)
         return out
 
     def copy_block(self, src: int, dst: int):
@@ -1016,17 +1030,20 @@ class PagedLlamaModel:
         with self._lock:
             if prev_batch is None:
                 prev_batch = self._zero_tokens
-            out, self._cache = self._decode(
-                self.params, self._cache,
-                jnp.asarray(prev_batch, jnp.int32),
-                jnp.asarray(host_tokens, jnp.int32),
-                jnp.asarray(use_host, bool),
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(temps, jnp.float32),
-                jnp.asarray(topks, jnp.int32),
-                jnp.asarray(topps, jnp.float32),
-                jnp.asarray(seeds, jnp.uint32))
+            with span("llm.model.h2d"):
+                operands = (
+                    jnp.asarray(prev_batch, jnp.int32),
+                    jnp.asarray(host_tokens, jnp.int32),
+                    jnp.asarray(use_host, bool),
+                    jnp.asarray(block_tables, jnp.int32),
+                    jnp.asarray(positions, jnp.int32),
+                    jnp.asarray(temps, jnp.float32),
+                    jnp.asarray(topks, jnp.int32),
+                    jnp.asarray(topps, jnp.float32),
+                    jnp.asarray(seeds, jnp.uint32))
+            with span("llm.model.launch"):
+                out, self._cache = self._decode(
+                    self.params, self._cache, *operands)
             return out
 
     def verify_step(self, tokens: np.ndarray,

@@ -701,7 +701,7 @@ class ServingServer:
                             req.t_dequeue - t0, 6)
                     emit_span("server.predict", t0_wall, dur,
                               trace=req.trace, parent=req.pspan,
-                              ok=outcome == "ok", **attrs)
+                              ok=outcome == "ok", t0=t0, **attrs)
 
             def _handle_generate(self, msg):
                 """Streaming autoregressive generation
@@ -919,7 +919,7 @@ class ServingServer:
                                   time.perf_counter() - t_stream,
                                   trace=trace_id,
                                   parent=msg.get("pspan"),
-                                  ok=completed, rid=rid,
+                                  ok=completed, t0=t_stream, rid=rid,
                                   resume_from=resume_from,
                                   sent_tokens=cursor - resume_from,
                                   outcome=h.outcome if completed

@@ -990,10 +990,10 @@ def bench_llm_serving(extra, n_requests=24, long_tokens=96,
     # The per-token cache cost comes from the model's OWN byte
     # accounting (kv_bytes_per_token: K+V rows across layers at the
     # active cache dtype, plus int8 scale rows), so the same formula
-    # prices every ZOO_LLM_KV_DTYPE.
-    from zoo_tpu.models.llm.llama import llama_param_count
+    # prices every ZOO_LLM_KV_DTYPE; the weights likewise cost what the
+    # model holds them as (bf16 dot leaves on a TPU).
     avg_live = 4 + 64 / 2  # prompt + half the generated length
-    weight_bytes = llama_param_count(cfg) * 4 / S
+    weight_bytes = model.weight_bytes / S
 
     def roofline_bytes(m):
         return m.kv_bytes_per_token * (avg_live + 1) + weight_bytes
@@ -1260,7 +1260,7 @@ def bench_llm_serving(extra, n_requests=24, long_tokens=96,
         resident = sum(min(plen, (i + 1) * chunk)
                        for i in range(n_chunks))
         per_prompt = (mp.kv_bytes_per_token * (resident + plen)
-                      + llama_param_count(cfg) * 4 * n_chunks)
+                      + mp.weight_bytes * n_chunks)
         return (n_prompts * plen / wall,
                 n_prompts * per_prompt / wall / 1e9,
                 mp.prefill_attention_impl)

@@ -361,6 +361,10 @@ def leg_serve(sz: Sizes, platform: str, rehearsal: bool) -> str:
     streams, st = _serve(bucketed, plan_a, prompts, sz.max_new, "flash",
                          platform, **pick)
     assert st["kv_cache_dtype"] == "f32" and st["prefill_chunk"] == 0, st
+    # the dot weights are held as the device's dot reads them: bf16 on
+    # the chip, as built on the CPU
+    assert st["weight_dtype"] == ("float32" if rehearsal
+                                  else "bfloat16"), st["weight_dtype"]
     assert st["spec_proposed_tokens"] > 0, \
         "the drafter proposed nothing on a tiled motif"
     assert st["prefix_hit_tokens"] > 0, "repeated prompt missed the cache"
@@ -392,7 +396,8 @@ def leg_serve(sz: Sizes, platform: str, rehearsal: bool) -> str:
     return (f"decode_impl=flash prefill_impl=flash decode_compiles=1 "
             f"(int8 engine) verify_compiles=1 (f32 engine) "
             f"failed_streams=0 leaked_blocks=0 "
-            f"kv=f32+int8 streams={len(plan_a) + len(plan_b)} "
+            f"kv=f32+int8 weights={st['weight_dtype']} "
+            f"streams={len(plan_a) + len(plan_b)} "
             f"prefill_compiles={st['compiles']['prefill']} "
             f"spec_accepted={st['spec_accepted_tokens']}"
             f"/{st['spec_proposed_tokens']} "

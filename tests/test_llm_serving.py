@@ -205,6 +205,38 @@ def _drain(handles, budget=20.0):
         time.sleep(0.005)
 
 
+def test_engine_collects_young_garbage_when_it_drains(monkeypatch):
+    """The moment the last stream of a burst leaves (nothing active,
+    nothing waiting) the engine runs a young-generation collection —
+    never while a stream is live — so the collector's next pass does
+    not land in whoever allocates next."""
+    from zoo_tpu.serving.llm import engine as E
+    calls = []
+
+    eng = LLMEngine(_FakeModel(num_slots=2, num_blocks=32,
+                               max_blocks_per_seq=8)).start()
+
+    def collect(gen):
+        calls.append((gen, sum(1 for s in eng._slots if s.handle),
+                      len(eng._wait)))
+        return 0
+
+    monkeypatch.setattr(E.gc, "collect", collect)
+    try:
+        for burst in range(2):
+            seen = len(calls)
+            hs = [eng.submit(p, 4) for p in ([3, 5], [7], [1, 2, 3])]
+            _drain(hs)
+            deadline = time.monotonic() + 5.0
+            while len(calls) == seen and time.monotonic() < deadline:
+                time.sleep(0.005)
+            assert len(calls) > seen, "no collection after the burst"
+    finally:
+        eng.stop()
+    # generation 0, and only ever with the engine empty
+    assert set(calls) == {(0, 0, 0)}, calls
+
+
 def test_engine_continuous_more_streams_than_slots():
     eng = LLMEngine(_FakeModel(num_slots=2, num_blocks=32,
                                max_blocks_per_seq=8)).start()
@@ -1281,6 +1313,225 @@ def test_kv_dtype_resolution_and_bytes_model():
     assert bf16._kc.dtype == jnp.bfloat16
     assert i8._cache["ks"].shape == (c.n_block, i8.num_blocks,
                                      c.n_kv_head, i8.block_size)
+
+
+# ----------------------- weights held in the dtype the dot reads (PR 26)
+
+_NARROW_KW = dict(num_slots=2, block_size=4, num_blocks=24,
+                  max_blocks_per_seq=6, prefill_buckets=(8, 16))
+
+
+def _bf16_exact(params):
+    """The same tree, every value one that bf16 holds exactly (what a
+    bf16 checkpoint loaded as f32 is): narrowing it loses nothing, so
+    only the activations' rounding separates the two models."""
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16).astype(jnp.float32), params)
+
+
+@pytest.mark.parametrize("case", [
+    "tpu_f32", "tpu_bf16_tree", "tpu_tied", "cpu", "gpu",
+    "precision_highest", "precision_float32"])
+def test_narrow_dot_weights_rule(paged, case):
+    """The rule of docs/llm_serving.md "Weights": f32 dot leaves go
+    bf16 on a TPU at the platform's own matmul precision; norms,
+    ``embed`` (tied: the head too), an already-narrow leaf, every other
+    platform and a raised precision come back as the SAME objects."""
+    import contextlib
+    import jax
+    import jax.numpy as jnp
+    from zoo_tpu.serving.llm.model import (
+        DOT_BLOCK_LEAVES,
+        narrow_dot_weights,
+    )
+    _, model = paged
+    params = model.params
+    assert model.weight_dtype == "float32"      # CPU: held as handed in
+    assert set(DOT_BLOCK_LEAVES) < set(params["blocks"])
+
+    def same(a, b):
+        la, lb = (jax.tree_util.tree_leaves(t) for t in (a, b))
+        return len(la) == len(lb) and all(x is y for x, y in zip(la, lb))
+
+    platform, precision = "tpu", contextlib.nullcontext()
+    if case in ("cpu", "gpu"):
+        platform = case
+    elif case.startswith("precision_"):
+        precision = jax.default_matmul_precision(case.split("_", 1)[1])
+    elif case == "tpu_tied":
+        params = {k: v for k, v in params.items() if k != "head"}
+    elif case == "tpu_bf16_tree":
+        params = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), params)
+    with precision:
+        out = narrow_dot_weights(params, platform)
+    if case not in ("tpu_f32", "tpu_tied"):
+        assert same(out, params)
+        return
+    for name, leaf in params["blocks"].items():
+        got = out["blocks"][name]
+        if name in DOT_BLOCK_LEAVES:
+            assert got.dtype == jnp.bfloat16 and got.shape == leaf.shape
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(leaf.astype(jnp.bfloat16)))
+        else:
+            assert got is leaf                   # the two norm gains
+    assert out["embed"] is params["embed"]
+    assert out["final_norm"] is params["final_norm"]
+    if case == "tpu_tied":
+        assert "head" not in out
+    else:
+        assert out["head"].dtype == jnp.bfloat16
+    # the caller's tree is left as it was
+    assert all(leaf.dtype == jnp.float32 and not leaf.is_deleted()
+               for leaf in jax.tree_util.tree_leaves(params))
+    # with bf16 the platform's stated default, the rule still engages
+    with jax.default_matmul_precision("bfloat16"):
+        assert not same(narrow_dot_weights(params, "tpu"), params)
+
+
+@pytest.fixture(scope="module")
+def narrowed(paged):
+    """The shared geometry twice over bf16-exact weights: held f32 (as
+    every platform but the TPU holds them) and narrowed as the TPU
+    rule narrows them — the CPU then runs the helper's narrow branch."""
+    from zoo_tpu.serving.llm.model import (
+        PagedLlamaModel,
+        narrow_dot_weights,
+    )
+    cfg, model = paged
+    exact = _bf16_exact(model.params)
+    wide = PagedLlamaModel(cfg, params=exact, **_NARROW_KW)
+    narrow = PagedLlamaModel(cfg, params=narrow_dot_weights(exact, "tpu"),
+                             **_NARROW_KW)
+    assert (wide.weight_dtype, narrow.weight_dtype) == \
+        ("float32", "bfloat16")
+    return cfg, wide, narrow
+
+
+@pytest.mark.parametrize("variant", ["chunked", "flash", "spec"])
+def test_narrowed_executables_agree(narrowed, variant):
+    """On narrowed leaves the four executables still agree with one
+    another as their f32 parity tests demand: chunk prefill == bucket
+    prefill, flash decode == dense decode, verify == plain decode —
+    byte-identical streams, greedy and seeded."""
+    from zoo_tpu.serving.llm.model import PagedLlamaModel
+    cfg, _, narrow = narrowed
+    rs = np.random.RandomState(11)
+    motif = rs.randint(0, cfg.vocab, (4,))
+    prompts = [rs.randint(0, cfg.vocab, (3,)), np.tile(motif, 3),
+               rs.randint(0, cfg.vocab, (14,))]
+    rids = [f"nw-{i}" for i in range(len(prompts))]
+    samp = dict(temperature=0.9, top_k=16, top_p=0.95)
+    kw = dict(_NARROW_KW, **{
+        "chunked": dict(prefill_chunk=4),
+        "flash": dict(decode_impl="flash"),
+        "spec": dict(spec_k=2)}[variant])
+    other = PagedLlamaModel(cfg, params=narrow.params, **kw)
+    assert other.params["blocks"]["wq"] is narrow.params["blocks"]["wq"]
+    for sampling in (None, samp):
+        want = _generate_all(narrow, prompts, 7, sampling=sampling,
+                             rids=rids)
+        assert _generate_all(other, prompts, 7, sampling=sampling,
+                             rids=rids) == want
+    counts = other.compile_counts()
+    assert counts[{"chunked": "prefill_chunk", "flash": "decode",
+                   "spec": "verify"}[variant]] == 1, counts
+
+
+@pytest.mark.parametrize("which", ["prefill", "prefill_chunk", "decode"])
+def test_narrowed_logits_within_bf16_activation_rounding(
+        narrowed, which, monkeypatch):
+    """What narrowing costs where the device does NOT already round
+    (this CPU): the activations entering each weight dot drop to bf16
+    — 8 significant bits, a relative step of 2**-8 — and nothing else
+    moves, the weights being bf16-exact. A dot averages its terms'
+    errors and this geometry has 2 x 4 dots in series plus the head:
+    the logits stay within four steps (2**-6) of the f32 model's,
+    relative to the widest logit (they read 0.85-0.96 of ONE step)."""
+    import jax.numpy as jnp
+    from zoo_tpu.serving.llm import model as M
+    cfg, wide, narrow = narrowed
+    # the executables end in a sampler; hand the logits out instead
+    monkeypatch.setattr(M, "_sample_row", lambda last, *a: last)
+    monkeypatch.setattr(M, "_sample_tokens", lambda logits, *a: logits)
+    prompt = np.random.RandomState(5).randint(0, cfg.vocab, (8,))
+    row = np.array([1, 2, 3, 0, 0, 0], np.int32)
+    greedy = (jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
+              jnp.uint32(0))
+
+    def logits(m):
+        cache = {k: jnp.zeros_like(v) for k, v in m._cache.items()}
+        ids = jnp.asarray(prompt[None], jnp.int32)
+        if which == "prefill_chunk":
+            for start in (0, 4):       # two chunks of 4 through the cache
+                out, cache = m._prefill_chunk_fn(
+                    m.params, cache, ids[:, start:start + 4],
+                    jnp.int32(start), jnp.int32(8), jnp.asarray(row),
+                    *greedy)
+            return np.asarray(out)
+        out, cache = m._prefill_fn(m.params, cache, ids, jnp.int32(8),
+                                   jnp.asarray(row), *greedy)
+        if which == "prefill":
+            return np.asarray(out)
+        S = m.num_slots
+        tables = np.zeros((S, m.max_blocks_per_seq), np.int32)
+        tables[0] = row
+        lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
+                 jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
+        out, _ = m._decode_fn(
+            m.params, cache, jnp.zeros(S, jnp.int32),
+            jnp.full((S,), 7, jnp.int32), jnp.ones(S, bool),
+            jnp.asarray(tables), jnp.asarray([8, 0], jnp.int32), *lanes)
+        return np.asarray(out)[0]
+
+    ref, got = logits(wide), logits(narrow)
+    assert ref.shape == got.shape == (cfg.vocab,)
+    assert got.dtype == np.float32          # accumulated and kept in f32
+    gap = np.abs(got - ref).max() / np.abs(ref).max()
+    assert 0.0 < gap < 2.0 ** -6, gap       # rounded, and only that
+
+
+@pytest.mark.parametrize("held", ["as_built", "narrowed"])
+def test_weight_dtype_and_bytes_published(paged, narrowed, held):
+    """``weight_dtype`` / ``weight_bytes`` in ``stats()`` and the
+    ``zoo_llm_weight_bytes`` gauge are the resident tree's own dtype
+    and summed ``nbytes`` — on this CPU the tree a model builds is
+    untouched f32, the narrowed one half of that in its dot leaves."""
+    import jax
+    from zoo_tpu.obs.metrics import get_registry
+    model = paged[1] if held == "as_built" else narrowed[2]
+    leaves = jax.tree_util.tree_leaves(model.params)
+    resident = sum(leaf.nbytes for leaf in leaves)
+    eng = LLMEngine(model)
+    st = eng.stats()
+    assert st["weight_bytes"] == resident == model.weight_bytes
+    assert st["weight_dtype"] == {"as_built": "float32",
+                                  "narrowed": "bfloat16"}[held]
+    assert st["kv_cache_dtype"] == "f32"
+
+    def gauge():
+        return {g["name"]: g["value"] for g in
+                get_registry().snapshot()["gauges"]}["zoo_llm_weight_bytes"]
+
+    # the gauge is process-global and every engine republishes it each
+    # scheduler pass (``llm_server``'s idles in this process, one pass
+    # every 5 ms): read it right after THIS engine published
+    def published():
+        eng._publish()
+        return gauge() == resident
+
+    assert any(published() for _ in range(50)), gauge()
+    if held == "narrowed":
+        f32 = sum(leaf.nbytes for leaf in
+                  jax.tree_util.tree_leaves(paged[1].params))
+        kept = sum(model.params[k].nbytes for k in
+                   ("embed", "final_norm")) + sum(
+            model.params["blocks"][k].nbytes for k in
+            ("attn_norm", "mlp_norm"))
+        assert resident - kept == (f32 - kept) // 2
 
 
 def test_spec_parses_kv_and_prefix_cache():

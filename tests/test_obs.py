@@ -465,6 +465,7 @@ def test_metrics_end_to_end_serving_fit_checkpoint(orca_ctx, tmp_path):
         max_context, prefill_chunk_size, eos_id = 16, 0, None
         suffix_chunk_size = 4
         kv_bytes_per_token = 160          # -> zoo_llm_kv_bytes_per_token
+        weight_bytes = 4096               # -> zoo_llm_weight_bytes
         spec_k = 2                        # -> the verify path + the
         #                                   zoo_llm_spec_* families
 
@@ -672,6 +673,9 @@ def test_metrics_end_to_end_serving_fit_checkpoint(orca_ctx, tmp_path):
             "zoo_llm_prefix_cache_miss_tokens_total",
             "zoo_llm_kv_blocks_shared",
             "zoo_llm_kv_bytes_per_token 160",
+            # the resident weight tree's bytes (PR 26: bf16 dot
+            # weights on a TPU halve it)
+            "zoo_llm_weight_bytes 4096",
             # speculative decoding (this PR): proposed/accepted draft
             # tokens, the per-pass accept-length histogram, and the
             # drafter hit-rate gauge — republished from engine.stats()

@@ -158,6 +158,74 @@ def test_serving_geometry_other_block_sizes_compile(v5e):
                     (kernel.__name__, bs, dt)
 
 
+@pytest.mark.parametrize("which", ["decode", "prefill_chunk"])
+def test_serving_step_holds_no_weight_convert(v5e, monkeypatch, which):
+    """An f32 x f32 dot is one bf16 pass on the MXU: handed f32 weights
+    the compiler converts each whole ``(n_layer, ...)`` stack to bf16
+    outside the layer scan, once per call (the largest line of both
+    serving cells in the ledger of PR 25). With the leaves narrowed as
+    ``PagedLlamaModel`` narrows them on a TPU the optimized HLO holds
+    no such convert; with the f32 leaves it does, so this test bites."""
+    import re
+
+    from zoo_tpu.models.llm.llama import LlamaConfig
+    from zoo_tpu.serving.llm.model import (
+        DOT_BLOCK_LEAVES,
+        PagedLlamaModel,
+        narrow_dot_weights,
+    )
+
+    # compile the paged kernels, although this process sits on a CPU
+    for mod in ("paged_decode", "paged_prefill"):
+        monkeypatch.setattr(sys.modules[f"zoo_tpu.ops.pallas.{mod}"],
+                            "_resolve_interpret", lambda i: False)
+    one = SingleDeviceSharding(v5e[0])
+    cfg = LlamaConfig(vocab=512, hidden=512, n_block=2, n_head=4,
+                      n_kv_head=2, intermediate=1024, rope_theta=1e6)
+    S, W, C = 8, 8, 32
+    model = PagedLlamaModel(
+        cfg, seed=0, num_slots=S, block_size=16, num_blocks=64,
+        max_blocks_per_seq=W, prefill_buckets=(C,), prefill_chunk=C,
+        kv_dtype="int8", decode_impl="flash", prefill_impl="flash")
+    assert model.weight_dtype == "float32"       # a CPU holds what it got
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    def avals(tree):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
+
+    def hlo(params):
+        if which == "decode":
+            fn, args = model._decode_fn, (
+                sds((S,), jnp.int32), sds((S,), jnp.int32),
+                sds((S,), jnp.bool_), sds((S, W), jnp.int32),
+                sds((S,), jnp.int32), sds((S,), jnp.float32),
+                sds((S,), jnp.int32), sds((S,), jnp.float32),
+                sds((S,), jnp.uint32))
+        else:
+            fn, args = model._prefill_chunk_fn, (
+                sds((1, C), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.int32), sds((W,), jnp.int32),
+                sds((), jnp.float32), sds((), jnp.int32),
+                sds((), jnp.float32), sds((), jnp.uint32))
+        return jax.jit(fn, donate_argnums=(1,)).lower(
+            avals(params), avals(model._cache), *args).compile().as_text()
+
+    stacks = {",".join(map(str, model.params["blocks"][n].shape))
+              for n in DOT_BLOCK_LEAVES}
+    stacks.add(",".join(map(str, model.params["head"].shape)))
+
+    def weight_converts(text):
+        return [shape for shape in re.findall(
+            r"= bf16\[([\d,]+)\]\S* convert\(", text) if shape in stacks]
+
+    narrow = hlo(narrow_dot_weights(model.params, "tpu"))
+    assert MOSAIC_CALL in narrow
+    assert weight_converts(narrow) == []
+    assert len(set(weight_converts(hlo(model.params)))) >= 4
+
+
 def test_flash_attention_compiles_on_a_mesh(v5e, monkeypatch):
     """Under a multi-device jit GSPMD refuses a bare Mosaic kernel;
     ``dot_product_attention`` places it with shard_map (batch rows over

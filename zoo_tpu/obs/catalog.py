@@ -125,6 +125,7 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "zoo_llm_kv_blocks_shared": ("gauge", ()),
     "zoo_llm_kv_blocks_cached": ("gauge", ()),
     "zoo_llm_kv_bytes_per_token": ("gauge", ()),
+    "zoo_llm_weight_bytes": ("gauge", ()),
     "zoo_llm_prefix_cache_hit_tokens_total": ("counter", ()),
     "zoo_llm_prefix_cache_miss_tokens_total": ("counter", ()),
     "zoo_llm_host_transfer_bytes_total": ("counter", ("kind",)),

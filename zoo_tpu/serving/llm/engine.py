@@ -78,6 +78,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import os
 import queue as _queue
 import threading
@@ -171,6 +172,10 @@ _kv_bytes_per_token = gauge(
     "zoo_llm_kv_bytes_per_token",
     "HBM bytes one cached token costs (K+V rows across layers, plus "
     "int8 scale rows) under the engine model's KV cache dtype")
+_weight_bytes = gauge(
+    "zoo_llm_weight_bytes",
+    "HBM bytes the engine model's resident weight tree holds (its dot "
+    "weights are bf16 on a TPU: docs/llm_serving.md, Weights)")
 # speculative-decoding families (docs/llm_serving.md): how many tokens
 # the drafter proposed, how many the verify pass accepted (the
 # amortization the feature exists for), the per-pass accept-length
@@ -579,6 +584,10 @@ class LLMEngine:
         self._kv_bpt = getattr(model, "kv_bytes_per_token", None)
         if self._kv_bpt:
             _kv_bytes_per_token.set(float(self._kv_bpt))
+        self._had_streams = False   # as of the last _publish
+        self._weight_bytes = getattr(model, "weight_bytes", None)
+        if self._weight_bytes:
+            _weight_bytes.set(float(self._weight_bytes))
         self._slots = [_Slot() for _ in range(model.num_slots)]
         self._wait: Deque[GenHandle] = collections.deque()  # guarded-by: _lock
         # ONE reentrant state lock: the scheduler holds it across each
@@ -808,8 +817,12 @@ class LLMEngine:
 
     def _publish(self):
         with self._lock:
-            _occupancy.set(sum(1 for s in self._slots if s.handle))
+            active = sum(1 for s in self._slots if s.handle)
+            _occupancy.set(active)
             _waiting.set(len(self._wait))
+            streams = bool(active or self._wait)
+            drained = self._had_streams and not streams
+            self._had_streams = streams
             if self.tenancy.enabled:
                 slots_by: Dict[str, int] = {}
                 for t, n in self._slots_by_tenant().items():
@@ -833,6 +846,16 @@ class LLMEngine:
         # hot-swap pairs) only displaces it until the next tick
         if self._kv_bpt:
             _kv_bytes_per_token.set(float(self._kv_bpt))
+        if self._weight_bytes:
+            _weight_bytes.set(float(self._weight_bytes))
+        if drained:
+            # the last stream of a burst just left: collect the burst's
+            # young garbage NOW, while no token waits on any thread of
+            # this process, so the collector's next pass does not fall
+            # at an arbitrary allocation of whoever runs next (the
+            # caller's bookkeeping after its last answer, or the first
+            # tick of the next burst)
+            gc.collect(0)
 
     def _finish_slot(self, slot: _Slot, outcome: str,
                      error: Optional[str] = None):
@@ -2154,6 +2177,11 @@ class LLMEngine:
                    self.model, "kv_cache_dtype_requested", "f32"),
                "kv_bytes_per_token": getattr(
                    self.model, "kv_bytes_per_token", None),
+               # what the dot weights are held as (bfloat16 on a TPU,
+               # the caller's dtype elsewhere) and the resident bytes
+               # of the whole tree (None for the jax-free synthetic)
+               "weight_dtype": getattr(self.model, "weight_dtype", None),
+               "weight_bytes": self._weight_bytes,
                "prefix_cache": self.prefix_cache,
                "prefix_hit_tokens": self._hit_tokens,
                "prefix_miss_tokens": self._miss_tokens,

@@ -191,6 +191,62 @@ def _pick_bucket(buckets: Sequence[int], n: int) -> Optional[int]:
     return None
 
 
+# ---------------------------------------------------------------- weights
+
+# the stacked block leaves that are consumed ONLY as dot operands (an
+# untied ``head`` is the eighth); norm gains multiply elementwise and
+# ``embed`` is gathered — and, tied, is the head too — so they stay f32
+DOT_BLOCK_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def narrow_dot_weights(params, platform: str):
+    """The weight tree as the serving executables should hold it on
+    ``platform`` (docs/llm_serving.md, "Weights").
+
+    On a TPU at the platform's own matmul precision
+    (``jax_default_matmul_precision`` unset / ``default`` /
+    ``bfloat16``) an f32 x f32 dot is ONE bf16 pass on the MXU: the
+    compiler rounds both operands to bf16 and accumulates in f32. The
+    weight operand is loop-invariant, so it hoists the convert of each
+    whole ``(n_layer, ...)`` stack out of the layer scan and runs it
+    once per call — every decode tick and prefill chunk re-derives the
+    same bf16 weights. So there, and only there, the f32 dot leaves are
+    rounded to bf16 ONCE, here: no value the step computes changes, the
+    per-call converts and half of the weights' bytes go. Everything
+    else comes back as the SAME object: norm gains, ``embed``, a leaf
+    that is already narrow (a bf16 checkpoint), every leaf on CPU/GPU
+    (there an f32 dot is an f32 dot) and every leaf when a higher
+    matmul precision was asked for. Cast leaf by leaf — the transient
+    is one leaf, not a second model — and the caller's arrays are
+    never deleted."""
+    if platform != "tpu" or jax.config.jax_default_matmul_precision \
+            not in (None, "default", "bfloat16"):
+        return params
+
+    def narrow(leaf):
+        return leaf.astype(jnp.bfloat16) \
+            if leaf.dtype == jnp.float32 else leaf
+
+    out = dict(params)
+    out["blocks"] = {name: narrow(leaf) if name in DOT_BLOCK_LEAVES
+                     else leaf for name, leaf in params["blocks"].items()}
+    if "head" in params:
+        out["head"] = narrow(params["head"])
+    return out
+
+
+def _weight_dot(x, w):
+    """``x @ w`` for a weight operand, multiplied in the dtype the
+    weight is HELD in: activations drop to a narrower weight's dtype
+    (what the MXU pass does to them anyway), the product accumulates
+    and comes back in f32. A weight that is not narrower than the
+    activations is the plain ``x @ w``."""
+    if w.dtype.itemsize < x.dtype.itemsize:
+        return jnp.matmul(x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+    return x @ w
+
+
 # ------------------------------------------------------ on-device sampling
 
 GREEDY = (0.0, 0, 1.0, 0)  # (temperature, top_k, top_p, seed)
@@ -355,6 +411,16 @@ class PagedLlamaModel:
         layer = Llama(config, lm_head=True)
         self.params = params if params is not None else layer.build(
             jax.random.PRNGKey(seed), (None, self.prefill_buckets[-1]))
+        # held in the dtype the device's dot reads them in (bf16 on a
+        # TPU, untouched elsewhere), before the mesh placement below
+        self.params = narrow_dot_weights(
+            self.params, (self.mesh.devices.flat[0] if self.mesh is not None
+                          else jax.devices()[0]).platform)
+        # what the dot leaves are held as and what the whole tree costs
+        # in HBM — ``llm_stats`` and the zoo_llm_weight_bytes gauge
+        self.weight_dtype = str(self.params["blocks"]["wq"].dtype)
+        self.weight_bytes = int(sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params)))
         # rope tables over the whole pageable context, closed over by
         # every executable (f32, tiny: max_context x head_dim/2)
         self._cos, self._sin = rope_frequencies(
@@ -546,17 +612,20 @@ class PagedLlamaModel:
     def _attn_proj(self, p, x):
         """Shared q/k/v projection + head split for every executable."""
         c = self.cfg
-        q = (x @ p["wq"]).reshape(*x.shape[:-1], c.n_head, c.head_dim)
-        k = (x @ p["wk"]).reshape(*x.shape[:-1], c.n_kv_head, c.head_dim)
-        v = (x @ p["wv"]).reshape(*x.shape[:-1], c.n_kv_head, c.head_dim)
+        q = _weight_dot(x, p["wq"]).reshape(
+            *x.shape[:-1], c.n_head, c.head_dim)
+        k = _weight_dot(x, p["wk"]).reshape(
+            *x.shape[:-1], c.n_kv_head, c.head_dim)
+        v = _weight_dot(x, p["wv"]).reshape(
+            *x.shape[:-1], c.n_kv_head, c.head_dim)
         return q, k, v
 
     @jax.named_scope("zoo.mlp")
     def _mlp(self, p, h):
         c = self.cfg
         x = _rms_norm(h, p["mlp_norm"], c.rms_eps)
-        return h + (jax.nn.silu(x @ p["w_gate"])
-                    * (x @ p["w_up"])) @ p["w_down"]
+        return h + _weight_dot(jax.nn.silu(_weight_dot(x, p["w_gate"]))
+                               * _weight_dot(x, p["w_up"]), p["w_down"])
 
     @jax.named_scope("zoo.lm_head")
     def _lm_head(self, params, h):
@@ -564,7 +633,7 @@ class PagedLlamaModel:
         h = _rms_norm(h, params["final_norm"], c.rms_eps)
         head = (params["embed"].T if c.tie_embeddings
                 else params["head"])
-        return h @ head.astype(h.dtype)
+        return _weight_dot(h, head)
 
     def _on_model_axis(self, kernel, q, q_spec, kcl, vcl, ksl, vsl,
                        block_tables, positions, pos_spec, scale):
@@ -728,7 +797,7 @@ class PagedLlamaModel:
             vcl, vsl = self._append_rows(vcl, vsl, blk, off, v)
             o = self._paged_attend(q, kcl, vcl, ksl, vsl,
                                    block_tables, positions)
-            h = h + o @ p["wo"]
+            h = h + _weight_dot(o, p["wo"])
             return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
 
         h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
@@ -769,7 +838,7 @@ class PagedLlamaModel:
                                       mesh=self.mesh)
             a = a.transpose(0, 2, 1, 3).reshape(1, L,
                                                 c.n_head * c.head_dim)
-            h = h + a @ p["wo"]
+            h = h + _weight_dot(a, p["wo"])
             kcl, ksl = self._append_rows(kcl, ksl, blk, off,
                                          k.transpose(0, 2, 1, 3)[0])
             vcl, vsl = self._append_rows(vcl, vsl, blk, off,
@@ -824,7 +893,7 @@ class PagedLlamaModel:
             # streams the table, dense gathers it
             a = self._prefill_attend(q, kcl, vcl, ksl, vsl,
                                      block_table[None], pos[None])
-            h = h + a @ p["wo"]
+            h = h + _weight_dot(a, p["wo"])
             return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
 
         h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
@@ -880,7 +949,7 @@ class PagedLlamaModel:
             vcl, vsl = self._append_rows(vcl, vsl, blk, off, v)
             a = self._prefill_attend(q, kcl, vcl, ksl, vsl,
                                      block_tables, pos)
-            h = h + a @ p["wo"]
+            h = h + _weight_dot(a, p["wo"])
             return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
 
         h = jnp.take(params["embed"], tokens, axis=0)   # (S, T, hidden)

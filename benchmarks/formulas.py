@@ -3,8 +3,11 @@
 Every function takes the configuration (the dict of its file under
 ``configs/``), the traffic file's dict and a ``census`` of what the
 window did (counts the harness took itself), and returns one number.
-A per-layer metric picks a formula by name (``FORMULAS``), so a later
-metric over the same census is a data file and no code.
+A per-layer metric's file names one of them (``"formula":
+"decode_flops"``), and the name resolves in the formulas module that the
+cell's configuration names (``"formulas"``, by default this one): a
+configuration of another architecture brings ``formulas_<arch>.py`` with
+functions of the same names and lists its cells under the same metrics.
 
 The counts are of what the algorithm needs, not of what today's program
 does: recomputed activations do not count, and weights are counted at
@@ -12,8 +15,6 @@ the precision the configuration states.
 """
 
 from __future__ import annotations
-
-from typing import Callable, Dict
 
 _BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1, "fp8": 1}
 
@@ -105,9 +106,3 @@ def decode_bytes(cfg: dict, traffic: dict, census: dict) -> float:
     return (per_tick * census["decode_ticks"] + logits
             + kv_bytes_per_token(cfg) * census["attended_positions"])
 
-
-FORMULAS: Dict[str, Callable[[dict, dict, dict], float]] = {
-    "train_flops": train_flops,
-    "decode_flops": decode_flops,
-    "decode_bytes": decode_bytes,
-}

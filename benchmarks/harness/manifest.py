@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -9,6 +10,11 @@ from typing import Dict, List
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
+# what a configuration's ``adapter`` has to give, by its ``kind``
+ADAPTER_DUTIES = {
+    "serve_decoder": ("weights", "model", "free"),
+    "train_classifier": ("model", "to_program_tree", "from_program_tree"),
+}
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -22,6 +28,53 @@ def _load(path: str) -> dict:
 
 def load_benchmark() -> dict:
     return _load(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+def data_file(*parts: str) -> str:
+    """``traffic/x.json``, ``metrics/y.json`` ... under ``benchmarks/``."""
+    return os.path.join(HERE, *parts)
+
+
+# -- the seam: what is particular to an architecture is named in the
+# configuration's file and lives in modules of its own
+
+def adapter_of(cfg: dict):
+    """``adapters/<cfg["adapter"]>.py``: builds the program for this
+    architecture, frees it, and maps the reference's tree to it."""
+    mod = importlib.import_module("adapters." + cfg["adapter"])
+    missing = [f for f in ADAPTER_DUTIES[cfg["kind"]]
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"adapter {cfg['adapter']!r} of kind "
+                             f"{cfg['kind']!r} lacks {missing}")
+    return mod
+
+
+def reference_of(cfg: dict):
+    """``reference/<cfg["reference"]>.py``: the plain reference."""
+    return importlib.import_module("reference." + cfg["reference"])
+
+
+def formulas_of(cfg: dict):
+    """The module a bare formula name of a metric file resolves in:
+    ``cfg["formulas"]`` under ``benchmarks/``, by default ``formulas``."""
+    return importlib.import_module(cfg.get("formulas", "formulas"))
+
+
+def resolve(name: str, table: dict):
+    """``name`` from ``table``, or, where it reads ``module:function``,
+    that function of a module under ``benchmarks/``: how a later PR
+    brings a reader or a formula of its own as a new file."""
+    if ":" in name:
+        module, attr = name.split(":", 1)
+        return getattr(importlib.import_module(module), attr)
+    return table[name]
+
+
+def formula(name: str, cfg: dict):
+    """The function ``name`` of the configuration's formulas module, or,
+    where it reads ``module:function``, that function of that module."""
+    return resolve(name, vars(formulas_of(cfg)))
 
 
 class Cell:
@@ -39,9 +92,9 @@ class Cell:
         cfg_entry = next(c for c in bench["configs"]
                          if c["name"] == self.spec["config"])
         self.config = _load(os.path.join(ROOT, cfg_entry["file"]))
-        self.traffic = _load(os.path.join(
-            HERE, "traffic", self.spec["traffic"] + ".json"))
-        limits = os.path.join(HERE, "limits", name + ".json")
+        self.traffic = _load(data_file(
+            "traffic", self.spec["traffic"] + ".json"))
+        limits = data_file("limits", name + ".json")
         self.limits = _load(limits) if os.path.exists(limits) else {}
 
     def _mine(self, metric: dict) -> bool:
@@ -57,7 +110,7 @@ class Cell:
         for m in self.bench["per_layer"]:
             if not self._mine(m):
                 continue
-            spec = _load(os.path.join(HERE, "metrics", m["name"] + ".json"))
+            spec = _load(data_file("metrics", m["name"] + ".json"))
             out.append({**spec, **m})
         return out
 
@@ -98,6 +151,13 @@ def check_manifest(bench: dict) -> List[str]:
             bad.append(f"config {c['name']}: file outside paths")
         if not os.path.exists(os.path.join(ROOT, c["file"])):
             bad.append(f"config {c['name']}: no file {c['file']}")
+        else:
+            cfg = _load(os.path.join(ROOT, c["file"]))
+            if cfg.get("kind") not in ADAPTER_DUTIES:
+                bad.append(f"config {c['name']}: kind {cfg.get('kind')!r}")
+            for key in ("adapter", "reference"):
+                if not isinstance(cfg.get(key), str):
+                    bad.append(f"config {c['name']}: names no {key}")
         cfgs[c["name"]] = c
     cells = set()
     pairs = set()
@@ -115,8 +175,8 @@ def check_manifest(bench: dict) -> List[str]:
             bad.append(f"workload {w['name']}: listed twice")
         pairs.add((w["config"], w["traffic"]))
         cells.add(w["name"])
-        if not os.path.exists(os.path.join(
-                HERE, "traffic", w["traffic"] + ".json")):
+        if not os.path.exists(data_file(
+                "traffic", w["traffic"] + ".json")):
             bad.append(f"workload {w['name']}: no traffic file")
     four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
     if four > max(1, len(bench["workloads"]) // 4):
@@ -174,8 +234,8 @@ def check_manifest(bench: dict) -> List[str]:
         if not mine <= e2e[m["moves"]]:
             bad.append(f"{m['name']}: cells {sorted(mine - e2e[m['moves']])}"
                        f" do not report {m['moves']}")
-        if not os.path.exists(os.path.join(HERE, "metrics",
-                                           m["name"] + ".json")):
+        if not os.path.exists(data_file("metrics",
+                                        m["name"] + ".json")):
             bad.append(f"{m['name']}: no file under metrics/")
     for cell in cells:
         mine_e = [n for n, ws in e2e.items() if cell in ws]

@@ -14,8 +14,8 @@ import re
 import statistics
 from typing import Callable, Dict, Optional
 
-import formulas
-from . import trace as tr
+from . import manifest, trace as tr
+from .manifest import resolve
 from .spans import Recorder
 
 
@@ -34,17 +34,6 @@ class Context:
     trace: Optional[tr.Trace] = None
     traced: Optional[tuple] = None        # (t0, t1) of the traced part
     traced_census: Optional[dict] = None  # counts within the traced part
-
-
-def resolve(name: str, table: dict):
-    """``name`` from ``table``, or, where it reads ``module:function``,
-    that function of a module under ``benchmarks/``: how a later PR
-    brings a formula or a reader of its own as a new file."""
-    if ":" in name:
-        import importlib
-        module, attr = name.split(":", 1)
-        return getattr(importlib.import_module(module), attr)
-    return table[name]
 
 
 def percentile(values, q: float) -> Optional[float]:
@@ -140,11 +129,21 @@ def _formula_share(ctx: Context, p: dict):
         if not host_ticks:
             return None
         census = {k: v * n / host_ticks for k, v in census.items()}
-    work = resolve(p["formula"], formulas.FORMULAS)(ctx.cfg, ctx.traffic,
-                                                    census)
+    work = manifest.formula(p["formula"], ctx.cfg)(ctx.cfg, ctx.traffic,
+                                                   census)
     if work <= 0:
         return None
     return 100.0 * (work / ctx.chips) / busy / ctx.peaks[p["peak"]]
+
+
+def _scope_share(ctx: Context, p: dict):
+    """Device time of the operations traced under a scope matching
+    ``scope`` (each operation's own time: a ``while`` does not count
+    its body twice) inside the executables matching ``module``, over
+    the device time of those executables, in percent."""
+    if ctx.trace is None:
+        return None
+    return tr.scope_share(ctx.trace, p["scope"], p["module"])
 
 
 READERS: Dict[str, Callable[[Context, dict], Optional[float]]] = {
@@ -157,6 +156,7 @@ READERS: Dict[str, Callable[[Context, dict], Optional[float]]] = {
     "trace_collective_exposed": _collective_exposed,
     "module_count_per": _module_count_per,
     "formula_share": _formula_share,
+    "scope_share": _scope_share,
 }
 
 

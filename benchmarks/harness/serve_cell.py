@@ -1,11 +1,15 @@
 """Driving a served decoder: ``HAServingClient.generate`` over TCP to an
-in-process ``ServingServer`` over ``LLMEngine`` over ``PagedLlamaModel``
-(the entry ``chip_smoke.py``'s serve leg shows).
+in-process ``ServingServer`` over ``LLMEngine`` over the model that the
+configuration's adapter builds (the entry ``chip_smoke.py``'s serve leg
+shows).
 
-The program is built with the calls ``build_llm_engine`` makes, not from
-a ``llama:`` spec string, because the spec grammar has no ``rope_theta``
-key. The benchmark makes the weights (``reference/decoder_lm.py``) and
-hands them in; it keeps no copy while the window runs.
+What is particular to an architecture is the adapter's
+(``adapters/<name>.py``, named in the configuration's file): the tree of
+weights the program takes, the model object, and how to free it. Here
+is what every served decoder shares: the engine, server and client, the
+spans round the engine's calls into the model, the gauges, warm-up and
+the windows. The benchmark makes the weights from the seed and keeps no
+copy while the window runs.
 """
 
 from __future__ import annotations
@@ -18,7 +22,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import traffic as tg
+from . import manifest, traffic as tg
 from .readers import percentile
 from .spans import GaugeSampler, GcWatch, Recorder
 
@@ -30,43 +34,18 @@ class ServedDecoder:
     """The system under test and the spans the benchmark puts round it."""
 
     def __init__(self, cfg: dict, seed: int, rec: Recorder, ref_mod):
-        import jax
-        from zoo_tpu.models.llm.llama import LlamaConfig
         from zoo_tpu.serving.ha_client import HAServingClient
         from zoo_tpu.serving.llm.engine import LLMEngine
-        from zoo_tpu.serving.llm.model import PagedLlamaModel
         from zoo_tpu.serving.server import ServingServer
 
         self.cfg, self.rec = cfg, rec
+        self.adapter = manifest.adapter_of(cfg)
         eng = cfg["engine"]
-        lcfg = LlamaConfig(
-            vocab=cfg["vocab_size"], hidden=cfg["hidden_size"],
-            n_block=cfg["num_hidden_layers"],
-            n_head=cfg["num_attention_heads"],
-            n_kv_head=cfg["num_key_value_heads"],
-            intermediate=cfg["intermediate_size"],
-            rope_theta=cfg["rope_theta"], rms_eps=cfg["rms_norm_eps"],
-            tie_embeddings=cfg["tie_word_embeddings"])
-        if lcfg.head_dim != cfg["head_dim"]:
-            raise ValueError("the program derives head_dim = hidden/heads "
-                             f"= {lcfg.head_dim}, the configuration "
-                             f"states {cfg['head_dim']}")
         with rec.span("setup.weights"):
-            ref = ref_mod.make_params(seed, cfg)
-            params = {"embed": ref["embed"], "blocks": ref["layers"],
-                      "final_norm": ref["final_norm"], "head": ref["head"]}
-            jax.block_until_ready(params)
-            del ref
+            weights = self.adapter.weights(seed, cfg, ref_mod)
         with rec.span("setup.engine"):
-            self.model = PagedLlamaModel(
-                lcfg, params=params, num_slots=eng["num_slots"],
-                block_size=eng["block_size"], num_blocks=eng["num_blocks"],
-                max_blocks_per_seq=eng["max_blocks_per_seq"],
-                prefill_buckets=(eng["prefill_chunk"],),
-                prefill_chunk=eng["prefill_chunk"],
-                kv_dtype=eng["kv_dtype"], spec_k=eng["spec_k"],
-                eos_id=eng["eos_id"])
-            del params
+            self.model = self.adapter.model(cfg, weights)
+            del weights
             self.engine = LLMEngine(self.model, mode="continuous",
                                     overlap=eng["overlap"],
                                     prefix_cache=eng["prefix_cache"])
@@ -182,7 +161,6 @@ class ServedDecoder:
     def close(self):
         """Stop the threads and free the device: the reference runs
         next and needs the room."""
-        import jax
         gc.unfreeze()
         self.sampler.stop()
         self.gc_watch.stop()
@@ -190,12 +168,7 @@ class ServedDecoder:
         self.server.stop()
         self.engine.stop()
         self.rec.unwrap_all()
-        leaves = jax.tree_util.tree_leaves(
-            (self.model.params, self.model._cache))
-        self.model.params = self.model._cache = None
-        for leaf in leaves:
-            if hasattr(leaf, "delete") and not leaf.is_deleted():
-                leaf.delete()
+        self.adapter.free(self.model)
         with self._lock:
             self._handles.clear()
         gc.collect()

@@ -133,17 +133,24 @@ class GaugeSampler:
 class GcWatch:
     """Times every pass of the cyclic garbage collector. A pass holds
     the interpreter's lock, so every thread of the program stands still
-    for as long as it lasts; each is a span ``host.gc<generation>``."""
+    for as long as it lasts; each becomes a span ``host.gc<generation>``
+    of the recorder when the watch stops.
+
+    The hook keeps a list of its own and takes no lock: a pass can
+    begin on a thread that is inside ``Recorder._lock`` (any allocation
+    there may start one), and a hook that asked for that lock would
+    wait for its own thread for ever."""
 
     def __init__(self, rec: Recorder):
         self._rec, self._t = rec, None
+        self._passes: List[Tuple[int, float, float]] = []
 
     def _seen(self, phase: str, info: dict):
         if phase == "start":
             self._t = time.perf_counter()
         elif self._t is not None:
-            self._rec.add_span(f"host.gc{info['generation']}", self._t,
-                               time.perf_counter() - self._t)
+            self._passes.append((info["generation"], self._t,
+                                 time.perf_counter() - self._t))
             self._t = None
 
     def start(self):
@@ -153,9 +160,13 @@ class GcWatch:
     def stop(self):
         if self._seen in gc.callbacks:
             gc.callbacks.remove(self._seen)
+        passes, self._passes = self._passes, []
+        for gen, t, dur in passes:
+            self._rec.add_span(f"host.gc{gen}", t, dur)
 
     def said(self, t0: float, t1: float) -> dict:
-        """Passes by generation and the time they held in [t0, t1)."""
+        """Passes by generation and the time they held in [t0, t1),
+        once the watch has stopped."""
         out, durs = {}, []
         for gen in (0, 1, 2):
             d = self._rec.spans_in(f"host.gc{gen}", t0, t1)

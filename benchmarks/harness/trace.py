@@ -10,15 +10,18 @@ trace with no profiler (``tests/test_trace.py``).
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
 import re
-from typing import Dict, List, Sequence, Tuple
+import sys
+from typing import Dict, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]          # name, start_s, duration_s
 
 SMALL_GAP_S = 20e-6
+DEVICE_PLANE = "/device:TPU:"
 COLLECTIVE_RE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all",
     re.I)
@@ -29,6 +32,11 @@ class Trace:
     ops: Dict[str, List[Event]]           # device -> operations
     modules: Dict[str, List[Event]]       # device -> executables run
     host: List[Event]                     # host spans (annotations)
+    # device -> for every operation of ``ops``, in its order, the scope
+    # it was traced under as the program's ``jax.named_scope``s give it
+    # (``jit(_decode_fn)/.../zoo.mlp/dot_general``), "" for none; empty
+    # where the profile carries no scope at all
+    scopes: Dict[str, List[str]] = dataclasses.field(default_factory=dict)
 
     def to_json(self) -> dict:
         return dataclasses.asdict(self)
@@ -38,7 +46,8 @@ class Trace:
         fix = lambda evs: [(n, float(s), float(t)) for n, s, t in evs]
         return Trace({k: fix(v) for k, v in d["ops"].items()},
                      {k: fix(v) for k, v in d["modules"].items()},
-                     fix(d["host"]))
+                     fix(d["host"]),
+                     {k: list(v) for k, v in d.get("scopes", {}).items()})
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -49,31 +58,81 @@ def find_xplane(trace_dir: str) -> str:
     return hits[-1]
 
 
+def _xplane_pb2():
+    """tsl's generated classes for the profile's format
+    (``tsl/profiler/protobuf/xplane.proto``). They ship inside the
+    tensorflow package; the one file is loaded by its path, because
+    importing the package round it takes nine seconds and the file
+    needs nothing of it."""
+    import importlib.util
+    if "_xplane_pb2" not in sys.modules:
+        root = importlib.util.find_spec(
+            "tensorflow").submodule_search_locations[0]
+        spec = importlib.util.spec_from_file_location(
+            "_xplane_pb2", os.path.join(
+                root, "tsl", "profiler", "protobuf", "xplane_pb2.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules["_xplane_pb2"] = mod
+    return sys.modules["_xplane_pb2"]
+
+
 def read_xplane(path: str, host_prefix: str = "bench:") -> Trace:
     """Device planes are those named ``/device:TPU:<n>``; their line
     "XLA Ops" holds the operations and "XLA Modules" the executables.
     Host spans are the events whose name starts with ``host_prefix`` on
-    any line of the host planes."""
-    import jax
-    data = jax.profiler.ProfileData.from_file(path)
+    any line of the host planes. An operation's scope is the stat
+    ``tf_op`` of its event's metadata (one entry per distinct operation
+    of a plane), which ``jax.profiler.ProfileData`` does not show."""
+    space = _xplane_pb2().XSpace()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
     ops: Dict[str, List[Event]] = {}
     modules: Dict[str, List[Event]] = {}
+    scopes: Dict[str, List[str]] = {}
     host: List[Event] = []
-    for plane in data.planes:
-        is_dev = plane.name.startswith("/device:TPU:")
+    for plane in space.planes:
+        is_dev = plane.name.startswith(DEVICE_PLANE)
+        meta = plane.event_metadata
         for line in plane.lines:
+            t_line = line.timestamp_ns * 1e-9
             if is_dev and line.name in ("XLA Ops", "XLA Modules"):
                 dst = ops if line.name == "XLA Ops" else modules
                 dst.setdefault(plane.name, []).extend(
-                    (ev.name, ev.start_ns * 1e-9, ev.duration_ns * 1e-9)
+                    (meta[ev.metadata_id].name,
+                     t_line + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12)
                     for ev in line.events)
+                if dst is ops:
+                    by_id = _scopes_by_metadata(plane)
+                    scopes.setdefault(plane.name, []).extend(
+                        by_id.get(ev.metadata_id, "") for ev in line.events)
             elif not is_dev:
                 host.extend(
-                    (ev.name[len(host_prefix):], ev.start_ns * 1e-9,
-                     ev.duration_ns * 1e-9)
+                    (meta[ev.metadata_id].name[len(host_prefix):],
+                     t_line + ev.offset_ps * 1e-12, ev.duration_ps * 1e-12)
                     for ev in line.events
-                    if ev.name.startswith(host_prefix))
-    return Trace(ops, modules, host)
+                    if meta[ev.metadata_id].name.startswith(host_prefix))
+    if not any(s for line in scopes.values() for s in line):
+        scopes = {}
+    return Trace(ops, modules, host, scopes)
+
+
+SCOPE_STAT = "tf_op"
+
+
+def _scopes_by_metadata(plane) -> Dict[int, str]:
+    """Metadata id -> the scope that operation was traced under, for
+    the operations of one plane that carry one. A stat holds its text
+    itself or points at the stat name that does."""
+    names = {k: m.name for k, m in plane.stat_metadata.items()}
+    out = {}
+    for key, m in plane.event_metadata.items():
+        for st in m.stats:
+            if names.get(st.metadata_id) == SCOPE_STAT:
+                text = st.str_value or names.get(st.ref_value, "")
+                if text:
+                    out[key] = text
+    return out
 
 
 # ------------------------------------------------------------- intervals
@@ -160,24 +219,29 @@ def clean(name: str, cap: int = 64) -> str:
     return re.sub(r"[^A-Za-z0-9_.\-]", "_", name)[:cap]
 
 
-def self_times(events: Sequence[Event]) -> List[Tuple[str, float]]:
-    """(name, seconds) of every event less the time of the events that
-    lie inside it: a ``while`` or a ``conditional`` holds its body's
-    operations, and only what it spends itself is its own."""
-    out: List[Tuple[str, float]] = []
-    open_: List[list] = []            # [name, end, self seconds]
-    for name, s, d in sorted(events, key=lambda ev: (ev[1], -ev[2])):
+def own_seconds(events: Sequence[Event]) -> List[float]:
+    """For every event, in the order given, its seconds less those of
+    the events that lie inside it: a ``while`` or a ``conditional``
+    holds its body's operations, and only what it spends itself is its
+    own."""
+    own = [d for _, _, d in events]
+    open_: List[Tuple[int, float]] = []        # (index, end)
+    for i in sorted(range(len(events)),
+                    key=lambda i: (events[i][1], -events[i][2])):
+        _, s, d = events[i]
         while open_ and open_[-1][1] <= s:
-            top = open_.pop()
-            out.append((top[0], max(top[2], 0.0)))
+            open_.pop()
         if open_ and s + d > open_[-1][1] + 1e-12:
-            out.append((name, d))     # overlaps its neighbour, not inside
-            continue
+            continue                  # overlaps its neighbour, not inside
         if open_:
-            open_[-1][2] -= d
-        open_.append([name, s + d, d])
-    out.extend((name, max(t, 0.0)) for name, _, t in open_)
-    return out
+            own[open_[-1][0]] -= d
+        open_.append((i, s + d))
+    return [max(t, 0.0) for t in own]
+
+
+def self_times(events: Sequence[Event]) -> List[Tuple[str, float]]:
+    """(name, seconds of its own) of every event."""
+    return [(ev[0], t) for ev, t in zip(events, own_seconds(events))]
 
 
 def leaf_events(events: Sequence[Event]) -> List[Event]:
@@ -254,6 +318,35 @@ def matched_time(events_by_dev: Dict[str, List[Event]], pattern: str
         if sum(hit) > best[0]:
             best = (sum(hit), len(hit))
     return best
+
+
+def scope_share(trace: Trace, scope: str, module: str) -> Optional[float]:
+    """Own seconds of the operations whose scope matches ``scope`` and
+    that began inside an executable matching ``module``, over the
+    seconds of those executables, in percent, on the device that spent
+    most in them. None where the trace names no scope, no such
+    executable ran, or no operation under the scope did."""
+    if not trace.scopes:
+        return None
+    rx_scope, rx_mod = re.compile(scope), re.compile(module)
+    best = None
+    for dev, evs in trace.ops.items():
+        runs = sorted((s, s + d) for name, s, d in trace.modules.get(dev, ())
+                      if rx_mod.search(name))
+        den = sum(e - s for s, e in runs)
+        if den <= 0 or (best and den <= best[1]):
+            continue
+        starts = [s for s, _ in runs]
+        num = 0.0
+        for (_, s, _), own, under in zip(evs, own_seconds(evs),
+                                         trace.scopes.get(dev, ())):
+            k = bisect.bisect_right(starts, s) - 1
+            if k >= 0 and s < runs[k][1] and rx_scope.search(under):
+                num += own
+        best = (num, den)
+    if best is None or best[0] <= 0:
+        return None
+    return 100.0 * best[0] / best[1]
 
 
 def collective_exposed_share(trace: Trace) -> float:

@@ -18,9 +18,8 @@ import re
 import statistics
 from typing import Dict, List, Optional, Tuple
 
-import formulas
-from harness import trace as tr
-from harness.readers import percentile, resolve
+from harness import manifest, trace as tr
+from harness.readers import percentile
 
 Span = Tuple[str, float, float, int, Optional[dict]]
 
@@ -178,7 +177,8 @@ def idle_unattributed(ctx, p: dict) -> Optional[float]:
 
 def op_formula_share(ctx, p: dict) -> Optional[float]:
     """The bytes or operations ``work`` counts for the traced work (a
-    name of ``formulas.FORMULAS`` or ``module:function``) over the
+    function of the configuration's formulas module, or
+    ``module:function``) over the
     device time of the OPERATIONS matching ``op`` (a kernel by its
     name), as a share of one of the chip's peaks, in percent. The trace
     says how many ticks it holds (executables matching ``module``); the
@@ -192,8 +192,8 @@ def op_formula_share(ctx, p: dict) -> Optional[float]:
         return None
     census = {k: v * n_ticks / host_ticks
               for k, v in ctx.traced_census.items()}
-    work = resolve(p["work"], formulas.FORMULAS)(ctx.cfg, ctx.traffic,
-                                                 census)
+    work = manifest.formula(p["work"], ctx.cfg)(ctx.cfg, ctx.traffic,
+                                                census)
     if work <= 0:
         return None
     return 100.0 * (work / ctx.chips) / busy / ctx.peaks[p["peak"]]

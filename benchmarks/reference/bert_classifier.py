@@ -188,8 +188,8 @@ def _adamw(hp, params, m, v, g, step):
     return jax.tree_util.tree_map(upd, params, m, v), m, v
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _follow(cfg_items, hp_items, lower, params, ids, labels):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _follow(cfg_items, hp_items, lower, first, params, ids, labels):
     c, hp = dict(cfg_items), dict(hp_items)
     zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
 
@@ -198,25 +198,29 @@ def _follow(cfg_items, hp_items, lower, params, ids, labels):
         loss, g = _grads(c, lower, params, xs[0], xs[1])
         k = k + 1.0
         params, m, v = _adamw(hp, params, m, v, g, k)
-        gnorm = jax.tree_util.tree_map(
-            lambda a: jnp.sqrt(jnp.sum(a * a)), g)
-        return (params, m, v, k), (loss, gnorm)
+        return (params, m, v, k), loss
 
-    (p_end, m, _, _), (losses, gnorms) = jax.lax.scan(
-        step, (params, zeros, zeros, jnp.zeros(())), (ids, labels))
-    first_g = jax.tree_util.tree_map(lambda a: a[0], gnorms)
-    return losses, p_end, m, first_g
+    _, first_grad = _grads(c, lower, params, ids[0], labels[0])
+    carry = (params, zeros, zeros, jnp.zeros(()))
+    carry, losses = jax.lax.scan(step, carry, (ids[:first], labels[:first]))
+    p_first = carry[0]
+    (p_end, m, _, _), later = jax.lax.scan(
+        step, carry, (ids[first:], labels[first:]))
+    return {"losses": jnp.concatenate([losses, later]), "params": p_end,
+            "moment": m, "params_first": p_first, "first_grad": first_grad}
 
 
 def follow(params, cfg: dict, ids, labels, steps: int, batch: int,
-           lower: bool = False, rows: float = 1.0, devices=None):
+           lower: bool = False, rows: float = 1.0, devices=None,
+           first: int = 1):
     """Train ``steps`` steps of ``batch`` rows each from ``params`` on
-    the rows of ``ids`` in order. Returns the per-step losses, the
-    parameters and Adam's first moment after the last step, and the
-    norm of every leaf's first gradient. ``rows`` < 1 keeps only that
-    leading share of every batch. Several ``devices`` share the rows of
-    each block (the compiler adds the reduction); the arithmetic is the
-    same."""
+    the rows of ``ids`` in order. Returns the per-step ``losses``, the
+    ``params`` and Adam's first ``moment`` after the last step, the
+    ``first_grad``ient, and ``params_first``, the parameters after the
+    ``first`` steps.
+    ``rows`` < 1 keeps only that leading share of every batch. Several
+    ``devices`` share the rows of each block (the compiler adds the
+    reduction); the arithmetic is the same."""
     import numpy as np
     hp = train_hyper(cfg)
     ids = np.asarray(ids)[:steps * batch].reshape(steps, batch, -1)
@@ -239,7 +243,8 @@ def follow(params, cfg: dict, ids, labels, steps: int, batch: int,
             labels, NamedSharding(mesh, P(None, None, "rows")))
         params = jax.device_put(params, NamedSharding(mesh, P()))
     return _follow(_cfg_items(cfg), tuple(sorted(hp.items())), bool(lower),
-                   params, jnp.asarray(ids), jnp.asarray(labels))
+                   max(1, min(int(first), steps)), params,
+                   jnp.asarray(ids), jnp.asarray(labels))
 
 
 def train_hyper(cfg: dict) -> dict:

@@ -51,12 +51,18 @@ def _merge(base: dict, over: dict) -> dict:
 
 
 def _rehearsal(cell):
-    """Toy widths and a toy load for the sandbox, from ``rehearsal/``."""
+    """Toy widths and a toy load for the sandbox, from ``rehearsal/``,
+    and, where rounding reads otherwise at toy widths, the limits that
+    hold there."""
+    from harness import manifest
     for what, name in (("config", cell.spec["config"]),
-                       ("traffic", cell.spec["traffic"])):
-        path = os.path.join(HERE, "rehearsal", f"{what}.{name}.json")
-        with open(path) as f:
-            setattr(cell, what, _merge(getattr(cell, what), json.load(f)))
+                       ("traffic", cell.spec["traffic"]),
+                       ("limits", cell.name)):
+        path = manifest.data_file("rehearsal", f"{what}.{name}.json")
+        if what != "limits" or os.path.exists(path):
+            with open(path) as f:
+                setattr(cell, what,
+                        _merge(getattr(cell, what), json.load(f)))
 
 
 class Tracer:
@@ -163,10 +169,8 @@ def _judge(numbers: dict, limits: dict):
 # ------------------------------------------------------------------ serving
 
 def run_serve(cell, args, devs, peaks, rec):
-    import importlib
-    from harness import device, readers, serve_cell as sc
-    ref_mod = importlib.import_module(
-        "reference." + cell.config["reference"])
+    from harness import device, manifest, readers, serve_cell as sc
+    ref_mod = manifest.reference_of(cell.config)
     tracer = Tracer(rec, cell.name) if args.trace else None
     sys_ = sc.ServedDecoder(cell.config, args.seed, rec, ref_mod)
     hook = tracer.during if tracer else (lambda t0, t1: None)
@@ -177,8 +181,8 @@ def run_serve(cell, args, devs, peaks, rec):
     stats = sys_.engine.stats()
     say("engine: " + json.dumps({k: stats.get(k) for k in (
         "decode_attention_impl", "prefill_attention_impl",
-        "kv_cache_dtype", "prefill_chunk", "overlap", "compiles",
-        "decode_steps", "generated_tokens")}))
+        "kv_cache_dtype", "weight_dtype", "weight_bytes", "prefill_chunk",
+        "overlap", "compiles", "decode_steps", "generated_tokens")}))
     sys_.close()
     cen = sc.census(rec, win["t0"], win["t1"], len(win["finished"]))
     say("census: " + json.dumps(cen))
@@ -211,10 +215,8 @@ def run_serve(cell, args, devs, peaks, rec):
 # ----------------------------------------------------------------- training
 
 def run_train(cell, args, devs, peaks, rec):
-    import importlib
-    from harness import device, readers, train_cell as tc
-    ref_mod = importlib.import_module(
-        "reference." + cell.config["reference"])
+    from harness import device, manifest, readers, train_cell as tc
+    ref_mod = manifest.reference_of(cell.config)
     tracer = Tracer(rec, cell.name) if args.trace else None
     job = tc.TrainedClassifier(cell, args.seed, devs, rec, ref_mod)
     first = job.first_call()                  # compiles; is compared
@@ -255,8 +257,9 @@ def run_train(cell, args, devs, peaks, rec):
                                   host_data)
     numbers = tc.compare_first_call(first, ref)
     say(f"reference_s={time.perf_counter() - t_ref:.3f} over "
-        f"{cell.traffic['followed_steps']} steps; loss program "
-        f"{first['loss']:.6f} reference {ref['loss']:.6f}")
+        f"{len(ref['step_losses'])} + {cell.traffic['followed_steps']} "
+        f"steps; loss program {first['step_losses']} {first['loss']:.6f} "
+        f"reference {ref['step_losses']} {ref['loss']:.6f}")
     if args.control:
         low = tc.reference_first_call(cell, args.seed, devs, ref_mod,
                                       host_data, lower=True)

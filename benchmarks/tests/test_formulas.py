@@ -69,10 +69,20 @@ def test_decode_tick_floor_and_flops_against_the_ledger():
                                            "attended_positions": 0}) == 0.0
 
 
-def test_every_formula_a_metric_names_exists():
-    for name in os.listdir(os.path.join(BENCH, "metrics")):
-        with open(os.path.join(BENCH, "metrics", name)) as f:
-            spec = json.load(f)
-        formula = spec.get("params", {}).get("formula")
-        if formula is not None:
-            assert formula in formulas.FORMULAS, name
+def test_every_formula_a_metric_names_exists_for_each_of_its_cells():
+    """A bare name resolves in the formulas module of the cell's own
+    configuration, ``module:function`` where it says, for ``formula``
+    (``formula_share``) and ``work`` (``op_formula_share``) alike."""
+    from harness import manifest
+    bench = manifest.load_benchmark()
+    seen = 0
+    for w in bench["workloads"]:
+        cell = manifest.Cell(bench, w["name"])
+        for m in cell.per_layer():
+            for key in ("formula", "work"):
+                name = m.get("params", {}).get(key)
+                if name is not None:
+                    assert callable(manifest.formula(name, cell.config)), \
+                        (cell.name, m["name"], name)
+                    seen += 1
+    assert seen >= 7

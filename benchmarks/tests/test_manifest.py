@@ -97,9 +97,55 @@ def test_config_files_keep_the_published_widths(bench):
     assert by_name["mistral-7b-v0.3-d8"]["reduced"] == ["num_hidden_layers"]
 
 
-def test_a_later_pr_brings_a_formula_as_a_module_of_its_own():
+def test_a_formula_resolves_in_the_configurations_own_module():
     import formulas
-    f = readers.resolve("formulas:kv_bytes_per_token", formulas.FORMULAS)
-    assert f is formulas.kv_bytes_per_token
-    assert readers.resolve("decode_bytes", formulas.FORMULAS) \
+    import formulas_kernels
+    assert manifest.formula("decode_bytes", {}) is formulas.decode_bytes
+    assert manifest.formula("decode_bytes", {"formulas": "formulas"}) \
         is formulas.decode_bytes
+    # another module under benchmarks/ that a configuration names: a
+    # bare name is looked up there and nowhere else
+    cfg = {"formulas": "formulas_kernels"}
+    assert manifest.formula("paged_decode_bytes", cfg) \
+        is formulas_kernels.paged_decode_bytes
+    with pytest.raises(KeyError):
+        manifest.formula("decode_bytes", cfg)
+    # ``module:function`` is that function whatever the cell
+    assert manifest.formula("formulas:kv_bytes_per_token", cfg) \
+        is formulas.kv_bytes_per_token
+
+
+def test_every_configuration_names_its_adapter_reference_and_formulas():
+    bench = manifest.load_benchmark()
+    assert {c["name"] for c in bench["configs"]} >= {
+        "mistral-7b-v0.3-d8", "bert-base"}
+    for c in bench["configs"]:
+        with open(os.path.join(manifest.ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        adapter = manifest.adapter_of(cfg)
+        for duty in manifest.ADAPTER_DUTIES[cfg["kind"]]:
+            assert callable(getattr(adapter, duty)), (c["name"], duty)
+        ref = manifest.reference_of(cfg)
+        wanted = {"serve_decoder": ("make_params", "served_gaps", "free"),
+                  "train_classifier": ("make_params", "make_data",
+                                       "follow")}[cfg["kind"]]
+        for fn in wanted:
+            assert callable(getattr(ref, fn)), (c["name"], fn)
+        assert manifest.formulas_of(cfg).__name__ == cfg.get(
+            "formulas", "formulas")
+
+
+def test_an_adapter_that_lacks_a_duty_is_refused():
+    with pytest.raises(AttributeError, match="lacks"):
+        manifest.adapter_of({"kind": "train_classifier",
+                             "adapter": "llama_paged"})
+
+
+@pytest.mark.parametrize("path", ["harness/serve_cell.py",
+                                  "harness/train_cell.py", "run.py"])
+@pytest.mark.parametrize("word", ["LlamaConfig", "PagedLlamaModel", "BERT(",
+                                  "head_dim", "intermediate_size",
+                                  "zoo_tpu.pipeline.api.keras.layers"])
+def test_the_harness_names_no_architecture(path, word):
+    with open(os.path.join(BENCH, path)) as f:
+        assert word not in f.read()

@@ -27,7 +27,8 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 KEEP = ("census:", "engine:", "peak_bytes_in_use=", "reference_s=",
-        "compile cache:", "errors:", "late:", "modules:", "gc:")
+        "compile cache:", "errors:", "late:", "modules:", "gc:",
+        "idle_by_program_span:")
 
 
 def spread(values) -> float:

@@ -17,7 +17,6 @@ knee into the traffic file as ``rate_rps``.
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import os
 import statistics
@@ -53,8 +52,7 @@ def main() -> int:
         from zoo_tpu.common.compile_cache import ensure_compile_cache
         ensure_compile_cache()
     devs, _ = device.claim(cell.chips, args.rehearse_cpu)
-    ref_mod = importlib.import_module(
-        "reference." + cell.config["reference"])
+    ref_mod = manifest.reference_of(cell.config)
     rec = Recorder()
     sys_ = sc.ServedDecoder(cell.config, args.seed, rec, ref_mod)
     out_dir = os.path.join(ROOT, "chiprun_out")

@@ -747,6 +747,58 @@ def test_chunked_prefill_interleaves_with_decode():
     eng.stop()
 
 
+def test_only_a_prompts_last_chunk_is_waited_for():
+    """The scheduler waits for the device once a prompt, for the first
+    generated token its LAST chunk returns; a chunk before it is
+    dispatched and left to the device, which goes on with the pass's
+    decode tick behind it. A model whose chunk call returns a plain int
+    (every fake here) is served alike."""
+    events = []
+
+    class _Token:
+        def __init__(self, value, start):
+            self.value, self.start = value, start
+
+        def __int__(self):
+            events.append(("wait", self.start))
+            return self.value
+
+    class _Model(_FakeModel):
+        def prefill_chunk(self, chunk, start, total_len, row,
+                          sampling=None):
+            tok = super().prefill_chunk(chunk, start, total_len, row,
+                                        sampling)
+            events.append(("chunk", int(start)))
+            return _Token(tok, int(start))
+
+        def decode_step(self, *args):
+            events.append(("tick", None))
+            return super().decode_step(*args)
+
+    model = _Model(num_slots=2, num_blocks=64, max_blocks_per_seq=16,
+                   max_prompt_len=32, prefill_chunk=4,
+                   decode_delay=0.002)
+    eng = LLMEngine(model, overlap=True).start()
+    try:
+        a = eng.submit([1], 60)
+        while len(a.tokens) < 3:
+            time.sleep(0.001)
+        del events[:]
+        prompt = list(range(1, 13))          # 12 tokens = 3 chunks
+        b = eng.submit(prompt, 4)
+        _drain([a, b])
+    finally:
+        eng.stop()
+    assert a.tokens == _reference([1], 60)
+    assert b.tokens == _reference(prompt, 4)
+    assert [e for e in events if e[0] != "tick"] == [
+        ("chunk", 0), ("chunk", 4), ("chunk", 8), ("wait", 8)]
+    # a decode tick goes out between two chunks: the live stream keeps
+    # its pace while the prompt feeds
+    first, last = events.index(("chunk", 0)), events.index(("chunk", 8))
+    assert events[first:last].count(("tick", None)) >= 2
+
+
 def test_chunked_prefill_preemption_resets_cleanly():
     """A stream preempted MID-PREFILL re-queues with just its prompt
     (nothing generated yet) and completes correctly later."""

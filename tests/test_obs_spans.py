@@ -303,6 +303,67 @@ def test_jitted_steps_kernels_and_scopes_keep_their_names():
         assert scope in decode and scope in chunk, scope
 
 
+def test_the_latent_moe_step_keeps_the_names_and_counts_its_experts():
+    """The second architecture runs the same ``_decode_fn`` /
+    ``_prefill_chunk_fn`` under the scopes the accepted metric files
+    match, adds its own (``zoo.moe_route``, ``zoo.moe_experts``,
+    ``zoo.moe_shared``, the kernel ``zoo_mla_decode``), and its two
+    counters are in the catalog and move with a decode tick."""
+    from zoo_tpu.models.llm.glm_moe_lite import tiny_glm_moe_lite_config
+    from zoo_tpu.obs.catalog import METRICS
+    from zoo_tpu.serving.llm.model import PagedDecoderModel
+    from zoo_tpu.serving.llm.model_mla import PagedGlmMoeLiteModel
+    assert issubclass(PagedGlmMoeLiteModel, PagedDecoderModel)
+    for fn in ("_decode_fn", "_prefill_chunk_fn", "_prefill_fn",
+               "_verify_fn", "decode_step", "read_tokens",
+               "prefill_chunk"):
+        # the skeleton's, not a copy of them
+        assert getattr(PagedGlmMoeLiteModel, fn) \
+            is getattr(PagedDecoderModel, fn), fn
+    model = PagedGlmMoeLiteModel(
+        tiny_glm_moe_lite_config(64), num_slots=2, block_size=4,
+        num_blocks=16, max_blocks_per_seq=4, prefill_buckets=(8,),
+        prefill_chunk=8, kv_dtype="f32", decode_impl="flash", spec_k=0)
+    S, W, C = model.num_slots, model.max_blocks_per_seq, 8
+    lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
+             jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
+    decode = model._decode.lower(
+        model.params, model._cache, jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.ones(S, bool),
+        jnp.zeros((S, W), jnp.int32), jnp.zeros(S, jnp.int32),
+        *lanes).as_text(debug_info=True)
+    chunk = model._prefill_chunked.lower(
+        model.params, model._cache, jnp.zeros((1, C), jnp.int32),
+        jnp.int32(0), jnp.int32(C), jnp.zeros(W, jnp.int32),
+        jnp.float32(0), jnp.int32(0), jnp.float32(1),
+        jnp.uint32(0)).as_text(debug_info=True)
+    assert "jit(_decode_fn)" in decode
+    assert "jit(_prefill_chunk_fn)" in chunk
+    assert "zoo_mla_decode" in decode and "zoo_paged_decode" not in decode
+    for scope in ("zoo.attn_proj", "zoo.kv_append", "zoo.paged_attend",
+                  "zoo.mlp", "zoo.moe_route", "zoo.moe_experts",
+                  "zoo.moe_shared", "zoo.lm_head", "zoo.sample"):
+        assert scope in decode and scope in chunk, scope
+    # the new scopes lie inside zoo.mlp
+    assert "zoo.mlp/zoo.moe_experts" in decode
+    for name in ("zoo_llm_moe_expert_visits_total",
+                 "zoo_llm_moe_rows_total"):
+        assert METRICS[name] == ("counter", ())
+    visits0 = _counter("zoo_llm_moe_expert_visits_total")
+    rows0 = _counter("zoo_llm_moe_rows_total")
+    tables = np.zeros((S, W), np.int32)
+    tables[0, 0] = 3                       # one live lane, one idle
+    args = (np.ones(S, np.int32), np.ones(S, bool), tables,
+            np.zeros(S, np.int32), tuple(np.asarray(x) for x in lanes))
+    first = model.decode_step(None, *args)
+    # the counts travel with the tick's batch: the second tick chains on
+    # the first, and the first, never read, leaves nothing behind
+    model.read_tokens(model.decode_step(first, *args))
+    # one live lane x 2 choices x 2 expert layers, of the tick read
+    assert _counter("zoo_llm_moe_rows_total") - rows0 == 4
+    assert 2 <= _counter("zoo_llm_moe_expert_visits_total") - visits0 <= 4
+
+
 def test_the_attributes_the_harness_wraps_are_there():
     """``benchmarks/harness/serve_cell.py`` wraps these by name and
     reads ``decode_step``'s positional arguments 3 and 4."""

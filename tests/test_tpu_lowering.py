@@ -90,6 +90,54 @@ def test_every_exported_kernel_is_covered():
     assert covered == kernels, covered ^ kernels
 
 
+def test_mla_decode_compiles_at_the_cell_widths(v5e):
+    """``zoo_mla_decode`` at the widths and engine sizes of
+    ``glm-4.7-flash.longctx-closed`` (20 heads, rank 512 + rope 64 in
+    rows of 640, 7 layers x 18,432 blocks of 16, 544 table entries):
+    Mosaic takes it, and the whole cache goes in as it lies (no
+    relayout copy of the 2.6 GB operand in front of the kernel)."""
+    from zoo_tpu.ops.pallas.mla_decode import mla_paged_decode
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def call(ql, qr, cache, layer, bt, pos):
+        return mla_paged_decode(ql, qr, cache, layer, bt, pos,
+                                scale=256 ** -0.5, interpret=False)
+
+    hlo = jax.jit(call).lower(
+        sds((32, 20, 512), jnp.float32), sds((32, 20, 64), jnp.float32),
+        sds((7, 18432, 16, 640), jnp.bfloat16), sds((), jnp.int32),
+        sds((32, 544), jnp.int32), sds((32,), jnp.int32)
+    ).compile().as_text()
+    assert MOSAIC_CALL in hlo and "zoo_mla_decode" in hlo
+    whole = [ln for ln in hlo.splitlines()
+             if "= bf16[7,18432,16,640]" in ln and " parameter(" not in ln]
+    assert not whole, whole[:2]
+
+
+@pytest.mark.parametrize("rows,k,n", [(128, 2048, 1536),
+                                      (2048, 1536, 2048)])
+def test_moe_gmm_compiles_at_the_cell_widths(v5e, rows, k, n):
+    """``zoo_moe_gmm`` at GLM-4.7-Flash's expert widths (64 experts of
+    2048 x 1536) with a decode tick's 128 rows and a prefill chunk's
+    2,048: the experts' leaf goes in as it lies."""
+    from zoo_tpu.ops.pallas.moe_gmm import moe_gmm
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    hlo = jax.jit(lambda a, b, s: moe_gmm(a, b, s, interpret=False)).lower(
+        sds((rows, k), jnp.bfloat16), sds((64, k, n), jnp.bfloat16),
+        sds((64,), jnp.int32)).compile().as_text()
+    assert MOSAIC_CALL in hlo and "zoo_moe_gmm" in hlo
+    copies = [ln for ln in hlo.splitlines()
+              if f"= bf16[64,{k},{n}]" in ln and " parameter(" not in ln]
+    assert not copies, copies[:2]
+
+
 @pytest.mark.parametrize("kv", ["f32", "int8"])
 def test_paged_kernels_compile_under_two_way_shard_map(v5e, kv):
     """The tp=2 serving layout: cache and query heads split over two

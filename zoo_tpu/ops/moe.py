@@ -11,6 +11,14 @@ static for XLA).
 
 Functional core only; ``models/llm/moe_llama.py`` wires it into the
 Llama block.
+
+:func:`moe_ffn_dropless` is the serving step's layer: no capacity and no
+dropped token. Tokens are sorted by expert and one grouped matrix
+product (the Pallas kernel ``zoo_moe_gmm`` on a TPU,
+``jax.lax.ragged_dot`` elsewhere) runs over the experts that have rows,
+so a decode tick reads only the experts its tokens chose and a prefill
+chunk computes each token's own experts, with fixed shapes and one
+executable for any routing.
 """
 
 from __future__ import annotations
@@ -22,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["init_moe_params", "moe_ffn", "moe_param_specs",
-           "expert_capacity"]
+           "expert_capacity", "route_topk", "moe_ffn_dropless"]
 
 
 def expert_capacity(n_tokens: int, n_experts: int, top_k: int,
@@ -139,3 +147,96 @@ def moe_ffn(params: Dict, x: jnp.ndarray, *, top_k: int = 2,
     pm = (probs * valid[..., None]).sum((0, 1)) / denom
     aux = E * jnp.sum(f * pm) * aux_loss_weight
     return y.reshape(B, T, H), aux.astype(jnp.float32)
+
+
+def route_topk(x: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
+               top_k: int, scale: float = 1.0,
+               norm_topk: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sigmoid routing with a selection bias (``noaux_tc``): scores
+    ``s = sigmoid(x . router)`` in float32 at full precision; the
+    ``top_k`` experts are those of largest ``s + bias``; the weights are
+    ``s`` of the chosen (the bias changes the choice, never the
+    weight), renormalised to sum to 1 if ``norm_topk``, times
+    ``scale``. ``x`` (N, H) → ``(idx (N, k) int32, w (N, k) f32)``."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w * scale
+
+
+def _held_dot(x, w, **kw):
+    """``x . w`` in the dtype the weight is held in, f32 out."""
+    if w.dtype.itemsize < x.dtype.itemsize:
+        x = x.astype(w.dtype)
+    return jnp.matmul(x, w, preferred_element_type=jnp.float32, **kw)
+
+
+def _grouped_dot(rows, weights, sizes):
+    """``rows`` (M, K), sorted by expert, against each expert's own
+    ``weights`` (E, K, N); ``sizes`` (E,) rows an expert. The Pallas
+    kernel ``zoo_moe_gmm`` on a TPU, ``jax.lax.ragged_dot`` elsewhere
+    (where the kernel would run interpreted)."""
+    from zoo_tpu.ops.pallas import on_tpu
+    if on_tpu():
+        from zoo_tpu.ops.pallas.moe_gmm import moe_gmm
+        return moe_gmm(rows, weights, sizes)
+    return jax.lax.ragged_dot(rows, weights, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def moe_ffn_dropless(params: Dict, x: jnp.ndarray, *, top_k: int,
+                     scale: float = 1.0, norm_topk: bool = True,
+                     valid: Optional[jnp.ndarray] = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless routed SwiGLU experts plus the shared expert.
+
+    ``x``: (N, H) tokens → ``(y (N, H) float32, counts (2,) int32)``.
+    ``params``: ``router`` (H, E) and ``bias`` (E,) in float32,
+    ``w_gate`` / ``w_up`` (E, H, F) and ``w_down`` (E, F, H) in the
+    dtype they are held in, and, where the layer has a shared expert,
+    ``ws_gate`` / ``ws_up`` (H, Fs) and ``ws_down`` (Fs, H), added once
+    for every token.
+
+    Every token's ``top_k`` choices are computed: the N * k
+    (token, choice) rows are sorted by expert and the three grouped
+    products run over the rows of each expert in turn, whatever the
+    routing (all rows on one expert included). There is no capacity
+    and nothing is dropped. ``counts`` is (experts that had a row, rows)
+    over the tokens ``valid`` marks (default: all): what a decode tick
+    reports as ``zoo_llm_moe_expert_visits_total`` /
+    ``zoo_llm_moe_rows_total``; ``valid`` changes no output."""
+    N, H = x.shape
+    E = params["router"].shape[-1]
+    with jax.named_scope("zoo.moe_route"):
+        idx, w = route_topk(x, params["router"], params["bias"], top_k,
+                            scale, norm_topk)
+        flat = idx.reshape(N * top_k)
+        order = jnp.argsort(flat)            # stable: rows of one expert
+        inverse = jnp.argsort(order)         # keep their token order
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        rows = jnp.take(x, order // top_k, axis=0)       # (N * k, H)
+        seen = sizes if valid is None else jnp.zeros(
+            (E,), jnp.int32).at[flat].add(
+                jnp.repeat(valid.reshape(N).astype(jnp.int32), top_k))
+        counts = jnp.stack([jnp.sum(seen > 0),
+                            jnp.sum(seen)]).astype(jnp.int32)
+    with jax.named_scope("zoo.moe_experts"):
+        if params["w_gate"].dtype.itemsize < rows.dtype.itemsize:
+            rows = rows.astype(params["w_gate"].dtype)
+        act = jax.nn.silu(_grouped_dot(rows, params["w_gate"], sizes)) \
+            * _grouped_dot(rows, params["w_up"], sizes)
+        out = _grouped_dot(act.astype(rows.dtype), params["w_down"], sizes)
+        # back to token order, (N, k, H), and the weighted sum of each
+        # token's own choices
+        y = jnp.sum(jnp.take(out, inverse, axis=0).reshape(N, top_k, H)
+                    * w[..., None], axis=1)
+    if "ws_gate" in params:
+        with jax.named_scope("zoo.moe_shared"):
+            y = y + _held_dot(
+                jax.nn.silu(_held_dot(x, params["ws_gate"]))
+                * _held_dot(x, params["ws_up"]), params["ws_down"])
+    return y, counts

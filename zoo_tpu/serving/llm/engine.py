@@ -1440,6 +1440,15 @@ class LLMEngine:
                         tok = self.model.prefill_chunk(
                             prompt[s0:min(s0 + C, n)], s0, n, row,
                             sampling=h.sampling)
+                if start + take >= n:
+                    # the prompt's last chunk: wait for its first
+                    # generated token, the one host sync of the prefill
+                    # path. A chunk before it is never waited for (the
+                    # device stream orders it before whatever reads its
+                    # rows): the device goes on with what is queued
+                    # behind it, this pass's decode tick next
+                    with span("llm.model.prefill_sync"):
+                        tok = int(tok)
             except Exception as e:  # noqa: BLE001 — a prefill failure
                 # must end THIS stream loudly, not kill the scheduler
                 # thread with every stream hanging
@@ -2046,6 +2055,14 @@ class LLMEngine:
                             self._inflight.release()
                     if self._stop.is_set():
                         return
+                # bound the pipeline depth: at most 2 ticks in flight.
+                # The pass waits for its place BEFORE it feeds a prompt
+                # chunk, so that the chunk queues behind one tick and
+                # one chunk on the device and not behind two of each
+                with span("llm.tick.inflight_wait"):
+                    while not self._inflight.acquire(timeout=0.5):
+                        if self._stop.is_set():
+                            return
                 t0 = time.perf_counter()
                 with self._held("llm.tick.sweep_admit"):
                     self._sweep()
@@ -2062,6 +2079,7 @@ class LLMEngine:
                 self._span_tick_schedule(
                     t0, (t1 - t0) + (time.perf_counter() - t2))
                 if built is None:
+                    self._inflight.release()
                     # no decodable lane: break the device token chain
                     # (every post-idle admission is host-fed anyway)
                     prev_batch = None
@@ -2069,11 +2087,6 @@ class LLMEngine:
                         self._wake.wait(0.005)
                     self._wake.clear()
                     continue
-                # bound the pipeline depth: at most 2 ticks in flight
-                with span("llm.tick.inflight_wait"):
-                    while not self._inflight.acquire(timeout=0.5):
-                        if self._stop.is_set():
-                            return
                 t_d = time.perf_counter()
                 if self._spec:
                     # verify batches are host-fed (the accept length
@@ -2229,6 +2242,11 @@ class LLMEngine:
                         "served_tokens": self._tenant_served.get(t, 0),
                     } for t in sorted(names)}
         out.update(self.allocator.stats())
+        if hasattr(self.model, "moe_expert_visits"):
+            # a mixture of experts in the decode step: experts that had
+            # a live row and the rows computed, over all decode ticks
+            out["moe_expert_visits"] = self.model.moe_expert_visits
+            out["moe_rows"] = self.model.moe_rows
         if hasattr(self.model, "compile_counts"):
             out["compiles"] = self.model.compile_counts()
         return out

@@ -199,7 +199,8 @@ def _pick_bucket(buckets: Sequence[int], n: int) -> Optional[int]:
 DOT_BLOCK_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
-def narrow_dot_weights(params, platform: str):
+def narrow_dot_weights(params, platform: str,
+                       dot_leaves: Sequence[str] = None):
     """The weight tree as the serving executables should hold it on
     ``platform`` (docs/llm_serving.md, "Weights").
 
@@ -218,7 +219,10 @@ def narrow_dot_weights(params, platform: str):
     (there an f32 dot is an f32 dot) and every leaf when a higher
     matmul precision was asked for. Cast leaf by leaf — the transient
     is one leaf, not a second model — and the caller's arrays are
-    never deleted."""
+    never deleted. ``dot_leaves`` names an architecture's own dot
+    leaves (default: the Llama block's seven); ``params["blocks"]`` (and
+    ``params["lead"]``, layers that differ from the rest) may each be
+    one dict of stacked leaves or a list of per-layer dicts."""
     if platform != "tpu" or jax.config.jax_default_matmul_precision \
             not in (None, "default", "bfloat16"):
         return params
@@ -227,9 +231,19 @@ def narrow_dot_weights(params, platform: str):
         return leaf.astype(jnp.bfloat16) \
             if leaf.dtype == jnp.float32 else leaf
 
+    names = DOT_BLOCK_LEAVES if dot_leaves is None else dot_leaves
+
+    def narrow_block(block):
+        return {name: narrow(leaf) if name in names else leaf
+                for name, leaf in block.items()}
+
     out = dict(params)
-    out["blocks"] = {name: narrow(leaf) if name in DOT_BLOCK_LEAVES
-                     else leaf for name, leaf in params["blocks"].items()}
+    for key in ("blocks", "lead"):
+        if key in params:
+            # one dict of stacked leaves, or a list of per-layer dicts
+            out[key] = narrow_block(params[key]) \
+                if isinstance(params[key], dict) \
+                else [narrow_block(b) for b in params[key]]
     if "head" in params:
         out["head"] = narrow(params["head"])
     return out
@@ -329,16 +343,52 @@ def _sample_tokens(logits, temps, topks, topps, seeds, token_index):
                         lambda _: greedy, None)
 
 
-class PagedLlamaModel:
-    """Llama weights + paged KV cache + the serving executables.
+class _TickBatch:
+    """A dispatched decode tick whose executable counted something: the
+    on-device token batch and, beside it, the ``aux`` arrays of
+    ``_layers``. The engine hands it back untouched as ``prev_batch``
+    and to :meth:`PagedDecoderModel.read_tokens`."""
+
+    __slots__ = ("tokens", "aux")
+
+    def __init__(self, tokens, aux):
+        self.tokens, self.aux = tokens, aux
+
+
+class PagedDecoderModel:
+    """Decoder weights + a paged cache + the serving executables: the
+    skeleton every served architecture runs.
+
+    What is shared lives here: the four jitted bodies (``_decode_fn``,
+    ``_prefill_fn``, ``_prefill_chunk_fn``, ``_verify_fn``: token
+    select, embedding, the step's addressing through the block tables,
+    the head and on-device sampling), cache donation, operand transfer,
+    the host spans, ``copy_block`` and KV migration. What an
+    architecture varies is a handful of hooks a subclass gives:
+
+    * ``_init_params`` / ``DOT_LEAVES`` / ``_weight_probe`` — its
+      weight tree, the leaves that are only ever dot operands, and one
+      of them (``weight_dtype`` reports its dtype);
+    * ``_rope_dim`` — the rotated width of a head;
+    * ``_init_cache`` / ``_cache_shardings`` — the cache pytree (block
+      axis at position 1 of every leaf) and its bytes per token;
+    * ``_layers(params, cache, h, attend, at)`` — the layer stack: the
+      scan (and any leading layer outside it), the attention half
+      through ``attend`` — one of ``_attend_decode`` / ``_attend_bucket``
+      / ``_attend_chunk`` / ``_attend_verify`` — and the feed-forward
+      half; returns ``(h, cache, aux)`` where ``aux`` is a (possibly
+      empty) tuple of small arrays, per-tick device counts that ride
+      back with a decode tick's tokens (:meth:`_apply_tick_aux`).
 
     ``params=None`` builds deterministic weights from ``seed`` — every
-    replica of a ``llama:...`` spec holds bit-identical params, so
-    decode (greedy or seeded sampling) is reproducible across the
-    group (the property the HA client's failover-resume leans on).
+    replica of a spec holds bit-identical params, so decode (greedy or
+    seeded sampling) is reproducible across the group (the property
+    the HA client's failover-resume leans on).
     """
 
-    def __init__(self, config: LlamaConfig, *,
+    DOT_LEAVES = DOT_BLOCK_LEAVES
+
+    def __init__(self, config, *,
                  params=None, seed: int = 0,
                  num_slots: int = 8,
                  block_size: int = 16,
@@ -401,52 +451,27 @@ class PagedLlamaModel:
         self.tp = self.mesh.shape.get("model", 1) if self.mesh is not None \
             else 1
         c = config
-        if self.tp > 1:
-            if c.n_kv_head % self.tp or c.n_head % self.tp:
-                raise ValueError(
-                    f"tensor-parallel serving shards the KV cache on the "
-                    f"kv-head axis: n_kv_head ({c.n_kv_head}) and n_head "
-                    f"({c.n_head}) must divide by the model-axis size "
-                    f"({self.tp})")
-        layer = Llama(config, lm_head=True)
-        self.params = params if params is not None else layer.build(
-            jax.random.PRNGKey(seed), (None, self.prefill_buckets[-1]))
+        self._check_config()
+        self.params = self._init_params(params, seed)
         # held in the dtype the device's dot reads them in (bf16 on a
         # TPU, untouched elsewhere), before the mesh placement below
         self.params = narrow_dot_weights(
             self.params, (self.mesh.devices.flat[0] if self.mesh is not None
-                          else jax.devices()[0]).platform)
+                          else jax.devices()[0]).platform, self.DOT_LEAVES)
         # what the dot leaves are held as and what the whole tree costs
         # in HBM — ``llm_stats`` and the zoo_llm_weight_bytes gauge
-        self.weight_dtype = str(self.params["blocks"]["wq"].dtype)
+        self.weight_dtype = str(self._weight_probe().dtype)
         self.weight_bytes = int(sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params)))
         # rope tables over the whole pageable context, closed over by
-        # every executable (f32, tiny: max_context x head_dim/2)
+        # every executable (f32, tiny: max_context x rotated width/2)
         self._cos, self._sin = rope_frequencies(
-            c.head_dim, self.max_context, c.rope_theta)
-        shape = (c.n_block, self.num_blocks, c.n_kv_head,
-                 self.block_size, c.head_dim)
-        cache_np = {"f32": jnp.float32, "bf16": jnp.bfloat16,
-                    "int8": jnp.int8}[self.kv_cache_dtype]
-        self._cache = {"k": jnp.zeros(shape, cache_np),
-                       "v": jnp.zeros(shape, cache_np)}
-        if self.kv_cache_dtype == "int8":
-            # absmax scale per written cache ROW, stored block-indexed
-            # right beside the K/V blocks (the block table routes both)
-            sshape = (c.n_block, self.num_blocks, c.n_kv_head,
-                      self.block_size)
-            self._cache["ks"] = jnp.zeros(sshape, jnp.float32)
-            self._cache["vs"] = jnp.zeros(sshape, jnp.float32)
-        # HBM bytes ONE cached token costs (K+V rows over every layer,
-        # plus the scale rows for int8) — the engine republishes this
-        # as the zoo_llm_kv_bytes_per_token gauge and the bench's byte
-        # model reads it instead of hardcoding f32
-        item = {"f32": 4, "bf16": 2, "int8": 1}[self.kv_cache_dtype]
-        self.kv_bytes_per_token = (
-            2 * c.n_block * c.n_kv_head * c.head_dim * item
-            + (2 * c.n_block * c.n_kv_head * 4
-               if self.kv_cache_dtype == "int8" else 0))
+            self._rope_dim(), self.max_context, c.rope_theta)
+        # the cache pytree and the HBM bytes ONE cached token costs
+        # over every layer — the engine republishes the latter as the
+        # zoo_llm_kv_bytes_per_token gauge and the bench's byte model
+        # reads it instead of hardcoding a layout
+        self._cache, self.kv_bytes_per_token = self._init_cache()
         # chunk-executable width: the scheduling chunk when chunked
         # prefill is on, else the fixed suffix-feed width prefix-cache
         # hits use (compiles at most ONE chunk executable either way)
@@ -475,7 +500,6 @@ class PagedLlamaModel:
             self._copy = jax.jit(self._copy_block_fn,
                                  donate_argnums=(0,))
         else:
-            from jax.sharding import NamedSharding, PartitionSpec as P
             from zoo_tpu.parallel.mesh import (
                 publish_mesh_metrics,
                 replicated_sharding,
@@ -486,16 +510,7 @@ class PagedLlamaModel:
             self.params = place_params(self.params, self.mesh)
             rep = replicated_sharding(self.mesh)
             self._zero_tokens = jax.device_put(self._zero_tokens, rep)
-            # K/V blocks shard on the kv-head axis; int8 scale rows
-            # carry the same head axis and shard with their blocks
-            # (docs/multichip.md: the tp=N layout quantization keeps)
-            kv_sh = NamedSharding(
-                self.mesh, P(None, None, "model", None, None))
-            scale_sh = NamedSharding(
-                self.mesh, P(None, None, "model", None))
-            cache_sh = {"k": kv_sh, "v": kv_sh}
-            if self.kv_cache_dtype == "int8":
-                cache_sh["ks"] = cache_sh["vs"] = scale_sh
+            cache_sh = self._cache_shardings()
             self._cache = {name: jax.device_put(arr, cache_sh[name])
                            for name, arr in self._cache.items()}
             p_sh = shardings_of(self.params, self.mesh)
@@ -528,10 +543,588 @@ class PagedLlamaModel:
         # as jax reports it — ``llm_stats`` publishes this, so a replica
         # that came up on the host CPU, or a tp=N cache that landed on
         # one device, is visible from outside the process
-        devs = sorted(self._cache["k"].devices(), key=lambda d: d.id)
+        devs = sorted(next(iter(self._cache.values())).devices(),
+                      key=lambda d: d.id)
         self.device_info = {"platform": devs[0].platform,
                             "kind": devs[0].device_kind,
                             "ids": [int(d.id) for d in devs]}
+
+    def _copy_block_fn(self, cache, src, dst):
+        """Block ``src`` -> ``dst`` across every layer (K, V and scale
+        rows alike): the device half of copy-on-write — the allocator
+        forks the table entry, this moves the bytes."""
+        return {name: arr.at[:, dst].set(arr[:, src])
+                for name, arr in cache.items()}
+
+    @jax.named_scope("zoo.lm_head")
+    def _lm_head(self, params, h):
+        c = self.cfg
+        h = _rms_norm(h, params["final_norm"], c.rms_eps)
+        head = (params["embed"].T if c.tie_embeddings
+                else params["head"])
+        return _weight_dot(h, head)
+
+    # -- compiled bodies: the skeleton ---------------------------------------
+    def _decode_fn(self, params, cache, prev_tokens, host_tokens,
+                   use_host, block_tables, positions,
+                   temps, topks, topps, seeds):
+        """One token for every slot. The incoming token per slot is
+        either ``host_tokens`` (freshly admitted stream: the prefill's
+        first token) or ``prev_tokens`` — the PREVIOUS tick's on-device
+        output, so back-to-back ticks chain without a host round trip.
+        ``positions`` (S,) is the cache index the incoming token's
+        cache row is written at. Returns the SAMPLED next tokens
+        (device) and the updated cache pytree, and after them the
+        arrays of the tick's ``aux`` counts where the architecture has
+        any."""
+        tokens = jnp.where(use_host, host_tokens, prev_tokens)
+        h = jnp.take(params["embed"], tokens, axis=0)        # (S, hidden)
+        at = {"cos": jnp.take(self._cos, positions, axis=0),  # (S, D/2)
+              "sin": jnp.take(self._sin, positions, axis=0),
+              "blk": jnp.take_along_axis(
+                  block_tables, (positions // self.block_size)[:, None],
+                  axis=1)[:, 0],                              # (S,)
+              "off": positions % self.block_size,
+              "tables": block_tables, "pos": positions, "real": None}
+        h, cache, aux = self._layers(params, cache, h,
+                                     self._attend_decode, at)
+        logits = self._lm_head(params, h)                     # (S, vocab)
+        # the token being drawn sits at sequence index position+1
+        nxt = _sample_tokens(logits, temps, topks, topps, seeds,
+                             positions + 1)
+        return (nxt, cache, *aux)
+
+    def _prefill_fn(self, params, cache, ids, length, block_table,
+                    temp, topk, topp, seed):
+        """Causal forward over one padded prompt (1, L_bucket): scatter
+        the prompt's cache rows into the paged cache and return the
+        sampled first generated token. ``length`` is the true prompt
+        length (dynamic); pad positions write to the trash block and
+        are never attended by real tokens (they sit in the causal
+        future)."""
+        L = ids.shape[1]
+        pos = jnp.arange(L)
+        # pad positions → trash block 0 (their rows must not land in the
+        # sequence's real blocks: block ``pos // bs`` may be unallocated
+        # past the prompt's last block)
+        at = {"cos": self._cos[:L], "sin": self._sin[:L],
+              "blk": jnp.where(pos < length,
+                               block_table[pos // self.block_size], 0),
+              "off": pos % self.block_size,
+              "tables": block_table[None], "pos": pos[None],
+              "real": (pos < length)[None]}
+        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
+        h, cache, _ = self._layers(params, cache, h,
+                                   self._attend_bucket, at)
+        logits = self._lm_head(params, h)                  # (1, L, vocab)
+        last = jnp.take(logits[0], length - 1, axis=0)     # (vocab,)
+        # first generated token = sequence index ``length``
+        tok = _sample_row(last, temp, topk, topp, seed, length)
+        return tok, cache
+
+    def _prefill_chunk_fn(self, params, cache, ids, start, length,
+                          block_table, temp, topk, topp, seed):
+        """One fixed-size CHUNK of a prompt: write the chunk's cache
+        rows through the block table at positions ``start..start+C-1``
+        and attend each chunk token causally over everything already
+        resident (earlier chunks included) — the same math as the
+        bucket prefill, just fed through the cache in N-token slices.
+        Returns the sampled first generated token, meaningful only on
+        the chunk that contains the prompt's last real token (earlier
+        chunks sample from a mid-prompt row the engine discards)."""
+        C = ids.shape[1]
+        ctx = self.max_blocks_per_seq * self.block_size
+        pos = start + jnp.arange(C)                       # (C,)
+        real = pos < length
+        # pad rows past the pageable context must still take FINITE
+        # rope rows: jnp.take fills out-of-bounds with NaN, and a NaN
+        # K/V written to the trash block poisons every later layer
+        # through 0 * NaN in the masked attention. Real rows always
+        # sit below max_context, so the clamp never moves them.
+        pos = jnp.minimum(pos, ctx - 1)
+        # causal over the CACHE index space: chunk row i attends every
+        # resident position <= start+i (all real writes — earlier
+        # chunks plus this chunk's own prefix)
+        at = {"cos": jnp.take(self._cos, pos, axis=0),    # (C, D/2)
+              "sin": jnp.take(self._sin, pos, axis=0),
+              "blk": jnp.where(real,
+                               block_table[pos // self.block_size], 0),
+              "off": pos % self.block_size,
+              "tables": block_table[None], "pos": pos[None],
+              "real": real[None]}
+        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
+        h, cache, _ = self._layers(params, cache, h,
+                                   self._attend_chunk, at)
+        logits = self._lm_head(params, h)                 # (1, C, vocab)
+        last = jnp.take(logits[0],
+                        jnp.clip(length - 1 - start, 0, C - 1), axis=0)
+        tok = _sample_row(last, temp, topk, topp, seed, length)
+        return tok, cache
+
+    def _verify_fn(self, params, cache, tokens, block_tables,
+                   positions, temps, topks, topps, seeds):
+        """Speculative-decode VERIFY: score ``spec_k + 1`` candidate
+        tokens per slot in ONE device call. Row 0 of ``tokens`` (S, T)
+        is the slot's incoming token (the last emitted one), rows 1..
+        are the drafter's proposals; row ``j`` is written through the
+        block table at cache index ``positions[s] + j`` and attends
+        everything ``<= its position`` — so its logits are exactly what
+        sequential decode would compute after accepting rows ``< j``.
+        Each row then samples with the SAME stateless per-position key
+        non-speculative decode would use (``fold_in(seed, pos + j +
+        1)``), which is what makes the host's longest-accepted-prefix
+        emission byte-identical to plain decode, greedy and seeded
+        alike. Rejected rows' cache rows stay in place as garbage the
+        position mask hides until the next append overwrites them —
+        rollback is a pure length reset. Rows past the pageable
+        context write to the trash block (their outputs are never
+        accepted; the engine caps draft length to owned blocks)."""
+        S, T = tokens.shape
+        ctx = self.max_blocks_per_seq * self.block_size
+        raw = positions[:, None] + jnp.arange(T)[None, :]     # (S, T)
+        real = raw < ctx
+        # same finite-rope clamp as the chunk executable (a NaN K/V in
+        # the trash block would poison later layers through 0 * NaN)
+        pos = jnp.minimum(raw, ctx - 1)
+        at = {"cos": jnp.take(self._cos, pos, axis=0),    # (S, T, D/2)
+              "sin": jnp.take(self._sin, pos, axis=0),
+              "blk": jnp.where(
+                  real,
+                  jnp.take_along_axis(block_tables,
+                                      pos // self.block_size, axis=1),
+                  0),                                         # (S, T)
+              "off": pos % self.block_size,
+              "tables": block_tables, "pos": pos, "real": real}
+        h = jnp.take(params["embed"], tokens, axis=0)   # (S, T, hidden)
+        h, cache, _ = self._layers(params, cache, h,
+                                   self._attend_verify, at)
+        logits = self._lm_head(params, h)               # (S, T, vocab)
+        nxt = _sample_tokens(
+            logits.reshape(S * T, -1),
+            jnp.repeat(temps, T), jnp.repeat(topks, T),
+            jnp.repeat(topps, T), jnp.repeat(seeds, T),
+            (raw + 1).reshape(S * T)).reshape(S, T)
+        return nxt, cache
+
+    # -- host-facing API (what the engine calls) ---------------------------
+    @staticmethod
+    def _sampling_tuple(sampling) -> Tuple[float, int, float, int]:
+        if sampling is None:
+            return GREEDY
+        t, k, p, s = sampling
+        return float(t), int(k), float(p), int(s) & 0xFFFFFFFF
+
+    def prefill(self, prompt: np.ndarray, block_table_row: np.ndarray,
+                sampling=None) -> int:
+        """Run one prompt through its bucket executable; the prompt's
+        K/V land in the blocks listed in ``block_table_row``. Returns
+        the first generated token (sampled per ``sampling`` =
+        ``(temperature, top_k, top_p, seed)``; None = greedy)."""
+        n = int(prompt.shape[0])
+        bucket = _pick_bucket(self.prefill_buckets, n)
+        if bucket is None:
+            raise ValueError(
+                f"prompt of {n} tokens exceeds the largest prefill "
+                f"bucket ({self.prefill_buckets[-1]})")
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :n] = prompt
+        bt = np.asarray(block_table_row, np.int32)
+        if bt.shape != (self.max_blocks_per_seq,):
+            raise ValueError("block_table_row has the wrong width")
+        t, k, p, s = self._sampling_tuple(sampling)
+        with self._lock:
+            tok, self._cache = self._prefill(
+                self.params, self._cache, jnp.asarray(ids),
+                jnp.int32(n), jnp.asarray(bt), jnp.float32(t),
+                jnp.int32(k), jnp.float32(p), jnp.uint32(s))
+            out = int(tok)
+        _host_transfer.labels(kind="prefill").inc(4)
+        return out
+
+    def prefill_chunk(self, chunk: np.ndarray, start: int,
+                      total_len: int, block_table_row: np.ndarray,
+                      sampling=None):
+        """Dispatch ONE fixed-size chunk of a prompt (`start` = offset
+        of ``chunk[0]`` in the sequence) WITHOUT a host sync. Every
+        chunk call runs the same single executable regardless of prompt
+        length (width = ``suffix_chunk_size``: the scheduling chunk when
+        chunked prefill is on, the fixed suffix-feed width the prefix
+        cache uses otherwise). Returns the sampled first generated
+        token as a device scalar — meaningful only when this chunk
+        contains the prompt's last real token, and ``int()`` of it
+        blocks until the chunk has run: the caller takes it when it
+        needs it, after it has given the device its next work."""
+        C = self.suffix_chunk_size
+        n = int(chunk.shape[0])
+        if n < 1 or n > C:
+            raise ValueError(f"chunk of {n} tokens (chunk size {C})")
+        with span("llm.model.prefill_chunk"):
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :n] = chunk
+            bt = np.asarray(block_table_row, np.int32)
+            if bt.shape != (self.max_blocks_per_seq,):
+                raise ValueError("block_table_row has the wrong width")
+            t, k, p, s = self._sampling_tuple(sampling)
+            with self._lock:
+                tok, self._cache = self._prefill_chunked(
+                    self.params, self._cache, jnp.asarray(ids),
+                    jnp.int32(start), jnp.int32(total_len),
+                    jnp.asarray(bt), jnp.float32(t), jnp.int32(k),
+                    jnp.float32(p), jnp.uint32(s))
+            _host_transfer.labels(kind="prefill").inc(4)
+        return tok
+
+    def copy_block(self, src: int, dst: int):
+        """Device half of copy-on-write: duplicate block ``src`` into
+        ``dst`` (K, V and int8 scale rows, every layer) before a
+        sequence writes into its forked copy. One tiny fixed-shape
+        executable, compiled once."""
+        with self._lock:
+            self._cache = self._copy(self._cache, jnp.int32(src),
+                                     jnp.int32(dst))
+
+    # -- KV migration (docs/disaggregated_serving.md) ----------------------
+    def export_kv_blocks(self, blocks) -> dict:
+        """Host copies of the cache rows for ``blocks``, keyed like the
+        cache pytree (``k``/``v`` and the int8 scale rows), block axis
+        at position 1 in the order given — exactly the bytes a decode
+        replica's :meth:`import_kv_blocks` writes back, so a migrated
+        sequence decodes from bit-identical cache state. Under int8 the
+        wire pays 1 byte/row-element + the f32 scales (the on-device
+        quantization IS the wire compression). The gather runs under
+        the dispatch lock (the donated-cache arrays must not be
+        consumed by a concurrent tick mid-read); the returned arrays
+        are detached host copies."""
+        idx = jnp.asarray(list(blocks), jnp.int32)
+        with self._lock:
+            parts = {name: arr[:, idx] for name, arr in
+                     self._cache.items()}
+        return {name: np.asarray(part) for name, part in parts.items()}
+
+    def import_kv_blocks(self, blocks, data: dict, start: int = 0):
+        """Write exported cache rows into local ``blocks``:
+        ``data[name][:, start : start + len(blocks)]`` lands in block
+        ``blocks[i]`` — the adopting engine skips ``start`` leading
+        blocks it aliased from its own prefix cache instead. Runs
+        eagerly (plain scatters), so a pure-decode replica's traced
+        executable census is untouched."""
+        blocks = list(blocks)
+        if not blocks:
+            return
+        missing = set(self._cache) - set(data)
+        if missing:
+            raise ValueError(
+                f"kv payload is missing cache planes {sorted(missing)} "
+                f"(this cache is {self.kv_cache_dtype})")
+        idx = jnp.asarray(blocks, jnp.int32)
+        stop = start + len(blocks)
+        with self._lock:
+            for name, arr in self._cache.items():
+                rows = jnp.asarray(np.asarray(data[name])[:, start:stop],
+                                   arr.dtype)
+                self._cache[name] = arr.at[:, idx].set(rows)
+
+    def decode_step(self, prev_batch, host_tokens: np.ndarray,
+                    use_host: np.ndarray, block_tables: np.ndarray,
+                    positions: np.ndarray, sampling_lanes):
+        """Dispatch ONE continuous-batching iteration WITHOUT a host
+        sync: returns the on-device (S,) token batch, which the next
+        tick accepts back as ``prev_batch`` (slots whose ``use_host``
+        lane is set take ``host_tokens`` instead — fresh admissions).
+        ``sampling_lanes`` = (temps, topks, topps, seeds) arrays, one
+        lane per slot. The donated-cache chain sequences back-to-back
+        dispatches on the device stream; only :meth:`read_tokens`
+        blocks."""
+        temps, topks, topps, seeds = sampling_lanes
+        with self._lock:
+            if prev_batch is None:
+                prev_batch = self._zero_tokens
+            elif isinstance(prev_batch, _TickBatch):
+                prev_batch = prev_batch.tokens
+            with span("llm.model.h2d"):
+                operands = (
+                    jnp.asarray(prev_batch, jnp.int32),
+                    jnp.asarray(host_tokens, jnp.int32),
+                    jnp.asarray(use_host, bool),
+                    jnp.asarray(block_tables, jnp.int32),
+                    jnp.asarray(positions, jnp.int32),
+                    jnp.asarray(temps, jnp.float32),
+                    jnp.asarray(topks, jnp.int32),
+                    jnp.asarray(topps, jnp.float32),
+                    jnp.asarray(seeds, jnp.uint32))
+            with span("llm.model.launch"):
+                out, self._cache, *aux = self._decode(
+                    self.params, self._cache, *operands)
+            # the tick's device counts travel with its token batch and
+            # are read with it (:meth:`read_tokens`); a tick that is
+            # dropped unread takes them along
+            return _TickBatch(out, aux) if aux else out
+
+    def verify_step(self, tokens: np.ndarray,
+                    block_tables: np.ndarray, positions: np.ndarray,
+                    sampling_lanes):
+        """Dispatch ONE speculative verify pass WITHOUT a host sync:
+        ``tokens`` (num_slots, spec_k + 1) candidate rows per slot
+        (row 0 = the incoming token, rows 1.. = drafted continuations,
+        zero-padded), written through the block tables starting at each
+        slot's ``positions`` entry. Returns the on-device
+        (num_slots, spec_k + 1) batch of per-position canonical tokens
+        — :meth:`read_tokens` blocks on it and the engine emits the
+        longest accepted prefix. ONE fixed shape, compiled once."""
+        tokens = np.asarray(tokens, np.int32)
+        if self.spec_k < 1:
+            raise RuntimeError("verify_step needs spec_k >= 1 at "
+                               "model construction")
+        if tokens.shape != (self.num_slots, self.spec_k + 1):
+            raise ValueError(
+                f"verify batch {tokens.shape} != the fixed "
+                f"{(self.num_slots, self.spec_k + 1)} census shape")
+        temps, topks, topps, seeds = sampling_lanes
+        with self._lock:
+            out, self._cache = self._verify(
+                self.params, self._cache, jnp.asarray(tokens),
+                jnp.asarray(block_tables, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                jnp.asarray(temps, jnp.float32),
+                jnp.asarray(topks, jnp.int32),
+                jnp.asarray(topps, jnp.float32),
+                jnp.asarray(seeds, jnp.uint32))
+            return out
+
+    def read_tokens(self, batch) -> np.ndarray:
+        """Block until a dispatched tick's token batch is on the host.
+        This is the ONLY device->host transfer of the decode hot path:
+        slots x 1 int32 ids (the logits never leave the device)."""
+        aux = None
+        if isinstance(batch, _TickBatch):
+            batch, aux = batch.tokens, batch.aux
+        arr = np.asarray(batch)
+        _host_transfer.labels(kind="tokens").inc(int(arr.nbytes))
+        if aux is not None:
+            # same executable as the tokens: already on its way, no
+            # further wait for the device
+            self._apply_tick_aux(jax.device_get(aux))
+        return arr
+
+    def _apply_tick_aux(self, aux):
+        """What a decode tick counted on the device, now on the host:
+        an architecture that returns ``aux`` from ``_layers`` adds it
+        to its counters here (the readback thread calls this)."""
+
+    def decode(self, tokens: np.ndarray, block_tables: np.ndarray,
+               positions: np.ndarray, sampling_lanes=None) -> np.ndarray:
+        """Synchronous decode tick (the pre-overlap contract, kept for
+        the request-level baseline and white-box tests): every slot's
+        incoming token comes from the host, the sampled batch is read
+        straight back."""
+        S = self.num_slots
+        if sampling_lanes is None:
+            sampling_lanes = (np.zeros(S, np.float32),
+                              np.zeros(S, np.int32),
+                              np.ones(S, np.float32),
+                              np.zeros(S, np.uint32))
+        batch = self.decode_step(None, tokens, np.ones(S, bool),
+                                 block_tables, positions, sampling_lanes)
+        return self.read_tokens(batch)
+
+    def donated_cache_leaves(self) -> int:
+        """Leaves of the donated cache pytree — every one must appear
+        in a compiled executable's ``input_output_alias`` table (the
+        zoo-lint HLO-DONATION contract: a dropped donation doubles
+        resident KV bytes and is invisible at runtime)."""
+        return len(jax.tree_util.tree_leaves(self._cache))
+
+    def compiled_hlo(self, which: str = "decode") -> Optional[str]:
+        """Optimized HLO text of the ``decode`` or ``verify``
+        executable, lowered with this model's exact census signature
+        (and explicit shardings under tp=N) — the input to the
+        zoo-lint donation / host-transfer / sharding checks. Returns
+        None when the executable does not exist (``verify`` with
+        spec_k=0)."""
+        S = self.num_slots
+
+        def sds(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt)
+
+        def avals(tree):
+            return jax.tree_util.tree_map(
+                lambda x: sds(jnp.shape(x), x.dtype), tree)
+
+        lanes = (sds((S,), jnp.float32), sds((S,), jnp.int32),
+                 sds((S,), jnp.float32), sds((S,), jnp.uint32))
+        tables = sds((S, self.max_blocks_per_seq), jnp.int32)
+        positions = sds((S,), jnp.int32)
+        if which == "decode":
+            args = (avals(self.params), avals(self._cache),
+                    sds((S,), jnp.int32), sds((S,), jnp.int32),
+                    sds((S,), jnp.bool_), tables, positions, *lanes)
+            fn = self._decode
+        elif which == "verify":
+            if self.spec_k < 1:
+                return None
+            args = (avals(self.params), avals(self._cache),
+                    sds((S, self.spec_k + 1), jnp.int32), tables,
+                    positions, *lanes)
+            fn = self._verify
+        else:
+            raise ValueError(f"unknown executable {which!r} "
+                             "(decode / verify)")
+        return fn.lower(*args).compile().as_text()
+
+    def compile_counts(self) -> dict:
+        """Executable counts per compiled function — the no-recompile
+        guarantee is asserted against these (decode must stay at 1
+        after warmup; prefill at <= len(buckets); the chunked prefill
+        at <= 1)."""
+        def size(fn):
+            try:
+                return int(fn._cache_size())
+            except Exception:  # noqa: BLE001 — private API moved
+                return -1
+        return {"decode": size(self._decode),
+                "prefill": size(self._prefill),
+                "prefill_chunk": size(self._prefill_chunked),
+                "verify": size(self._verify),
+                "copy_block": size(self._copy)}
+
+
+
+
+class PagedLlamaModel(PagedDecoderModel):
+    """Llama-shaped blocks under the skeleton: pre-norm, grouped-query
+    attention over a per-head K/V paged cache
+    ``(n_layer, num_blocks, n_kv_head, block, head_dim)`` (int8 with
+    per-row scale planes, bf16 or f32), a dense SwiGLU feed-forward,
+    every block alike under one ``lax.scan`` whose ``xs`` carry the
+    weights and the layer's cache slices."""
+
+    # -- the hooks -----------------------------------------------------------
+    def _check_config(self):
+        c = self.cfg
+        if self.tp > 1:
+            if c.n_kv_head % self.tp or c.n_head % self.tp:
+                raise ValueError(
+                    f"tensor-parallel serving shards the KV cache on the "
+                    f"kv-head axis: n_kv_head ({c.n_kv_head}) and n_head "
+                    f"({c.n_head}) must divide by the model-axis size "
+                    f"({self.tp})")
+
+    def _init_params(self, params, seed):
+        if params is not None:
+            return params
+        return Llama(self.cfg, lm_head=True).build(
+            jax.random.PRNGKey(seed), (None, self.prefill_buckets[-1]))
+
+    def _weight_probe(self):
+        return self.params["blocks"]["wq"]
+
+    def _rope_dim(self) -> int:
+        return self.cfg.head_dim
+
+    def _init_cache(self):
+        c = self.cfg
+        shape = (c.n_block, self.num_blocks, c.n_kv_head,
+                 self.block_size, c.head_dim)
+        cache_np = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+                    "int8": jnp.int8}[self.kv_cache_dtype]
+        cache = {"k": jnp.zeros(shape, cache_np),
+                 "v": jnp.zeros(shape, cache_np)}
+        if self.kv_cache_dtype == "int8":
+            # absmax scale per written cache ROW, stored block-indexed
+            # right beside the K/V blocks (the block table routes both)
+            sshape = (c.n_block, self.num_blocks, c.n_kv_head,
+                      self.block_size)
+            cache["ks"] = jnp.zeros(sshape, jnp.float32)
+            cache["vs"] = jnp.zeros(sshape, jnp.float32)
+        # K+V rows over every layer, plus the scale rows for int8
+        item = {"f32": 4, "bf16": 2, "int8": 1}[self.kv_cache_dtype]
+        per_token = (2 * c.n_block * c.n_kv_head * c.head_dim * item
+                     + (2 * c.n_block * c.n_kv_head * 4
+                        if self.kv_cache_dtype == "int8" else 0))
+        return cache, per_token
+
+    def _cache_shardings(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        # K/V blocks shard on the kv-head axis; int8 scale rows carry
+        # the same head axis and shard with their blocks
+        # (docs/multichip.md: the tp=N layout quantization keeps)
+        kv_sh = NamedSharding(
+            self.mesh, P(None, None, "model", None, None))
+        scale_sh = NamedSharding(
+            self.mesh, P(None, None, "model", None))
+        cache_sh = {"k": kv_sh, "v": kv_sh}
+        if self.kv_cache_dtype == "int8":
+            cache_sh["ks"] = cache_sh["vs"] = scale_sh
+        return cache_sh
+
+    def _layers(self, params, cache, h, attend, at):
+        """Every block alike under one scan: norm, q/k/v, the step's
+        ``attend`` (rope, append, attention), output projection, MLP."""
+        c = self.cfg
+
+        def layer(h, xs):
+            p, *cl = self._unpack_xs(xs)
+            x = _rms_norm(h, p["attn_norm"], c.rms_eps)
+            q, k, v = self._attn_proj(p, x)
+            a, cl = attend(q, k, v, cl, at)
+            h = h + _weight_dot(a, p["wo"])
+            return self._mlp(p, h), self._layer_ys(*cl)
+
+        h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
+        return h, self._repack_cache(ys), ()
+
+    def _attend_decode(self, q, k, v, cl, at):
+        kcl, vcl, ksl, vsl = cl
+        # rope at each slot's own position (per-slot angle rows)
+        q = _rope_rows(q, at["cos"], at["sin"])
+        k = _rope_rows(k, at["cos"], at["sin"])
+        # write this token's k/v through the block table (narrowed per
+        # the cache dtype), THEN attend — the token attends to itself
+        # like any other
+        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"], k)
+        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"], v)
+        o = self._paged_attend(q, kcl, vcl, ksl, vsl, at["tables"],
+                               at["pos"])
+        return o, (kcl, vcl, ksl, vsl)
+
+    def _attend_bucket(self, q, k, v, cl, at):
+        c = self.cfg
+        kcl, vcl, ksl, vsl = cl
+        L = q.shape[1]
+        q = apply_rope(q.transpose(0, 2, 1, 3), at["cos"], at["sin"])
+        k = apply_rope(k.transpose(0, 2, 1, 3), at["cos"], at["sin"])
+        v = v.transpose(0, 2, 1, 3)
+        a = dot_product_attention(
+            q, k, v, causal=True, impl=resolve_attention_impl("auto", L),
+            mesh=self.mesh)
+        a = a.transpose(0, 2, 1, 3).reshape(1, L, c.n_head * c.head_dim)
+        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"],
+                                     k.transpose(0, 2, 1, 3)[0])
+        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"],
+                                     v.transpose(0, 2, 1, 3)[0])
+        return a, (kcl, vcl, ksl, vsl)
+
+    def _attend_chunk(self, q, k, v, cl, at):
+        kcl, vcl, ksl, vsl = cl
+        q = _rope_rows(q[0], at["cos"], at["sin"])[None]
+        k = _rope_rows(k[0], at["cos"], at["sin"])[None]
+        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"], k[0])
+        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"], v[0])
+        # flash streams the table, dense gathers it
+        a = self._prefill_attend(q, kcl, vcl, ksl, vsl, at["tables"],
+                                 at["pos"])
+        return a, (kcl, vcl, ksl, vsl)
+
+    def _attend_verify(self, q, k, v, cl, at):
+        kcl, vcl, ksl, vsl = cl
+        q = _rope_rows(q, at["cos"], at["sin"])
+        k = _rope_rows(k, at["cos"], at["sin"])
+        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"], k)
+        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"], v)
+        a = self._prefill_attend(q, kcl, vcl, ksl, vsl, at["tables"],
+                                 at["pos"])
+        return a, (kcl, vcl, ksl, vsl)
 
     # test/debug views of the cache arrays (the canonical home is the
     # donated ``self._cache`` pytree)
@@ -600,14 +1193,6 @@ class PagedLlamaModel:
         B, W, n_kv, bs, D = g.shape
         return g.transpose(0, 1, 3, 2, 4).reshape(B, W * bs, n_kv, D)
 
-    def _copy_block_fn(self, cache, src, dst):
-        """Block ``src`` -> ``dst`` across every layer (K, V and scale
-        rows alike): the device half of copy-on-write — the allocator
-        forks the table entry, this moves the bytes."""
-        return {name: arr.at[:, dst].set(arr[:, src])
-                for name, arr in cache.items()}
-
-    # -- compiled bodies ---------------------------------------------------
     @jax.named_scope("zoo.attn_proj")
     def _attn_proj(self, p, x):
         """Shared q/k/v projection + head split for every executable."""
@@ -626,14 +1211,6 @@ class PagedLlamaModel:
         x = _rms_norm(h, p["mlp_norm"], c.rms_eps)
         return h + _weight_dot(jax.nn.silu(_weight_dot(x, p["w_gate"]))
                                * _weight_dot(x, p["w_up"]), p["w_down"])
-
-    @jax.named_scope("zoo.lm_head")
-    def _lm_head(self, params, h):
-        c = self.cfg
-        h = _rms_norm(h, params["final_norm"], c.rms_eps)
-        head = (params["embed"].T if c.tie_embeddings
-                else params["head"])
-        return _weight_dot(h, head)
 
     def _on_model_axis(self, kernel, q, q_spec, kcl, vcl, ksl, vsl,
                        block_tables, positions, pos_spec, scale):
@@ -761,474 +1338,6 @@ class PagedLlamaModel:
         probs = jax.nn.softmax(s, axis=-1).astype(vals.dtype)
         return jnp.einsum("rkgt,rtkd->rkgd", probs, vals).reshape(
             R, c.n_head * c.head_dim)
-
-    def _decode_fn(self, params, cache, prev_tokens, host_tokens,
-                   use_host, block_tables, positions,
-                   temps, topks, topps, seeds):
-        """One token for every slot. The incoming token per slot is
-        either ``host_tokens`` (freshly admitted stream: the prefill's
-        first token) or ``prev_tokens`` — the PREVIOUS tick's on-device
-        output, so back-to-back ticks chain without a host round trip.
-        ``positions`` (S,) is the cache index the incoming token's K/V
-        are written at. Returns the SAMPLED next tokens (device) and
-        the updated cache pytree."""
-        c = self.cfg
-        S = self.num_slots
-        tokens = jnp.where(use_host, host_tokens, prev_tokens)
-        h = jnp.take(params["embed"], tokens, axis=0)        # (S, hidden)
-        cos = jnp.take(self._cos, positions, axis=0)          # (S, D/2)
-        sin = jnp.take(self._sin, positions, axis=0)
-        blk = jnp.take_along_axis(
-            block_tables, (positions // self.block_size)[:, None],
-            axis=1)[:, 0]                                     # (S,)
-        off = positions % self.block_size
-
-        def layer(h, xs):
-            p, kcl, vcl, ksl, vsl = self._unpack_xs(xs)
-            x = _rms_norm(h, p["attn_norm"], c.rms_eps)
-            q, k, v = self._attn_proj(p, x)
-            # rope at each slot's own position (per-slot angle rows)
-            q = _rope_rows(q, cos, sin)
-            k = _rope_rows(k, cos, sin)
-            # write this token's k/v through the block table (narrowed
-            # per the cache dtype), THEN attend — the token attends to
-            # itself like any other
-            kcl, ksl = self._append_rows(kcl, ksl, blk, off, k)
-            vcl, vsl = self._append_rows(vcl, vsl, blk, off, v)
-            o = self._paged_attend(q, kcl, vcl, ksl, vsl,
-                                   block_tables, positions)
-            h = h + _weight_dot(o, p["wo"])
-            return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
-
-        h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
-        cache = self._repack_cache(ys)
-        logits = self._lm_head(params, h)                     # (S, vocab)
-        # the token being drawn sits at sequence index position+1
-        nxt = _sample_tokens(logits, temps, topks, topps, seeds,
-                             positions + 1)
-        return nxt, cache
-
-    def _prefill_fn(self, params, cache, ids, length, block_table,
-                    temp, topk, topp, seed):
-        """Causal forward over one padded prompt (1, L_bucket): scatter
-        the prompt's K/V into the paged cache and return the sampled
-        first generated token. ``length`` is the true prompt length
-        (dynamic); pad positions write to the trash block and are never
-        attended by real tokens (they sit in the causal future)."""
-        c = self.cfg
-        L = ids.shape[1]
-        pos = jnp.arange(L)
-        cos, sin = self._cos[:L], self._sin[:L]
-        # pad positions → trash block 0 (their k/v must not land in the
-        # sequence's real blocks: block ``pos // bs`` may be unallocated
-        # past the prompt's last block)
-        blk = jnp.where(pos < length,
-                        block_table[pos // self.block_size], 0)
-        off = pos % self.block_size
-        impl = resolve_attention_impl("auto", L)
-
-        def layer(h, xs):
-            p, kcl, vcl, ksl, vsl = self._unpack_xs(xs)
-            x = _rms_norm(h, p["attn_norm"], c.rms_eps)
-            q, k, v = self._attn_proj(p, x)                   # (1,L,H,D)
-            q = apply_rope(q.transpose(0, 2, 1, 3), cos, sin)
-            k = apply_rope(k.transpose(0, 2, 1, 3), cos, sin)
-            v = v.transpose(0, 2, 1, 3)
-            a = dot_product_attention(q, k, v, causal=True, impl=impl,
-                                      mesh=self.mesh)
-            a = a.transpose(0, 2, 1, 3).reshape(1, L,
-                                                c.n_head * c.head_dim)
-            h = h + _weight_dot(a, p["wo"])
-            kcl, ksl = self._append_rows(kcl, ksl, blk, off,
-                                         k.transpose(0, 2, 1, 3)[0])
-            vcl, vsl = self._append_rows(vcl, vsl, blk, off,
-                                         v.transpose(0, 2, 1, 3)[0])
-            return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
-
-        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
-        h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
-        cache = self._repack_cache(ys)
-        logits = self._lm_head(params, h)                  # (1, L, vocab)
-        last = jnp.take(logits[0], length - 1, axis=0)     # (vocab,)
-        # first generated token = sequence index ``length``
-        tok = _sample_row(last, temp, topk, topp, seed, length)
-        return tok, cache
-
-    def _prefill_chunk_fn(self, params, cache, ids, start, length,
-                          block_table, temp, topk, topp, seed):
-        """One fixed-size CHUNK of a prompt: write the chunk's K/V
-        through the block table at positions ``start..start+C-1`` and
-        attend each chunk token causally over everything already
-        resident (earlier chunks included) — the same math as the
-        bucket prefill, just fed through the cache in N-token slices.
-        Returns the sampled first generated token, meaningful only on
-        the chunk that contains the prompt's last real token (earlier
-        chunks sample from a mid-prompt row the engine discards)."""
-        c = self.cfg
-        C = ids.shape[1]
-        ctx = self.max_blocks_per_seq * self.block_size
-        pos = start + jnp.arange(C)                       # (C,)
-        real = pos < length
-        # pad rows past the pageable context must still take FINITE
-        # rope rows: jnp.take fills out-of-bounds with NaN, and a NaN
-        # K/V written to the trash block poisons every later layer
-        # through 0 * NaN in the masked attention. Real rows always
-        # sit below max_context, so the clamp never moves them.
-        pos = jnp.minimum(pos, ctx - 1)
-        cos = jnp.take(self._cos, pos, axis=0)            # (C, D/2)
-        sin = jnp.take(self._sin, pos, axis=0)
-        blk = jnp.where(real, block_table[pos // self.block_size], 0)
-        off = pos % self.block_size
-        def layer(h, xs):
-            p, kcl, vcl, ksl, vsl = self._unpack_xs(xs)
-            x = _rms_norm(h, p["attn_norm"], c.rms_eps)
-            q, k, v = self._attn_proj(p, x)               # (1, C, H, D)
-            q = _rope_rows(q[0], cos, sin)[None]
-            k = _rope_rows(k[0], cos, sin)[None]
-            kcl, ksl = self._append_rows(kcl, ksl, blk, off, k[0])
-            vcl, vsl = self._append_rows(vcl, vsl, blk, off, v[0])
-            # causal over the CACHE index space: chunk row i attends
-            # every resident position <= start+i (all real writes —
-            # earlier chunks plus this chunk's own prefix); flash
-            # streams the table, dense gathers it
-            a = self._prefill_attend(q, kcl, vcl, ksl, vsl,
-                                     block_table[None], pos[None])
-            h = h + _weight_dot(a, p["wo"])
-            return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
-
-        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
-        h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
-        cache = self._repack_cache(ys)
-        logits = self._lm_head(params, h)                 # (1, C, vocab)
-        last = jnp.take(logits[0],
-                        jnp.clip(length - 1 - start, 0, C - 1), axis=0)
-        tok = _sample_row(last, temp, topk, topp, seed, length)
-        return tok, cache
-
-    def _verify_fn(self, params, cache, tokens, block_tables,
-                   positions, temps, topks, topps, seeds):
-        """Speculative-decode VERIFY: score ``spec_k + 1`` candidate
-        tokens per slot in ONE device call. Row 0 of ``tokens`` (S, T)
-        is the slot's incoming token (the last emitted one), rows 1..
-        are the drafter's proposals; row ``j`` is written through the
-        block table at cache index ``positions[s] + j`` and attends
-        everything ``<= its position`` — so its logits are exactly what
-        sequential decode would compute after accepting rows ``< j``.
-        Each row then samples with the SAME stateless per-position key
-        non-speculative decode would use (``fold_in(seed, pos + j +
-        1)``), which is what makes the host's longest-accepted-prefix
-        emission byte-identical to plain decode, greedy and seeded
-        alike. Rejected rows' K/V stay in place as garbage the
-        position mask hides until the next append overwrites them —
-        rollback is a pure length reset. Rows past the pageable
-        context write to the trash block (their outputs are never
-        accepted; the engine caps draft length to owned blocks)."""
-        c = self.cfg
-        S, T = tokens.shape
-        ctx = self.max_blocks_per_seq * self.block_size
-        raw = positions[:, None] + jnp.arange(T)[None, :]     # (S, T)
-        real = raw < ctx
-        # same finite-rope clamp as the chunk executable (a NaN K/V in
-        # the trash block would poison later layers through 0 * NaN)
-        pos = jnp.minimum(raw, ctx - 1)
-        cos = jnp.take(self._cos, pos, axis=0)            # (S, T, D/2)
-        sin = jnp.take(self._sin, pos, axis=0)
-        blk = jnp.where(
-            real,
-            jnp.take_along_axis(block_tables, pos // self.block_size,
-                                axis=1), 0)                   # (S, T)
-        off = pos % self.block_size
-
-        def layer(h, xs):
-            p, kcl, vcl, ksl, vsl = self._unpack_xs(xs)
-            x = _rms_norm(h, p["attn_norm"], c.rms_eps)
-            q, k, v = self._attn_proj(p, x)               # (S, T, H, D)
-            q = _rope_rows(q, cos, sin)
-            k = _rope_rows(k, cos, sin)
-            kcl, ksl = self._append_rows(kcl, ksl, blk, off, k)
-            vcl, vsl = self._append_rows(vcl, vsl, blk, off, v)
-            a = self._prefill_attend(q, kcl, vcl, ksl, vsl,
-                                     block_tables, pos)
-            h = h + _weight_dot(a, p["wo"])
-            return self._mlp(p, h), self._layer_ys(kcl, vcl, ksl, vsl)
-
-        h = jnp.take(params["embed"], tokens, axis=0)   # (S, T, hidden)
-        h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
-        cache = self._repack_cache(ys)
-        logits = self._lm_head(params, h)               # (S, T, vocab)
-        nxt = _sample_tokens(
-            logits.reshape(S * T, -1),
-            jnp.repeat(temps, T), jnp.repeat(topks, T),
-            jnp.repeat(topps, T), jnp.repeat(seeds, T),
-            (raw + 1).reshape(S * T)).reshape(S, T)
-        return nxt, cache
-
-    # -- host-facing API (what the engine calls) ---------------------------
-    @staticmethod
-    def _sampling_tuple(sampling) -> Tuple[float, int, float, int]:
-        if sampling is None:
-            return GREEDY
-        t, k, p, s = sampling
-        return float(t), int(k), float(p), int(s) & 0xFFFFFFFF
-
-    def prefill(self, prompt: np.ndarray, block_table_row: np.ndarray,
-                sampling=None) -> int:
-        """Run one prompt through its bucket executable; the prompt's
-        K/V land in the blocks listed in ``block_table_row``. Returns
-        the first generated token (sampled per ``sampling`` =
-        ``(temperature, top_k, top_p, seed)``; None = greedy)."""
-        n = int(prompt.shape[0])
-        bucket = _pick_bucket(self.prefill_buckets, n)
-        if bucket is None:
-            raise ValueError(
-                f"prompt of {n} tokens exceeds the largest prefill "
-                f"bucket ({self.prefill_buckets[-1]})")
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = prompt
-        bt = np.asarray(block_table_row, np.int32)
-        if bt.shape != (self.max_blocks_per_seq,):
-            raise ValueError("block_table_row has the wrong width")
-        t, k, p, s = self._sampling_tuple(sampling)
-        with self._lock:
-            tok, self._cache = self._prefill(
-                self.params, self._cache, jnp.asarray(ids),
-                jnp.int32(n), jnp.asarray(bt), jnp.float32(t),
-                jnp.int32(k), jnp.float32(p), jnp.uint32(s))
-            out = int(tok)
-        _host_transfer.labels(kind="prefill").inc(4)
-        return out
-
-    def prefill_chunk(self, chunk: np.ndarray, start: int,
-                      total_len: int, block_table_row: np.ndarray,
-                      sampling=None) -> int:
-        """Feed ONE fixed-size chunk of a prompt (`start` = offset of
-        ``chunk[0]`` in the sequence). Every chunk call runs the same
-        single executable regardless of prompt length (width =
-        ``suffix_chunk_size``: the scheduling chunk when chunked
-        prefill is on, the fixed suffix-feed width the prefix cache
-        uses otherwise). Returns the sampled first generated token —
-        meaningful only when this chunk contains the prompt's last
-        real token."""
-        C = self.suffix_chunk_size
-        n = int(chunk.shape[0])
-        if n < 1 or n > C:
-            raise ValueError(f"chunk of {n} tokens (chunk size {C})")
-        with span("llm.model.prefill_chunk"):
-            ids = np.zeros((1, C), np.int32)
-            ids[0, :n] = chunk
-            bt = np.asarray(block_table_row, np.int32)
-            if bt.shape != (self.max_blocks_per_seq,):
-                raise ValueError("block_table_row has the wrong width")
-            t, k, p, s = self._sampling_tuple(sampling)
-            with self._lock:
-                with span("llm.model.prefill_launch"):
-                    tok, self._cache = self._prefill_chunked(
-                        self.params, self._cache, jnp.asarray(ids),
-                        jnp.int32(start), jnp.int32(total_len),
-                        jnp.asarray(bt), jnp.float32(t), jnp.int32(k),
-                        jnp.float32(p), jnp.uint32(s))
-                # the scheduler thread blocks here until the chunk has
-                # run: the one host sync of the prefill path
-                with span("llm.model.prefill_sync"):
-                    out = int(tok)
-            _host_transfer.labels(kind="prefill").inc(4)
-        return out
-
-    def copy_block(self, src: int, dst: int):
-        """Device half of copy-on-write: duplicate block ``src`` into
-        ``dst`` (K, V and int8 scale rows, every layer) before a
-        sequence writes into its forked copy. One tiny fixed-shape
-        executable, compiled once."""
-        with self._lock:
-            self._cache = self._copy(self._cache, jnp.int32(src),
-                                     jnp.int32(dst))
-
-    # -- KV migration (docs/disaggregated_serving.md) ----------------------
-    def export_kv_blocks(self, blocks) -> dict:
-        """Host copies of the cache rows for ``blocks``, keyed like the
-        cache pytree (``k``/``v`` and the int8 scale rows), block axis
-        at position 1 in the order given — exactly the bytes a decode
-        replica's :meth:`import_kv_blocks` writes back, so a migrated
-        sequence decodes from bit-identical cache state. Under int8 the
-        wire pays 1 byte/row-element + the f32 scales (the on-device
-        quantization IS the wire compression). The gather runs under
-        the dispatch lock (the donated-cache arrays must not be
-        consumed by a concurrent tick mid-read); the returned arrays
-        are detached host copies."""
-        idx = jnp.asarray(list(blocks), jnp.int32)
-        with self._lock:
-            parts = {name: arr[:, idx] for name, arr in
-                     self._cache.items()}
-        return {name: np.asarray(part) for name, part in parts.items()}
-
-    def import_kv_blocks(self, blocks, data: dict, start: int = 0):
-        """Write exported cache rows into local ``blocks``:
-        ``data[name][:, start : start + len(blocks)]`` lands in block
-        ``blocks[i]`` — the adopting engine skips ``start`` leading
-        blocks it aliased from its own prefix cache instead. Runs
-        eagerly (plain scatters), so a pure-decode replica's traced
-        executable census is untouched."""
-        blocks = list(blocks)
-        if not blocks:
-            return
-        missing = set(self._cache) - set(data)
-        if missing:
-            raise ValueError(
-                f"kv payload is missing cache planes {sorted(missing)} "
-                f"(this cache is {self.kv_cache_dtype})")
-        idx = jnp.asarray(blocks, jnp.int32)
-        stop = start + len(blocks)
-        with self._lock:
-            for name, arr in self._cache.items():
-                rows = jnp.asarray(np.asarray(data[name])[:, start:stop],
-                                   arr.dtype)
-                self._cache[name] = arr.at[:, idx].set(rows)
-
-    def decode_step(self, prev_batch, host_tokens: np.ndarray,
-                    use_host: np.ndarray, block_tables: np.ndarray,
-                    positions: np.ndarray, sampling_lanes):
-        """Dispatch ONE continuous-batching iteration WITHOUT a host
-        sync: returns the on-device (S,) token batch, which the next
-        tick accepts back as ``prev_batch`` (slots whose ``use_host``
-        lane is set take ``host_tokens`` instead — fresh admissions).
-        ``sampling_lanes`` = (temps, topks, topps, seeds) arrays, one
-        lane per slot. The donated-cache chain sequences back-to-back
-        dispatches on the device stream; only :meth:`read_tokens`
-        blocks."""
-        temps, topks, topps, seeds = sampling_lanes
-        with self._lock:
-            if prev_batch is None:
-                prev_batch = self._zero_tokens
-            with span("llm.model.h2d"):
-                operands = (
-                    jnp.asarray(prev_batch, jnp.int32),
-                    jnp.asarray(host_tokens, jnp.int32),
-                    jnp.asarray(use_host, bool),
-                    jnp.asarray(block_tables, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(topks, jnp.int32),
-                    jnp.asarray(topps, jnp.float32),
-                    jnp.asarray(seeds, jnp.uint32))
-            with span("llm.model.launch"):
-                out, self._cache = self._decode(
-                    self.params, self._cache, *operands)
-            return out
-
-    def verify_step(self, tokens: np.ndarray,
-                    block_tables: np.ndarray, positions: np.ndarray,
-                    sampling_lanes):
-        """Dispatch ONE speculative verify pass WITHOUT a host sync:
-        ``tokens`` (num_slots, spec_k + 1) candidate rows per slot
-        (row 0 = the incoming token, rows 1.. = drafted continuations,
-        zero-padded), written through the block tables starting at each
-        slot's ``positions`` entry. Returns the on-device
-        (num_slots, spec_k + 1) batch of per-position canonical tokens
-        — :meth:`read_tokens` blocks on it and the engine emits the
-        longest accepted prefix. ONE fixed shape, compiled once."""
-        tokens = np.asarray(tokens, np.int32)
-        if self.spec_k < 1:
-            raise RuntimeError("verify_step needs spec_k >= 1 at "
-                               "model construction")
-        if tokens.shape != (self.num_slots, self.spec_k + 1):
-            raise ValueError(
-                f"verify batch {tokens.shape} != the fixed "
-                f"{(self.num_slots, self.spec_k + 1)} census shape")
-        temps, topks, topps, seeds = sampling_lanes
-        with self._lock:
-            out, self._cache = self._verify(
-                self.params, self._cache, jnp.asarray(tokens),
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(temps, jnp.float32),
-                jnp.asarray(topks, jnp.int32),
-                jnp.asarray(topps, jnp.float32),
-                jnp.asarray(seeds, jnp.uint32))
-            return out
-
-    def read_tokens(self, batch) -> np.ndarray:
-        """Block until a dispatched tick's token batch is on the host.
-        This is the ONLY device->host transfer of the decode hot path:
-        slots x 1 int32 ids (the logits never leave the device)."""
-        arr = np.asarray(batch)
-        _host_transfer.labels(kind="tokens").inc(int(arr.nbytes))
-        return arr
-
-    def decode(self, tokens: np.ndarray, block_tables: np.ndarray,
-               positions: np.ndarray, sampling_lanes=None) -> np.ndarray:
-        """Synchronous decode tick (the pre-overlap contract, kept for
-        the request-level baseline and white-box tests): every slot's
-        incoming token comes from the host, the sampled batch is read
-        straight back."""
-        S = self.num_slots
-        if sampling_lanes is None:
-            sampling_lanes = (np.zeros(S, np.float32),
-                              np.zeros(S, np.int32),
-                              np.ones(S, np.float32),
-                              np.zeros(S, np.uint32))
-        batch = self.decode_step(None, tokens, np.ones(S, bool),
-                                 block_tables, positions, sampling_lanes)
-        return self.read_tokens(batch)
-
-    def donated_cache_leaves(self) -> int:
-        """Leaves of the donated cache pytree — every one must appear
-        in a compiled executable's ``input_output_alias`` table (the
-        zoo-lint HLO-DONATION contract: a dropped donation doubles
-        resident KV bytes and is invisible at runtime)."""
-        return len(jax.tree_util.tree_leaves(self._cache))
-
-    def compiled_hlo(self, which: str = "decode") -> Optional[str]:
-        """Optimized HLO text of the ``decode`` or ``verify``
-        executable, lowered with this model's exact census signature
-        (and explicit shardings under tp=N) — the input to the
-        zoo-lint donation / host-transfer / sharding checks. Returns
-        None when the executable does not exist (``verify`` with
-        spec_k=0)."""
-        S = self.num_slots
-
-        def sds(shape, dt):
-            return jax.ShapeDtypeStruct(shape, dt)
-
-        def avals(tree):
-            return jax.tree_util.tree_map(
-                lambda x: sds(jnp.shape(x), x.dtype), tree)
-
-        lanes = (sds((S,), jnp.float32), sds((S,), jnp.int32),
-                 sds((S,), jnp.float32), sds((S,), jnp.uint32))
-        tables = sds((S, self.max_blocks_per_seq), jnp.int32)
-        positions = sds((S,), jnp.int32)
-        if which == "decode":
-            args = (avals(self.params), avals(self._cache),
-                    sds((S,), jnp.int32), sds((S,), jnp.int32),
-                    sds((S,), jnp.bool_), tables, positions, *lanes)
-            fn = self._decode
-        elif which == "verify":
-            if self.spec_k < 1:
-                return None
-            args = (avals(self.params), avals(self._cache),
-                    sds((S, self.spec_k + 1), jnp.int32), tables,
-                    positions, *lanes)
-            fn = self._verify
-        else:
-            raise ValueError(f"unknown executable {which!r} "
-                             "(decode / verify)")
-        return fn.lower(*args).compile().as_text()
-
-    def compile_counts(self) -> dict:
-        """Executable counts per compiled function — the no-recompile
-        guarantee is asserted against these (decode must stay at 1
-        after warmup; prefill at <= len(buckets); the chunked prefill
-        at <= 1)."""
-        def size(fn):
-            try:
-                return int(fn._cache_size())
-            except Exception:  # noqa: BLE001 — private API moved
-                return -1
-        return {"decode": size(self._decode),
-                "prefill": size(self._prefill),
-                "prefill_chunk": size(self._prefill_chunked),
-                "verify": size(self._verify),
-                "copy_block": size(self._copy)}
 
 
 def _rope_rows(x: jnp.ndarray, cos: jnp.ndarray,

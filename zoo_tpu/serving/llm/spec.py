@@ -18,6 +18,14 @@ is the llm half of that resolution:
 * ``llama:vocab=256,hidden=64,n_block=2,n_head=4,n_kv_head=2,``
   ``intermediate=128`` — explicit architecture, no preset.
 
+* ``glm_moe_lite:tiny:slots=4,block=8,blocks=96,tables=8,chunk=8`` —
+  the second architecture (``glm4_moe_lite``: latent attention, a
+  dropless mixture of experts; ``serving/llm/model_mla.py``): the same
+  engine keys, its own architecture keys (``vocab``, ``hidden``,
+  ``n_block``, ``n_head``, ``q_rank``, ``kv_rank``, ``nope``, ``rope``,
+  ``v_dim``, ``intermediate``, ``moe_intermediate``, ``experts``,
+  ``shared``, ``top_k``, ``dense``).
+
 Engine knobs resolve env (``ZOO_LLM_*``) < spec < explicit kwargs —
 the env is the deployment-wide default, an explicit spec component
 overrides it; the env names are documented in docs/llm_serving.md.
@@ -29,6 +37,17 @@ import os
 from typing import Dict, Optional, Tuple
 
 LLM_PREFIX = "llama:"
+GLM_PREFIX = "glm_moe_lite:"
+# spec key → GlmMoeLiteConfig field
+_GLM_ARCH_KEYS = {"vocab": "vocab", "hidden": "hidden",
+                  "n_block": "n_block", "n_head": "n_head",
+                  "q_rank": "q_lora_rank", "kv_rank": "kv_lora_rank",
+                  "nope": "qk_nope_head_dim", "rope": "qk_rope_head_dim",
+                  "v_dim": "v_head_dim", "intermediate": "intermediate",
+                  "moe_intermediate": "moe_intermediate",
+                  "experts": "n_routed_experts",
+                  "shared": "n_shared_experts",
+                  "top_k": "num_experts_per_tok", "dense": "first_k_dense"}
 # jax-free deterministic engine (chaos smokes / transport benches):
 # synthllm:slots=2,block=4,blocks=64,tables=8 — the generate-path twin
 # of the predict path's synthetic:double (see llm/synthetic.py)
@@ -53,7 +72,7 @@ _STR_KEYS = {"kv": "kv_dtype", "prefill_impl": "prefill_impl",
 
 def is_llm_spec(spec) -> bool:
     return isinstance(spec, str) and spec.startswith(
-        (LLM_PREFIX, SYNTH_LLM_PREFIX))
+        (LLM_PREFIX, GLM_PREFIX, SYNTH_LLM_PREFIX))
 
 
 def _parse_kv(parts) -> Dict[str, str]:
@@ -72,26 +91,36 @@ def _parse_kv(parts) -> Dict[str, str]:
 
 
 def parse_llm_spec(spec: str) -> Tuple[Dict, Dict]:
-    """``(config_kwargs, engine_kwargs)`` from a ``llama:...`` spec."""
+    """``(config_kwargs, engine_kwargs)`` from a ``llama:...`` or
+    ``glm_moe_lite:...`` spec."""
     if not is_llm_spec(spec):
         raise ValueError(f"not an llm spec: {spec!r}")
-    body = spec[len(LLM_PREFIX):]
+    glm = spec.startswith(GLM_PREFIX)
+    body = spec[len(GLM_PREFIX if glm else LLM_PREFIX):]
     parts = body.split(":") if body else [""]
     preset = parts[0] if parts[0] and "=" not in parts[0] else None
     kvs = _parse_kv(parts[1:] if preset else parts)
+    arch_keys = _GLM_ARCH_KEYS if glm else {k: k for k in _ARCH_KEYS}
 
     cfg_kwargs: Dict = {}
     if preset == "tiny" or preset is None and not any(
-            k in kvs for k in _ARCH_KEYS):
-        from zoo_tpu.models.llm.llama import tiny_llama_config
-        cfg_kwargs = dict(tiny_llama_config().__dict__)
+            k in kvs for k in arch_keys):
+        if glm:
+            from zoo_tpu.models.llm.glm_moe_lite import (
+                tiny_glm_moe_lite_config as tiny_config,
+            )
+        else:
+            from zoo_tpu.models.llm.llama import (
+                tiny_llama_config as tiny_config,
+            )
+        cfg_kwargs = dict(tiny_config().__dict__)
         cfg_kwargs.pop("tie_embeddings", None)
     elif preset is not None and preset != "tiny":
-        raise ValueError(f"unknown llama preset {preset!r} "
+        raise ValueError(f"unknown llm preset {preset!r} "
                          "(supported: tiny, or explicit key=value dims)")
-    for k in _ARCH_KEYS:
+    for k, field in arch_keys.items():
         if k in kvs:
-            cfg_kwargs[k] = int(kvs.pop(k))
+            cfg_kwargs[field] = int(kvs.pop(k))
 
     eng: Dict = {}
     for short, name in _ENGINE_KEYS.items():
@@ -104,7 +133,7 @@ def parse_llm_spec(spec: str) -> Tuple[Dict, Dict]:
         eng["prefill_buckets"] = tuple(
             int(b) for b in kvs.pop("buckets").split("/"))
     if kvs:
-        raise ValueError(f"unknown llama spec keys {sorted(kvs)}")
+        raise ValueError(f"unknown llm spec keys {sorted(kvs)}")
     return cfg_kwargs, eng
 
 
@@ -159,14 +188,22 @@ def build_synthetic_engine(spec: str, mode: Optional[str] = None,
 def build_llm_engine(spec: str, mode: Optional[str] = None,
                      start: bool = True, **overrides):
     """An :class:`LLMEngine` (started unless ``start=False``) from a
-    ``llama:...`` or ``synthllm:...`` spec. ``overrides`` are
+    ``llama:...``, ``glm_moe_lite:...`` or ``synthllm:...`` spec. ``overrides`` are
     engine/model kwargs that win over both the spec and the env."""
     if spec.startswith(SYNTH_LLM_PREFIX):
         return build_synthetic_engine(spec, mode=mode, start=start,
                                       **overrides)
-    from zoo_tpu.models.llm.llama import LlamaConfig
     from zoo_tpu.serving.llm.engine import LLMEngine
-    from zoo_tpu.serving.llm.model import PagedLlamaModel
+    if spec.startswith(GLM_PREFIX):
+        from zoo_tpu.models.llm.glm_moe_lite import (
+            GlmMoeLiteConfig as Config,
+        )
+        from zoo_tpu.serving.llm.model_mla import (
+            PagedGlmMoeLiteModel as Model,
+        )
+    else:
+        from zoo_tpu.models.llm.llama import LlamaConfig as Config
+        from zoo_tpu.serving.llm.model import PagedLlamaModel as Model
 
     cfg_kwargs, eng_kwargs = parse_llm_spec(spec)
     merged = dict(_env_engine_defaults())
@@ -191,7 +228,7 @@ def build_llm_engine(spec: str, mode: Optional[str] = None,
     # role is a SCHEDULER policy (prefill parks / decode adopts), not a
     # model shape: spec `role=` < ZOO_LLM_ROLE env in the engine
     role = merged.pop("role", None)
-    cfg = LlamaConfig(**cfg_kwargs)
+    cfg = Config(**cfg_kwargs)
     # tensor-parallel serving: `tp=N` (spec) / ZOO_LLM_TP (env) / a
     # `mesh=` override span ONE model over N local devices instead of
     # replicating it (docs/multichip.md)
@@ -206,7 +243,7 @@ def build_llm_engine(spec: str, mode: Optional[str] = None,
                 f"llama spec asks for tp={tp} but only {len(devs)} "
                 "local device(s) are visible")
         merged["mesh"] = build_mesh(devs[:tp], axis_sizes={"model": tp})
-    model = PagedLlamaModel(cfg, **merged)
+    model = Model(cfg, **merged)
     from zoo_tpu.common.knobs import value as knob_value
     mode = mode or knob_value("ZOO_LLM_MODE")
     engine = LLMEngine(model, mode=mode,

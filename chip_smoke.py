@@ -440,7 +440,8 @@ TOL_EXACT = 1e-5         # integer accumulation or elementwise f32 only (0)
 
 def _dense_paged_ref(q, kc, vc, bt, pos, ks=None, vs=None):
     """Gather-then-attend reference for both paged kernels. ``q`` is
-    (S, C, H, D) with per-row positions ``pos`` (S, C)."""
+    (S, C, H, D) with per-row positions ``pos`` (S, C); a block's
+    scales are one head-major row, (num_blocks, 1, n_kv * block)."""
     import jax
     import jax.numpy as jnp
     S, C, H, D = q.shape
@@ -450,7 +451,7 @@ def _dense_paged_ref(q, kc, vc, bt, pos, ks=None, vs=None):
     def gather(cache, scale):
         g = cache[bt].astype(jnp.float32)       # (S, W, n_kv, bs, D)
         if scale is not None:
-            g = g * scale[bt][..., None]
+            g = g * scale[bt].reshape(S, W, n_kv, bs, 1)
         return g.transpose(0, 1, 3, 2, 4).reshape(S, W * bs, n_kv, D)
 
     keys, vals = gather(kc, ks), gather(vc, vs)
@@ -478,12 +479,14 @@ def _paged_cases(sz: Sizes, interpret) -> List[KernelCase]:
     T = sz.llm_spec_k + 1
     ctx = W * bs
 
-    def make(n_seq, C, kv, starts):
+    def make(n_seq, C, kv, starts, layers=()):
+        """``layers=(n,)`` stacks n layers' caches (and scale planes)
+        and appends the layer to attend as the last argument."""
         def _make():
             rs = np.random.RandomState(C)
             q = rs.randn(n_seq, C, H, D).astype(np.float32)
-            kc = rs.randn(nb, n_kv, bs, D).astype(np.float32)
-            vc = rs.randn(nb, n_kv, bs, D).astype(np.float32)
+            kc = rs.randn(*layers, nb, n_kv, bs, D).astype(np.float32)
+            vc = rs.randn(*layers, nb, n_kv, bs, D).astype(np.float32)
             bt = np.stack([rs.permutation(np.arange(1, nb))[:W]
                            for _ in range(n_seq)]).astype(np.int32)
             pos = np.minimum(np.asarray(starts[:n_seq])[:, None]
@@ -494,24 +497,38 @@ def _paged_cases(sz: Sizes, interpret) -> List[KernelCase]:
                 out[1] = jnp.asarray(kc, jnp.bfloat16)
                 out[2] = jnp.asarray(vc, jnp.bfloat16)
             elif kv == "int8":
-                ks = np.asarray(absmax_scale(kc, axis=-1))
-                vs = np.asarray(absmax_scale(vc, axis=-1))
+                ks = np.asarray(absmax_scale(kc, axis=-1), np.float32)
+                vs = np.asarray(absmax_scale(vc, axis=-1), np.float32)
                 out[1] = narrow_int8(kc, ks[..., None])
                 out[2] = narrow_int8(vc, vs[..., None])
-                out += [ks.astype(np.float32), vs.astype(np.float32)]
+                # a block's scales: one head-major row
+                out += [ks.reshape(*layers, nb, 1, n_kv * bs),
+                        vs.reshape(*layers, nb, 1, n_kv * bs)]
+            if layers:
+                out.append(np.int32(layers[0] - 1))
             return out
         return _make
 
     def scales(sc):
         return dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
 
-    def decode(q, kc, vc, bt, pos, *sc):
+    def decode(q, kc, vc, bt, pos, *sc, layer=None):
         return paged_flash_decode(q[:, 0], kc, vc, bt, pos[:, 0],
-                                  interpret=interpret, **scales(sc))[:, None]
+                                  layer=layer, interpret=interpret,
+                                  **scales(sc))[:, None]
 
-    def prefill(q, kc, vc, bt, pos, *sc):
-        return paged_flash_prefill(q, kc, vc, bt, pos,
+    def prefill(q, kc, vc, bt, pos, *sc, layer=None):
+        return paged_flash_prefill(q, kc, vc, bt, pos, layer=layer,
                                    interpret=interpret, **scales(sc))
+
+    # the serving step's form: the whole stacked cache and a traced layer
+    def at_layer(kernel):
+        return lambda q, kc, vc, bt, pos, *sc: kernel(
+            q, kc, vc, bt, pos, *sc[:-1], layer=sc[-1])
+
+    def stacked_ref(q, kc, vc, bt, pos, *sc):
+        return _dense_paged_ref(q, kc[sc[-1]], vc[sc[-1]], bt, pos,
+                                *(s[sc[-1]] for s in sc[:-1]))
 
     # slot positions: the first token, both sides of a block edge, mid
     # table, the last column
@@ -531,6 +548,14 @@ def _paged_cases(sz: Sizes, interpret) -> List[KernelCase]:
                        make(S, T, kv, spread), prefill, _dense_paged_ref,
                        tol),
         ]
+    cases += [
+        KernelCase("paged_flash_decode[stacked,int8]",
+                   make(S, 1, "int8", spread, layers=(3,)),
+                   at_layer(decode), stacked_ref, TOL_INT8_KV),
+        KernelCase("paged_flash_prefill[stacked,int8]",
+                   make(1, sz.llm_chunk, "int8", [ctx // 2], layers=(3,)),
+                   at_layer(prefill), stacked_ref, TOL_INT8_KV),
+    ]
     return cases
 
 

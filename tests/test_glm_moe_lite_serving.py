@@ -396,7 +396,8 @@ def test_spec_builds_the_architecture():
 
 # tokens, bytes a token and cache layout of `llama:tiny:seed=3` as the
 # parent commit (097c5f8) served them on this spec, before the skeleton
-# was factored out of PagedLlamaModel
+# was factored out of PagedLlamaModel (the int8 scale planes as PR 29
+# holds them: a block's scales are one head-major row)
 _BEFORE = {
     "f32": ([[162, 162, 162, 162, 162, 162, 0, 127],
              [183, 127, 207, 26, 26, 26, 133, 196],
@@ -412,9 +413,9 @@ _BEFORE = {
               [183, 127, 207, 26, 26, 26, 133, 196],
               [251, 58, 105, 234, 183, 157, 58, 183]], 160,
              {"k": ((2, 64, 2, 8, 16), "int8"),
-              "ks": ((2, 64, 2, 8), "float32"),
+              "ks": ((2, 64, 1, 16), "float32"),
               "v": ((2, 64, 2, 8, 16), "int8"),
-              "vs": ((2, 64, 2, 8), "float32")}),
+              "vs": ((2, 64, 1, 16), "float32")}),
 }
 
 

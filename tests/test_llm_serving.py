@@ -1363,8 +1363,10 @@ def test_kv_dtype_resolution_and_bytes_model():
     import jax.numpy as jnp
     assert i8._kc.dtype == jnp.int8
     assert bf16._kc.dtype == jnp.bfloat16
-    assert i8._cache["ks"].shape == (c.n_block, i8.num_blocks,
-                                     c.n_kv_head, i8.block_size)
+    # a block's scales are one head-major row (what the TPU holds
+    # as declared once it is 128 wide)
+    assert i8._cache["ks"].shape == (c.n_block, i8.num_blocks, 1,
+                                     c.n_kv_head * i8.block_size)
 
 
 # ----------------------- weights held in the dtype the dot reads (PR 26)

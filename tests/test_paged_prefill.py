@@ -99,22 +99,63 @@ def test_kernel_int8_dequant_matches_dense_widen():
                      bt, pos)
     out = paged_flash_prefill(
         q, jnp.asarray(kq), jnp.asarray(vq), bt, pos,
-        k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs),
+        k_scale=jnp.asarray(ks.reshape(nb, 1, -1)),
+        v_scale=jnp.asarray(vs.reshape(nb, 1, -1)),
         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+def test_kernel_reads_a_stacked_cache_at_a_traced_layer(kv):
+    """What the chunk and verify executables run: the WHOLE
+    (n_layer, ...) cache goes in and the layer is a traced scalar
+    inside ``lax.scan``; each layer's output is the dense reference of
+    ``cache[layer]``."""
+    from zoo_tpu.util.quantize import absmax_scale, narrow_int8, \
+        widen_int8
+
+    rs = np.random.RandomState(6)
+    L, S, C, H, n_kv, D, nb, bs, W = 3, 2, 4, 4, 2, 16, 10, 4, 4
+    q = jnp.asarray(rs.randn(S, C, H, D).astype(np.float32))
+    kc = rs.randn(L, nb, n_kv, bs, D).astype(np.float32)
+    vc = rs.randn(L, nb, n_kv, bs, D).astype(np.float32)
+    bt = jnp.asarray(rs.randint(1, nb, (S, W)).astype(np.int32))
+    pos = jnp.asarray(np.array([[0, 1, 2, 3], [9, 10, 11, 12]], np.int32))
+    if kv == "int8":
+        ks, vs = (np.asarray(absmax_scale(c, axis=-1)) for c in (kc, vc))
+        kq, vq = narrow_int8(kc, ks[..., None]), narrow_int8(vc, vs[..., None])
+        kw = dict(k_scale=jnp.asarray(ks.reshape(L, nb, 1, -1)),
+                  v_scale=jnp.asarray(vs.reshape(L, nb, 1, -1)))
+        kd, vd = widen_int8(kq, ks[..., None]), widen_int8(vq, vs[..., None])
+        tol = 2e-5
+    else:
+        kq, vq = jnp.asarray(kc, jnp.bfloat16), jnp.asarray(vc, jnp.bfloat16)
+        kw, tol = {}, 2e-2
+        kd, vd = np.asarray(kq, np.float32), np.asarray(vq, np.float32)
+    kq, vq = jnp.asarray(kq), jnp.asarray(vq)
+
+    def layer(_, i):
+        return None, paged_flash_prefill(q, kq, vq, bt, pos, layer=i,
+                                         interpret=True, **kw)
+
+    _, outs = jax.jit(lambda: jax.lax.scan(layer, None, jnp.arange(L)))()
+    for i in range(L):
+        ref = _dense_ref(q, jnp.asarray(kd[i]), jnp.asarray(vd[i]), bt, pos)
+        np.testing.assert_allclose(np.asarray(outs[i]), np.asarray(ref),
+                                   atol=tol, rtol=tol, err_msg=f"layer {i}")
 
 
 def test_kernel_argument_validation():
     q, kc, vc, bt, pos = _case()
     with pytest.raises(ValueError, match="travel together"):
         paged_flash_prefill(q, kc, vc, bt, pos,
-                            k_scale=jnp.zeros((12, 2, 4)),
+                            k_scale=jnp.zeros((12, 1, 8)),
                             interpret=True)
     with pytest.raises(ValueError, match="scale shape"):
         paged_flash_prefill(q, kc, vc, bt, pos,
-                            k_scale=jnp.zeros((12, 9, 4)),
-                            v_scale=jnp.zeros((12, 9, 4)),
+                            k_scale=jnp.zeros((12, 2, 4)),
+                            v_scale=jnp.zeros((12, 2, 4)),
                             interpret=True)
     with pytest.raises(ValueError, match="positions shape"):
         paged_flash_prefill(q, kc, vc, bt, pos[:, :2], interpret=True)

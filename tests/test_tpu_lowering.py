@@ -148,7 +148,7 @@ def test_paged_kernels_compile_under_two_way_shard_map(v5e, kv):
     mesh = Mesh(np.array(v5e[:2]), ("model",))
     cases = {c.name: c for c in _CASES}
     heads = P(None, "model", None, None)
-    scale = P(None, "model", None)
+    scale = P(None, None, "model")        # one head-major row a block
 
     def sharded(kernel, q_spec, pos_spec, n_scales):
         return jax.shard_map(
@@ -192,7 +192,7 @@ def test_serving_geometry_other_block_sizes_compile(v5e):
     for bs in (8, 32):
         for dt in (jnp.float32, jnp.bfloat16, jnp.int8):
             cache = jnp.zeros((nb, n_kv, bs, D), dt)
-            sc = [jnp.zeros((nb, n_kv, bs), jnp.float32)] * 2 \
+            sc = [jnp.zeros((nb, 1, n_kv * bs), jnp.float32)] * 2 \
                 if dt == jnp.int8 else []
             bt = jnp.zeros((S, W), jnp.int32)
             for kernel, q, pos in (
@@ -204,6 +204,47 @@ def test_serving_geometry_other_block_sizes_compile(v5e):
                 assert MOSAIC_CALL in _compile_for(
                     _compiled(kernel), args, [one] * len(args)), \
                     (kernel.__name__, bs, dt)
+
+
+def _compile_paged_kernels(monkeypatch):
+    """Compile the paged kernels, although this process sits on a CPU."""
+    for mod in ("paged_decode", "paged_prefill"):
+        monkeypatch.setattr(sys.modules[f"zoo_tpu.ops.pallas.{mod}"],
+                            "_resolve_interpret", lambda i: False)
+
+
+def _step_hlo(model, which, params, cache, one):
+    """Optimized HLO of one of ``model``'s four serving steps for the
+    v5e target, the cache donated, from shapes alone: ``params`` and
+    ``cache`` are trees of arrays or of ``ShapeDtypeStruct``."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    def avals(tree):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
+
+    S, W = model.num_slots, model.max_blocks_per_seq
+    lanes = [sds((S,), dt) for dt in (jnp.float32, jnp.int32, jnp.float32,
+                                      jnp.uint32)]
+    lane = [sds((), a.dtype) for a in lanes]
+    fn, args = {
+        "decode": (model._decode_fn, [
+            sds((S,), jnp.int32), sds((S,), jnp.int32),
+            sds((S,), jnp.bool_), sds((S, W), jnp.int32),
+            sds((S,), jnp.int32), *lanes]),
+        "prefill_chunk": (model._prefill_chunk_fn, [
+            sds((1, model.suffix_chunk_size), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32), sds((W,), jnp.int32),
+            *lane]),
+        "prefill": (model._prefill_fn, [
+            sds((1, model.prefill_buckets[-1]), jnp.int32),
+            sds((), jnp.int32), sds((W,), jnp.int32), *lane]),
+        "verify": (model._verify_fn, [
+            sds((S, model.spec_k + 1), jnp.int32), sds((S, W), jnp.int32),
+            sds((S,), jnp.int32), *lanes]),
+    }[which]
+    return jax.jit(fn, donate_argnums=(1,)).lower(
+        avals(params), avals(cache), *args).compile().as_text()
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill_chunk"])
@@ -223,42 +264,19 @@ def test_serving_step_holds_no_weight_convert(v5e, monkeypatch, which):
         narrow_dot_weights,
     )
 
-    # compile the paged kernels, although this process sits on a CPU
-    for mod in ("paged_decode", "paged_prefill"):
-        monkeypatch.setattr(sys.modules[f"zoo_tpu.ops.pallas.{mod}"],
-                            "_resolve_interpret", lambda i: False)
+    _compile_paged_kernels(monkeypatch)
     one = SingleDeviceSharding(v5e[0])
     cfg = LlamaConfig(vocab=512, hidden=512, n_block=2, n_head=4,
                       n_kv_head=2, intermediate=1024, rope_theta=1e6)
-    S, W, C = 8, 8, 32
+    C = 32
     model = PagedLlamaModel(
-        cfg, seed=0, num_slots=S, block_size=16, num_blocks=64,
-        max_blocks_per_seq=W, prefill_buckets=(C,), prefill_chunk=C,
+        cfg, seed=0, num_slots=8, block_size=16, num_blocks=64,
+        max_blocks_per_seq=8, prefill_buckets=(C,), prefill_chunk=C,
         kv_dtype="int8", decode_impl="flash", prefill_impl="flash")
     assert model.weight_dtype == "float32"       # a CPU holds what it got
 
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
-
-    def avals(tree):
-        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
-
     def hlo(params):
-        if which == "decode":
-            fn, args = model._decode_fn, (
-                sds((S,), jnp.int32), sds((S,), jnp.int32),
-                sds((S,), jnp.bool_), sds((S, W), jnp.int32),
-                sds((S,), jnp.int32), sds((S,), jnp.float32),
-                sds((S,), jnp.int32), sds((S,), jnp.float32),
-                sds((S,), jnp.uint32))
-        else:
-            fn, args = model._prefill_chunk_fn, (
-                sds((1, C), jnp.int32), sds((), jnp.int32),
-                sds((), jnp.int32), sds((W,), jnp.int32),
-                sds((), jnp.float32), sds((), jnp.int32),
-                sds((), jnp.float32), sds((), jnp.uint32))
-        return jax.jit(fn, donate_argnums=(1,)).lower(
-            avals(params), avals(model._cache), *args).compile().as_text()
+        return _step_hlo(model, which, params, model._cache, one)
 
     stacks = {",".join(map(str, model.params["blocks"][n].shape))
               for n in DOT_BLOCK_LEAVES}
@@ -272,6 +290,87 @@ def test_serving_step_holds_no_weight_convert(v5e, monkeypatch, which):
     assert MOSAIC_CALL in narrow
     assert weight_converts(narrow) == []
     assert len(set(weight_converts(hlo(model.params)))) >= 4
+
+
+# what moves an array: a relayout or plain copy, its asynchronous form,
+# and a slice taken out of or written back into a larger array
+_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def cache_moves(hlo_text, leaf_shapes):
+    """Instructions of the optimized HLO, fused or not, that move a
+    whole cache leaf or one layer's slab of it: an opcode of ``_MOVES``
+    whose result has the shape of a leaf ``(n_layer, ...)``, of
+    ``(1, ...)`` or of ``(...)``. The in-place scatters of
+    ``zoo.kv_append`` are none of these. One thing is let through: a
+    ``copy-start`` whose two sides have ONE layout and differ in their
+    memory space alone, which is the compiler's own prefetch of a small
+    operand into VMEM and no relayout (at the cells' 8 layers and 32
+    slots it does that to the int8 scale planes, 21 MB each; a K or V
+    leaf never fits). Returns ``(moves, prefetches)``."""
+    import re
+    dims = set()
+    for shape in leaf_shapes:
+        dims |= {shape, (1,) + shape[1:], shape[1:]}
+    dims = {",".join(map(str, d)) for d in dims}
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = \(?(\w+)\[([\d,]*)\]"
+                     r"(\{[^}]*\})?(?:, \w+\[[\d,]*\](\{[^}]*\})?)?"
+                     r".*? ([\w-]+)\(", line)
+        if not m or m.group(6) not in _MOVES or m.group(3) not in dims:
+            continue
+
+        def space_free(layout):
+            return re.sub(r"S\(\d+\)", "", layout or "")
+        prefetch = m.group(6) == "copy-start" and m.group(5) is not None \
+            and space_free(m.group(4)) == space_free(m.group(5))
+        found.append((prefetch, f"{m.group(1)} = {m.group(2)}"
+                      f"[{m.group(3)}]{m.group(4) or ''} {m.group(6)}"))
+    return [f for p, f in found if not p], [f for p, f in found if p]
+
+
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+@pytest.mark.parametrize("which", ["decode", "prefill_chunk", "prefill",
+                                   "verify"])
+def test_serving_step_moves_no_cache(v5e, monkeypatch, which, kv):
+    """The cache rides the layer loop's carry and both paged kernels
+    read it at a layer index: every leaf is aliased input to output,
+    and no operation copies, relays, slices out or writes back a whole
+    leaf or a layer's slab of one (K, V and the int8 scale planes
+    alike). At the geometry of the Mistral cells (5,120 blocks of 8 kv
+    heads x 16 rows x 128, two layers of it), where the parent's
+    ``xs``/``ys`` loop held 24 / 24 / 20 / 24 such operations (int8)
+    and 12 / 12 / 10 / 12 (bf16): the ledger's top lines. Shapes
+    alone: the blocks are never allocated."""
+    from zoo_tpu.analysis.hlo import input_output_aliases
+    from zoo_tpu.models.llm.llama import LlamaConfig
+    from zoo_tpu.serving.llm.model import (
+        PagedLlamaModel,
+        narrow_dot_weights,
+    )
+
+    _compile_paged_kernels(monkeypatch)
+    cfg = LlamaConfig(vocab=512, hidden=2048, n_block=2, n_head=16,
+                      n_kv_head=8, intermediate=512, rope_theta=1e6)
+    model = PagedLlamaModel(
+        cfg, seed=0, num_slots=8, block_size=16, num_blocks=4,
+        max_blocks_per_seq=16, prefill_buckets=(32,), prefill_chunk=32,
+        kv_dtype=kv, spec_k=2, decode_impl="flash", prefill_impl="flash")
+    cache = {name: jax.ShapeDtypeStruct(
+        (a.shape[0], 5120) + a.shape[2:], a.dtype)
+        for name, a in model._cache.items()}
+    text = _step_hlo(model, which, narrow_dot_weights(model.params, "tpu"),
+                     cache, SingleDeviceSharding(v5e[0]))
+    if which != "prefill":                    # the bucket attends no cache
+        assert MOSAIC_CALL in text
+    n_leaves = model.donated_cache_leaves()
+    assert n_leaves == (4 if kv == "int8" else 2)
+    assert len({p for _, p in input_output_aliases(text)}) == n_leaves
+    kv_leaves = [a.shape for a in cache.values() if len(a.shape) == 5]
+    planes = [a.shape for a in cache.values() if len(a.shape) == 4]
+    assert cache_moves(text, kv_leaves) == ([], [])
+    assert cache_moves(text, planes)[0] == []
 
 
 def test_flash_attention_compiles_on_a_mesh(v5e, monkeypatch):

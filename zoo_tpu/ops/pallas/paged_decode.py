@@ -12,6 +12,11 @@ al., 2023):
 * **paged** — K/V blocks are read directly where they live, routed by a
   scalar-prefetched block table in the ``BlockSpec`` index maps, so the
   per-sequence gather copy never exists;
+* **stacked** — the cache operand is the WHOLE ``(n_layer, num_blocks,
+  H_kv, block, D)`` array and the layer is a third prefetched scalar,
+  the leading block index of the K/V and scale maps, so a layer loop
+  carries the cache and never slices a layer's slab out of it (a 4-D
+  cache is the same path at one layer);
 * **flash** — online-softmax accumulation in VMEM scratch, never a
   ``(ctx,)`` score row in HBM;
 * **split-KV** — the sequence axis is cut into ``num_splits`` grid
@@ -60,7 +65,8 @@ def attend_block(h, q, k, v, k_scale, v_scale, start, limit, scale,
     column ``c`` of the block is cache index ``start + c`` and a row
     attends it iff that is ``<= limit`` (a scalar position, or a
     (rows, 1) column of per-row positions). An int8 block comes with
-    its (1, block) scale rows: it is widened in register and the scales
+    its (1, block) scale rows (the head's stretch of the block's
+    head-major scale row): it is widened in register and the scales
     land on the (rows, block) score tile — K's on the scores, V's on
     the probabilities, both row broadcasts with no relayout — so HBM
     moves the int8 bytes and the math stays f32."""
@@ -91,15 +97,57 @@ def attend_block(h, q, k, v, k_scale, v_scale, start, limit, scale,
     l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
 
-def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+def stacked_cache(k_cache, v_cache, k_scale, v_scale, layer):
+    """The operands as both paged kernels take them: K and V
+    ``(n_layer, num_blocks, H_kv, block, D)``, the scale planes (or
+    None) ``(n_layer, num_blocks, 1, H_kv * block)`` in float32 — a
+    block's scales are one head-major row, which at 128 values is how
+    the TPU holds the plane anyway — and the layer as a ``(1,)`` int32
+    array to prefetch. A 4-D cache (3-D scale planes) with no ``layer``
+    is one layer's: the stacked case at layer 0, a free reshape."""
+    if (k_scale is None) != (v_scale is None):
+        raise ValueError("k_scale and v_scale travel together")
+    if k_cache.ndim == 4:
+        if layer is not None:
+            raise ValueError("a layer index needs the stacked "
+                             "(n_layer, num_blocks, H_kv, block, D) cache")
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    elif layer is None:
+        raise ValueError("a stacked cache needs the layer to attend")
+    if k_scale is not None:
+        n_layer, n_blocks, n_kv, block_size, _ = k_cache.shape
+        want = (n_layer, n_blocks, 1, n_kv * block_size)
+        for s_arr in (k_scale, v_scale):
+            if s_arr.shape != want:
+                raise ValueError(f"scale shape {s_arr.shape} != {want}")
+        k_scale = k_scale.astype(jnp.float32)
+        v_scale = v_scale.astype(jnp.float32)
+    return (k_cache, v_cache, k_scale, v_scale,
+            jnp.asarray(layer, jnp.int32).reshape(1))
+
+
+def scale_row(ref, h, block_size):
+    """Head ``h``'s (1, block) stretch of a block's head-major scale
+    row ``(1, 1, H_kv * block)``; None for an unquantized cache."""
+    if ref is None:
+        return None
+    return ref[0][:, h * block_size:(h + 1) * block_size]
+
+
+def _kernel(bt_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref, *rest,
             n_kv, block_size, bps, scale, quantized):
     """One (slot, split) program; the innermost grid axis walks the
     split's ``bps`` table entries with the online-softmax carry in VMEM
     scratch. Each entry's block arrives with ALL its kv heads —
     ``(n_kv, block_size, D)``, the cache's own minor dims, which is
     what the TPU lowering can slice — and the heads are walked by a
-    static loop. ``quantized`` adds two per-(block, kv-head, row) scale
-    refs after ``v_ref`` (see :func:`attend_block`)."""
+    static loop (the layer axis is squeezed out by the BlockSpec;
+    ``lay_ref`` is for the index maps alone). ``quantized`` adds two
+    per-(block, kv-head, row) scale refs after ``v_ref`` (see
+    :func:`attend_block`)."""
+    ks_ref = vs_ref = None
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -123,8 +171,8 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         for h in range(n_kv):
             attend_block(
                 h, q_ref[0, h], k_ref[0, h], v_ref[0, h],
-                ks_ref[0, h:h + 1, :] if quantized else None,
-                vs_ref[0, h:h + 1, :] if quantized else None,
+                scale_row(ks_ref, h, block_size),
+                scale_row(vs_ref, h, block_size),
                 start, pos, scale, m_scr, l_scr, a_scr)
 
     @pl.when(j == bps - 1)
@@ -151,6 +199,7 @@ def resolve_num_splits(table_width: int,  # zoo-lint: config-parse
 def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
                        v_cache: jnp.ndarray, block_tables: jnp.ndarray,
                        positions: jnp.ndarray, *,
+                       layer=None,
                        k_scale: Optional[jnp.ndarray] = None,
                        v_scale: Optional[jnp.ndarray] = None,
                        scale: Optional[float] = None,
@@ -159,25 +208,27 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     """Single-query paged attention for one decode tick.
 
     ``q``: (S, H, D) — one query per slot; ``k_cache``/``v_cache``:
-    (num_blocks, H_kv, block_size, D) — ``(block_size, D)`` are the
-    minor dims so one block of every kv head is a slab the TPU can DMA
-    and index by head; ``block_tables``: (S, W) int32; ``positions``:
-    (S,) int32 — the cache index the slot's incoming token was written
-    at (tokens ``0..position`` are attended). Returns (S, H, D) in
-    ``q``'s dtype.
+    (n_layer, num_blocks, H_kv, block_size, D), every layer's blocks,
+    with ``layer`` the (traced) index of the layer attended —
+    ``(block_size, D)`` are the minor dims so one block of every kv
+    head is a slab the TPU can DMA and index by head; a 4-D
+    (num_blocks, H_kv, block_size, D) cache with no ``layer`` is one
+    layer's; ``block_tables``: (S, W) int32; ``positions``: (S,) int32
+    — the cache index the slot's incoming token was written at (tokens
+    ``0..position`` are attended). Returns (S, H, D) in ``q``'s dtype.
 
     An int8 cache passes ``k_scale``/``v_scale`` — per-(block, kv-head,
-    row) absmax scales, shape (num_blocks, H_kv, block_size) — and each
-    block stream is dequantized in VMEM right after the DMA, so the HBM
-    roofline sees int8 bytes while the softmax math stays f32 (a bf16
-    cache needs no scales; the matmuls widen it natively).
+    row) absmax scales, a head-major row a block: (n_layer, num_blocks,
+    1, H_kv * block_size) — and each block stream is dequantized in
+    VMEM right after the DMA, so the HBM roofline sees int8 bytes while
+    the softmax math stays f32 (a bf16 cache needs no scales; the
+    matmuls widen it natively).
     """
     S, H, D = q.shape
-    n_blocks, n_kv, block_size, _ = k_cache.shape
+    k_cache, v_cache, k_scale, v_scale, lay = stacked_cache(
+        k_cache, v_cache, k_scale, v_scale, layer)
+    _, _, n_kv, block_size, _ = k_cache.shape
     quantized = k_scale is not None
-    if quantized and v_scale is None or not quantized \
-            and v_scale is not None:
-        raise ValueError("k_scale and v_scale travel together")
     if H % n_kv:
         raise ValueError(f"q heads ({H}) must be a multiple of kv heads "
                          f"({n_kv})")
@@ -201,37 +252,34 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
         live = idx * block_size <= pos_ref[s]
         return jnp.where(live, bt_ref[s, idx], 0)
 
-    def _kv_map(s, sp, j, bt_ref, pos_ref):
-        return _entry(s, sp, j, bt_ref, pos_ref), 0, 0, 0
+    def _kv_map(s, sp, j, bt_ref, pos_ref, lay_ref):
+        return lay_ref[0], _entry(s, sp, j, bt_ref, pos_ref), 0, 0, 0
 
-    def _out_map(s, sp, j, bt_ref, pos_ref):
+    def _out_map(s, sp, j, bt_ref, pos_ref, lay_ref):
         return s, sp, 0, 0, 0
 
     kernel = functools.partial(
         _kernel, n_kv=n_kv, block_size=block_size, bps=bps, scale=scale,
         quantized=quantized)
-    kv_spec = pl.BlockSpec((1, n_kv, block_size, D), _kv_map)
+    kv_spec = pl.BlockSpec((None, 1, n_kv, block_size, D), _kv_map)
     in_specs = [
         pl.BlockSpec((1, n_kv, group, D),
-                     lambda s, sp, j, bt_ref, pos_ref: (s, 0, 0, 0)),
+                     lambda s, sp, j, bt_ref, pos_ref, lay_ref:
+                     (s, 0, 0, 0)),
         kv_spec, kv_spec,
     ]
     operands = [q4, k_cache, v_cache]
     if quantized:
-        # the scale rows ride the exact same block-table routing as
-        # their K/V block (dead entries clamp to the trash block too)
-        for s_arr in (k_scale, v_scale):
-            if s_arr.shape != (n_blocks, n_kv, block_size):
-                raise ValueError(
-                    f"scale shape {s_arr.shape} != "
-                    f"{(n_blocks, n_kv, block_size)}")
-            in_specs.append(pl.BlockSpec(
-                (1, n_kv, block_size),
-                lambda s, sp, j, bt_ref, pos_ref:
-                (_entry(s, sp, j, bt_ref, pos_ref), 0, 0)))
-            operands.append(s_arr.astype(jnp.float32))
+        # the scale rows ride the exact same layer and block-table
+        # routing as their K/V block (dead entries clamp to the trash
+        # block too)
+        in_specs += [pl.BlockSpec(
+            (None, 1, 1, n_kv * block_size),
+            lambda s, sp, j, bt_ref, pos_ref, lay_ref:
+            (lay_ref[0], _entry(s, sp, j, bt_ref, pos_ref), 0, 0))] * 2
+        operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, splits, bps),
         in_specs=in_specs,
         out_specs=[
@@ -265,7 +313,7 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
         ],
         interpret=interpret,
         name="zoo_paged_decode",
-    )(bt, pos, *operands)
+    )(bt, pos, lay, *operands)
 
     # split-KV epilogue: merge the per-split partial softmaxes with the
     # log-sum-exp correction (dead splits carry m=-inf/l=0 and drop out)

@@ -9,7 +9,10 @@ from the decode path. This kernel is the prefill/verify counterpart:
 
 * **paged** — K/V blocks are streamed IN PLACE through a
   scalar-prefetched block table (dead entries clamp to the resident
-  trash block 0, so no DMA is wasted on blocks past the live length);
+  trash block 0, so no DMA is wasted on blocks past the live length),
+  out of the WHOLE stacked ``(n_layer, num_blocks, H_kv, block, D)``
+  cache with the layer as a third prefetched scalar, as the decode
+  kernel reads it: the layer loop carries the cache and slices nothing;
 * **flash** — online-softmax accumulation in VMEM scratch per chunk
   row, never a ``(ctx,)`` score row in HBM;
 * **chunk-causal** — each query row carries its own cache position and
@@ -51,18 +54,25 @@ from zoo_tpu.ops.pallas import LANES as _LANES
 from zoo_tpu.ops.pallas import SUBLANES as _SUBLANES
 from zoo_tpu.ops.pallas import pad_dim as _pad_dim
 from zoo_tpu.ops.pallas import resolve_interpret as _resolve_interpret
-from zoo_tpu.ops.pallas.paged_decode import attend_block
+from zoo_tpu.ops.pallas.paged_decode import (
+    attend_block,
+    scale_row,
+    stacked_cache,
+)
 
 
-def _kernel(bt_ref, last_ref, q_ref, pos_ref, k_ref, v_ref, *rest,
-            n_kv, block_size, width, scale, quantized):
+def _kernel(bt_ref, last_ref, lay_ref, q_ref, pos_ref, k_ref, v_ref,
+            *rest, n_kv, block_size, width, scale, quantized):
     """One (sequence, table-entry) program; the innermost grid axis
     walks the table with the online-softmax carry in VMEM scratch. Each
     entry's block arrives with ALL its kv heads — ``(n_kv, block_size,
-    D)``, the cache's own minor dims — and the heads are walked by a
-    static loop. Rows = chunk positions x the kv head's query group,
-    flattened (and padded to the sublane tile) by the wrapper, with
-    each row's cache position riding a lane-broadcast carrier."""
+    D)``, the cache's own minor dims, the layer axis squeezed out by
+    the BlockSpec (``lay_ref`` is for the index maps alone) — and the
+    heads are walked by a static loop. Rows = chunk positions x the kv
+    head's query group, flattened (and padded to the sublane tile) by
+    the wrapper, with each row's cache position riding a lane-broadcast
+    carrier."""
+    ks_ref = vs_ref = None
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
@@ -88,8 +98,8 @@ def _kernel(bt_ref, last_ref, q_ref, pos_ref, k_ref, v_ref, *rest,
         for h in range(n_kv):
             attend_block(
                 h, q_ref[0, h], k_ref[0, h], v_ref[0, h],
-                ks_ref[0, h:h + 1, :] if quantized else None,
-                vs_ref[0, h:h + 1, :] if quantized else None,
+                scale_row(ks_ref, h, block_size),
+                scale_row(vs_ref, h, block_size),
                 start, prow, scale, m_scr, l_scr, a_scr)
 
     @pl.when(j == width - 1)
@@ -103,6 +113,7 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
                         v_cache: jnp.ndarray,
                         block_tables: jnp.ndarray,
                         positions: jnp.ndarray, *,
+                        layer=None,
                         k_scale: Optional[jnp.ndarray] = None,
                         v_scale: Optional[jnp.ndarray] = None,
                         scale: Optional[float] = None,
@@ -111,21 +122,24 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     ``q``: (S, C, H, D) — C query rows per sequence (a prefill chunk,
     or a verify pass's k+1 candidate rows); ``k_cache``/``v_cache``:
-    (num_blocks, H_kv, block_size, D); ``block_tables``: (S, W) int32;
+    (n_layer, num_blocks, H_kv, block_size, D) with ``layer`` the
+    (traced) index of the layer attended, or one layer's 4-D
+    (num_blocks, H_kv, block_size, D) with none; ``block_tables``:
+    (S, W) int32;
     ``positions``: (S, C) int32 — the cache index each row's token was
     written at, NONDECREASING per sequence (row r attends every column
     ``<= positions[s, r]``, which covers causal-within-chunk plus the
     resident prefix). Returns (S, C, H, D) in ``q``'s dtype.
 
     An int8 cache passes ``k_scale``/``v_scale`` (per-(block, kv-head,
-    row) absmax, shape (num_blocks, H_kv, block_size)); each block
-    stream is widened in VMEM right after the DMA."""
+    row) absmax, a head-major row a block: (n_layer, num_blocks, 1,
+    H_kv * block_size)); each block stream is widened in VMEM right
+    after the DMA."""
     S, C, H, D = q.shape
-    n_blocks, n_kv, block_size, _ = k_cache.shape
+    k_cache, v_cache, k_scale, v_scale, lay = stacked_cache(
+        k_cache, v_cache, k_scale, v_scale, layer)
+    _, _, n_kv, block_size, _ = k_cache.shape
     quantized = k_scale is not None
-    if quantized and v_scale is None or not quantized \
-            and v_scale is not None:
-        raise ValueError("k_scale and v_scale travel together")
     if H % n_kv:
         raise ValueError(f"q heads ({H}) must be a multiple of kv "
                          f"heads ({n_kv})")
@@ -162,36 +176,31 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
         live = j * block_size <= last_ref[s]
         return jnp.where(live, bt_ref[s, j], 0)
 
-    def _q_map(s, j, bt_ref, last_ref):
+    def _q_map(s, j, bt_ref, last_ref, lay_ref):
         return s, 0, 0, 0
 
-    def _kv_map(s, j, bt_ref, last_ref):
-        return _entry(s, j, bt_ref, last_ref), 0, 0, 0
+    def _kv_map(s, j, bt_ref, last_ref, lay_ref):
+        return lay_ref[0], _entry(s, j, bt_ref, last_ref), 0, 0, 0
 
     kernel = functools.partial(
         _kernel, n_kv=n_kv, block_size=block_size, width=W, scale=scale,
         quantized=quantized)
-    kv_spec = pl.BlockSpec((1, n_kv, block_size, D), _kv_map)
+    kv_spec = pl.BlockSpec((None, 1, n_kv, block_size, D), _kv_map)
     in_specs = [
         pl.BlockSpec((1, n_kv, rows_p, D), _q_map),
         pl.BlockSpec((1, rows_p, _LANES),
-                     lambda s, j, bt_ref, last_ref: (s, 0, 0)),
+                     lambda s, j, bt_ref, last_ref, lay_ref: (s, 0, 0)),
         kv_spec, kv_spec,
     ]
     operands = [q4, prow, k_cache, v_cache]
     if quantized:
-        for s_arr in (k_scale, v_scale):
-            if s_arr.shape != (n_blocks, n_kv, block_size):
-                raise ValueError(
-                    f"scale shape {s_arr.shape} != "
-                    f"{(n_blocks, n_kv, block_size)}")
-            in_specs.append(pl.BlockSpec(
-                (1, n_kv, block_size),
-                lambda s, j, bt_ref, last_ref:
-                (_entry(s, j, bt_ref, last_ref), 0, 0)))
-            operands.append(s_arr.astype(jnp.float32))
+        in_specs += [pl.BlockSpec(
+            (None, 1, 1, n_kv * block_size),
+            lambda s, j, bt_ref, last_ref, lay_ref:
+            (lay_ref[0], _entry(s, j, bt_ref, last_ref), 0, 0))] * 2
+        operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, W),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, n_kv, rows_p, D), _q_map)],
@@ -214,6 +223,6 @@ def paged_flash_prefill(q: jnp.ndarray, k_cache: jnp.ndarray,
         ],
         interpret=interpret,
         name="zoo_paged_prefill",
-    )(bt, last, *operands)
+    )(bt, last, lay, *operands)
     out = out[:, :, :rows].reshape(S, n_kv, C, group, D)
     return out.transpose(0, 2, 1, 3, 4).reshape(S, C, H, D)

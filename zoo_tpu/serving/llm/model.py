@@ -48,7 +48,10 @@ The cache lives here as two device arrays
 ``(n_layer, num_blocks, n_kv_head, block_size, head_dim)`` —
 ``(block_size, head_dim)`` minor, so one table entry is a slab the TPU
 kernels DMA whole and index by head — donated through every
-prefill/decode call so XLA updates them in place. The
+prefill/decode call so XLA updates them in place: the layer loop
+carries them whole, appends at ``[layer, block, head, row]`` and hands
+them whole to the paged kernels with the layer's index, so no call
+copies, relays or slices a layer's slab out of them. The
 sampled token batch of a decode tick is likewise returned as a DEVICE
 array that :meth:`decode_step` accepts back as the next tick's input —
 the engine's overlapped pipeline chains ticks without a host round
@@ -490,7 +493,8 @@ class PagedDecoderModel:
         self._zero_tokens = jnp.zeros((self.num_slots,), jnp.int32)
         if self.mesh is None:
             # the cache pytree is arg 1 → donated: XLA aliases it in
-            # place (K/V blocks and, under int8, their scale rows)
+            # place, leaf for leaf, through the layer loop's carry (K/V
+            # blocks and, under int8, their scale rows)
             self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
             self._prefill = jax.jit(self._prefill_fn,
                                     donate_argnums=(1,))
@@ -994,9 +998,10 @@ class PagedLlamaModel(PagedDecoderModel):
     """Llama-shaped blocks under the skeleton: pre-norm, grouped-query
     attention over a per-head K/V paged cache
     ``(n_layer, num_blocks, n_kv_head, block, head_dim)`` (int8 with
-    per-row scale planes, bf16 or f32), a dense SwiGLU feed-forward,
-    every block alike under one ``lax.scan`` whose ``xs`` carry the
-    weights and the layer's cache slices."""
+    per-row scale planes ``(n_layer, num_blocks, 1, n_kv_head * block)``,
+    bf16 or f32), a dense SwiGLU feed-forward, every block alike under
+    one ``lax.scan`` whose ``xs`` are the stacked weights and the
+    layer's index and whose carry holds the whole cache beside ``h``."""
 
     # -- the hooks -----------------------------------------------------------
     def _check_config(self):
@@ -1031,9 +1036,14 @@ class PagedLlamaModel(PagedDecoderModel):
                  "v": jnp.zeros(shape, cache_np)}
         if self.kv_cache_dtype == "int8":
             # absmax scale per written cache ROW, stored block-indexed
-            # right beside the K/V blocks (the block table routes both)
-            sshape = (c.n_block, self.num_blocks, c.n_kv_head,
-                      self.block_size)
+            # right beside the K/V blocks (the block table routes
+            # both): a block's scales are ONE row, head-major
+            # (``[h * block + r]``). With 128 of them (8 kv heads x 16
+            # rows) the TPU holds the plane as it is declared and the
+            # kernels read it where it lies; a (..., n_kv, block) plane
+            # it holds block-minor, and every call relays it for them.
+            sshape = (c.n_block, self.num_blocks, 1,
+                      c.n_kv_head * self.block_size)
             cache["ks"] = jnp.zeros(sshape, jnp.float32)
             cache["vs"] = jnp.zeros(sshape, jnp.float32)
         # K+V rows over every layer, plus the scale rows for int8
@@ -1046,51 +1056,56 @@ class PagedLlamaModel(PagedDecoderModel):
     def _cache_shardings(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        # K/V blocks shard on the kv-head axis; int8 scale rows carry
-        # the same head axis and shard with their blocks
+        # K/V blocks shard on the kv-head axis; an int8 scale row is
+        # head-major, so its shards are the same heads' scales
         # (docs/multichip.md: the tp=N layout quantization keeps)
         kv_sh = NamedSharding(
             self.mesh, P(None, None, "model", None, None))
         scale_sh = NamedSharding(
-            self.mesh, P(None, None, "model", None))
+            self.mesh, P(None, None, None, "model"))
         cache_sh = {"k": kv_sh, "v": kv_sh}
         if self.kv_cache_dtype == "int8":
             cache_sh["ks"] = cache_sh["vs"] = scale_sh
         return cache_sh
 
     def _layers(self, params, cache, h, attend, at):
-        """Every block alike under one scan: norm, q/k/v, the step's
-        ``attend`` (rope, append, attention), output projection, MLP."""
+        """Every block alike under one scan over the stacked weights:
+        norm, q/k/v, the step's ``attend`` (rope, append, attention),
+        output projection, MLP. The cache pytree rides the CARRY beside
+        ``h`` and is written and read at the layer's index where it
+        lies, so the loop holds no slice, copy or write-back of a
+        cache leaf."""
         c = self.cfg
+        if self.kv_cache_dtype == "int8":
+            # the same for every layer: worked out once, ahead of the loop
+            at = dict(at, cells=self._scale_cells(at))
 
-        def layer(h, xs):
-            p, *cl = self._unpack_xs(xs)
+        def layer(carry, xs):
+            h, cache = carry
+            p, i = xs
             x = _rms_norm(h, p["attn_norm"], c.rms_eps)
             q, k, v = self._attn_proj(p, x)
-            a, cl = attend(q, k, v, cl, at)
+            a, cache = attend(q, k, v, cache, i, at)
             h = h + _weight_dot(a, p["wo"])
-            return self._mlp(p, h), self._layer_ys(*cl)
+            return (self._mlp(p, h), cache), None
 
-        h, ys = jax.lax.scan(layer, h, self._layer_xs(params, cache))
-        return h, self._repack_cache(ys), ()
+        (h, cache), _ = jax.lax.scan(
+            layer, (h, cache), (params["blocks"], jnp.arange(c.n_block)))
+        return h, cache, ()
 
-    def _attend_decode(self, q, k, v, cl, at):
-        kcl, vcl, ksl, vsl = cl
+    def _attend_decode(self, q, k, v, cache, layer, at):
         # rope at each slot's own position (per-slot angle rows)
         q = _rope_rows(q, at["cos"], at["sin"])
         k = _rope_rows(k, at["cos"], at["sin"])
         # write this token's k/v through the block table (narrowed per
         # the cache dtype), THEN attend — the token attends to itself
         # like any other
-        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"], k)
-        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"], v)
-        o = self._paged_attend(q, kcl, vcl, ksl, vsl, at["tables"],
-                               at["pos"])
-        return o, (kcl, vcl, ksl, vsl)
+        cache = self._append_rows(cache, layer, at, k, v)
+        return self._paged_attend(q, cache, layer, at["tables"],
+                                  at["pos"]), cache
 
-    def _attend_bucket(self, q, k, v, cl, at):
+    def _attend_bucket(self, q, k, v, cache, layer, at):
         c = self.cfg
-        kcl, vcl, ksl, vsl = cl
         L = q.shape[1]
         q = apply_rope(q.transpose(0, 2, 1, 3), at["cos"], at["sin"])
         k = apply_rope(k.transpose(0, 2, 1, 3), at["cos"], at["sin"])
@@ -1099,32 +1114,24 @@ class PagedLlamaModel(PagedDecoderModel):
             q, k, v, causal=True, impl=resolve_attention_impl("auto", L),
             mesh=self.mesh)
         a = a.transpose(0, 2, 1, 3).reshape(1, L, c.n_head * c.head_dim)
-        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"],
-                                     k.transpose(0, 2, 1, 3)[0])
-        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"],
-                                     v.transpose(0, 2, 1, 3)[0])
-        return a, (kcl, vcl, ksl, vsl)
+        return a, self._append_rows(cache, layer, at,
+                                    k.transpose(0, 2, 1, 3)[0],
+                                    v.transpose(0, 2, 1, 3)[0])
 
-    def _attend_chunk(self, q, k, v, cl, at):
-        kcl, vcl, ksl, vsl = cl
+    def _attend_chunk(self, q, k, v, cache, layer, at):
         q = _rope_rows(q[0], at["cos"], at["sin"])[None]
-        k = _rope_rows(k[0], at["cos"], at["sin"])[None]
-        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"], k[0])
-        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"], v[0])
+        k = _rope_rows(k[0], at["cos"], at["sin"])
+        cache = self._append_rows(cache, layer, at, k, v[0])
         # flash streams the table, dense gathers it
-        a = self._prefill_attend(q, kcl, vcl, ksl, vsl, at["tables"],
-                                 at["pos"])
-        return a, (kcl, vcl, ksl, vsl)
+        return self._prefill_attend(q, cache, layer, at["tables"],
+                                    at["pos"]), cache
 
-    def _attend_verify(self, q, k, v, cl, at):
-        kcl, vcl, ksl, vsl = cl
+    def _attend_verify(self, q, k, v, cache, layer, at):
         q = _rope_rows(q, at["cos"], at["sin"])
         k = _rope_rows(k, at["cos"], at["sin"])
-        kcl, ksl = self._append_rows(kcl, ksl, at["blk"], at["off"], k)
-        vcl, vsl = self._append_rows(vcl, vsl, at["blk"], at["off"], v)
-        a = self._prefill_attend(q, kcl, vcl, ksl, vsl, at["tables"],
-                                 at["pos"])
-        return a, (kcl, vcl, ksl, vsl)
+        cache = self._append_rows(cache, layer, at, k, v)
+        return self._prefill_attend(q, cache, layer, at["tables"],
+                                    at["pos"]), cache
 
     # test/debug views of the cache arrays (the canonical home is the
     # donated ``self._cache`` pytree)
@@ -1136,61 +1143,95 @@ class PagedLlamaModel(PagedDecoderModel):
     def _vc(self):
         return self._cache["v"]
 
-    # -- cache quantization helpers (traced inside the executables) --------
-    def _layer_xs(self, params, cache):
-        """The per-layer scan operands: weights + this layer's cache
-        slices (+ scale slices under int8)."""
-        xs = (params["blocks"], cache["k"], cache["v"])
-        if self.kv_cache_dtype == "int8":
-            xs += (cache["ks"], cache["vs"])
-        return xs
-
-    def _unpack_xs(self, xs):
-        """(p, kcl, vcl, ksl, vsl) with None scales off-int8."""
-        if self.kv_cache_dtype == "int8":
-            return xs
-        p, kcl, vcl = xs
-        return p, kcl, vcl, None, None
-
-    def _repack_cache(self, ys):
-        cache = {"k": ys[0], "v": ys[1]}
-        if self.kv_cache_dtype == "int8":
-            cache["ks"], cache["vs"] = ys[2], ys[3]
+    # -- cache append and quantization (traced inside the executables) -----
+    @jax.named_scope("zoo.kv_append")
+    def _append_rows(self, cache, layer, at, k, v):
+        """Write f32 K and V rows (..., n_kv, D) into the stacked cache
+        at ``[layer, blk, :, off]``, quantizing per the cache dtype:
+        int8 rows store ``clip(rint(x/scale))`` with their own absmax
+        scale (a row is written once and never requantized, so
+        bucketed, chunked and decode-appended writes of the same token
+        are bit-identical cache bytes); bf16 narrows; f32 passes
+        through. Every leading index is explicit, the head's too: the
+        update window is then a row's D values, contiguous in the
+        cache's own layout, and the scatter runs in place (with the
+        head axis left as a window between two scattered ones the
+        compiler relays the whole stacked array, once a layer)."""
+        blk, off = at["blk"][..., None], at["off"][..., None]
+        heads = jnp.arange(self.cfg.n_kv_head)
+        cache = dict(cache)
+        for name, x in (("k", k), ("v", v)):
+            if self.kv_cache_dtype == "int8":
+                s = absmax_scale(x, axis=-1, keepdims=True, xp=jnp)
+                cache[name + "s"] = self._append_scales(
+                    cache[name + "s"], layer, at["cells"], s[..., 0])
+                x = narrow_int8(x, s, xp=jnp)
+            cache[name] = cache[name].at[layer, blk, heads, off].set(
+                x.astype(cache[name].dtype))
         return cache
 
-    @jax.named_scope("zoo.kv_append")
-    def _append_rows(self, cachel, scalel, blk, off, x):
-        """Write f32 K or V rows ``x`` (..., n_kv, D) through the block
-        table at (blk, off), quantizing per the cache dtype: int8 rows
-        store ``clip(rint(x/scale))`` with their own absmax scale (a
-        row is written once and never requantized, so bucketed, chunked
-        and decode-appended writes of the same token are bit-identical
-        cache bytes); bf16 narrows; f32 passes through."""
-        # (blk, :, off) — the advanced indices straddle the kv-head
-        # slice, so the indexed view is (..., n_kv, D): x's own shape
-        if self.kv_cache_dtype == "int8":
-            s = absmax_scale(x, axis=-1, keepdims=True, xp=jnp)
-            cachel = cachel.at[blk, :, off].set(
-                narrow_int8(x, s, xp=jnp))
-            scalel = scalel.at[blk, :, off].set(s[..., 0])
-            return cachel, scalel
-        return cachel.at[blk, :, off].set(x.astype(cachel.dtype)), scalel
+    def _scale_cells(self, at):
+        """Where a step's rows fall in the scale planes, block by
+        block. The rows of a sequence sit at consecutive cache
+        positions from its first, so R of them touch at most ``nb``
+        table entries; for each: the block's id (the trash block 0
+        where no real row falls in it), the row that lands in each of
+        its ``block`` cells, and, laid out as the block's head-major
+        scale row, whether one does. Shapes (B, nb), (B, nb, block),
+        (B, nb, 1, n_kv * block)."""
+        bs = self.block_size
+        tables = at["tables"]                                 # (B, W)
+        B, W = tables.shape
+        pos = at["pos"].reshape(B, -1)                        # (B, R)
+        R = pos.shape[1]
+        first = pos[:, :1]
+        nb = (R + bs - 2) // bs + 1
+        col = first // bs + jnp.arange(nb)                    # (B, nb)
+        row = (col * bs)[..., None] + jnp.arange(bs) - first[..., None]
+        held = (row >= 0) & (row < R) & (col < W)[..., None]
+        row = jnp.clip(row, 0, R - 1)
+        if at["real"] is not None:
+            held &= jnp.take_along_axis(
+                at["real"].reshape(B, R), row.reshape(B, -1),
+                axis=1).reshape(row.shape)
+        ids = jnp.where(
+            jnp.any(held, axis=-1),
+            jnp.take_along_axis(tables, jnp.minimum(col, W - 1), axis=1),
+            0)
+        return ids, row, jnp.tile(held, (1, 1, self.cfg.n_kv_head))[
+            :, :, None]
 
-    def _layer_ys(self, kcl, vcl, ksl, vsl):
-        ys = (kcl, vcl)
-        if self.kv_cache_dtype == "int8":
-            ys += (ksl, vsl)
-        return ys
+    def _append_scales(self, plane, layer, cells, s):
+        """Write the rows' scales ``s`` (..., n_kv) into a scale plane
+        ``(n_layer, num_blocks, 1, n_kv * block)``: read the blocks'
+        rows the step's rows fall in, set their cells and write them
+        back. The scatter's window is then a whole row of the plane,
+        in the layout the kernels read; scattered a value at a time
+        the compiler lays the plane out for the scatter and copies it
+        back for the kernel, every layer. A live block has one writer a
+        call (idle slots and pad rows all write the trash block, where
+        any may win)."""
+        ids, row, held = cells
+        B, nb, bs = row.shape
+        n_kv = s.shape[-1]
+        s = s.reshape(B, -1, n_kv)                            # (B, R, n_kv)
+        new = jnp.take_along_axis(
+            s, row.reshape(B, nb * bs, 1), axis=1).reshape(
+                B, nb, bs, n_kv).transpose(0, 1, 3, 2)        # head-major
+        return plane.at[layer, ids].set(jnp.where(
+            held, new.reshape(B, nb, 1, -1), plane[layer, ids]))
 
-    def _widen_gather(self, cachel, scalel, idx):
-        """Gather cache blocks by table ``idx`` (B, W), widen to f32
-        (int8 rows times their scales; bf16/f32 a plain cast) and lay
-        the result out token-major, (B, W * block, n_kv, D) — the dense
-        reference for exactly what the flash kernel does in VMEM."""
-        g = cachel[idx].astype(jnp.float32)      # (B, W, n_kv, block, D)
-        if scalel is not None:
-            g = g * scalel[idx][..., None]
+    def _widen_gather(self, cache, name, layer, idx):
+        """Gather one layer's K or V blocks (``name``) by table ``idx``
+        (B, W), widen to f32 (int8 rows times their scales; bf16/f32 a
+        plain cast) and lay the result out token-major, (B, W * block,
+        n_kv, D) — the dense reference for exactly what the flash
+        kernel does in VMEM."""
+        g = cache[name][layer, idx].astype(jnp.float32)
         B, W, n_kv, bs, D = g.shape
+        if name + "s" in cache:
+            g = g * cache[name + "s"][layer, idx].reshape(
+                B, W, n_kv, bs, 1)
         return g.transpose(0, 1, 3, 2, 4).reshape(B, W * bs, n_kv, D)
 
     @jax.named_scope("zoo.attn_proj")
@@ -1212,41 +1253,43 @@ class PagedLlamaModel(PagedDecoderModel):
         return h + _weight_dot(jax.nn.silu(_weight_dot(x, p["w_gate"]))
                                * _weight_dot(x, p["w_up"]), p["w_down"])
 
-    def _on_model_axis(self, kernel, q, q_spec, kcl, vcl, ksl, vsl,
+    def _on_model_axis(self, kernel, q, q_spec, cache, layer,
                        block_tables, positions, pos_spec, scale):
         """tp: run a paged kernel under ``shard_map`` over the mesh's
-        ``model`` axis — each device streams ITS kv heads' cache shard
-        (and, under int8, their scale rows) against the query heads of
-        those groups. Attention is head-local, so the only
-        communication after the kernel is the row-parallel ``wo``
+        ``model`` axis — each device streams ITS kv heads' shard of the
+        stacked cache (and, under int8, their scale rows) against the
+        query heads of those groups. Attention is head-local, so the
+        only communication after the kernel is the row-parallel ``wo``
         matmul GSPMD already inserts."""
         from jax.sharding import PartitionSpec as P
 
-        kv = P(None, "model", None, None)
-        scales = () if ksl is None else (ksl, vsl)
+        kv = P(None, None, "model", None, None)
+        scales = tuple(cache[n] for n in ("ks", "vs") if n in cache)
 
-        def local(q_, k_, v_, bt_, pos_, *sc):
+        def local(q_, k_, v_, bt_, pos_, lay_, *sc):
             kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
-            return kernel(q_, k_, v_, bt_, pos_, scale=scale, **kw)
+            return kernel(q_, k_, v_, bt_, pos_, layer=lay_, scale=scale,
+                          **kw)
 
         return jax.shard_map(
             local, mesh=self.mesh,
-            in_specs=(q_spec, kv, kv, P(None, None), pos_spec)
-            + (P(None, "model", None),) * len(scales),
+            in_specs=(q_spec, kv, kv, P(None, None), pos_spec, P())
+            + (P(None, None, None, "model"),) * len(scales),
             out_specs=q_spec, check_vma=False,
-        )(q, kcl, vcl, block_tables, positions, *scales)
+        )(q, cache["k"], cache["v"], block_tables, positions, layer,
+          *scales)
 
     @jax.named_scope("zoo.paged_attend")
-    def _paged_attend(self, q, kcl, vcl, ksl, vsl, block_tables,
-                      positions):
+    def _paged_attend(self, q, cache, layer, block_tables, positions):
         """Single-query attention over the paged cache: (S, H, D) q
-        against the (blocks, n_kv, block, D) layer cache, routed by the
-        block tables and masked to each slot's live length. Dispatches
-        to the paged flash-decode Pallas kernel or the dense-gather
-        reference per ``decode_attention_impl``; an int8 cache hands
-        the kernel its scale rows (in-register dequant) and the dense
-        path widens the gathered blocks the same way, so token parity
-        between the two stays testable off-TPU."""
+        against layer ``layer`` of the stacked (n_layer, blocks, n_kv,
+        block, D) cache, routed by the block tables and masked to each
+        slot's live length. Dispatches to the paged flash-decode Pallas
+        kernel or the dense-gather reference per
+        ``decode_attention_impl``; an int8 cache hands the kernel its
+        scale planes (in-register dequant) and the dense path widens
+        the gathered blocks the same way, so token parity between the
+        two stays testable off-TPU."""
         c = self.cfg
         S = self.num_slots
         scale = 1.0 / float(c.head_dim) ** 0.5
@@ -1254,34 +1297,34 @@ class PagedLlamaModel(PagedDecoderModel):
             from zoo_tpu.ops.pallas.paged_decode import paged_flash_decode
             if self.mesh is None:
                 return paged_flash_decode(
-                    q, kcl, vcl, block_tables, positions,
-                    k_scale=ksl, v_scale=vsl,
+                    q, cache["k"], cache["v"], block_tables, positions,
+                    layer=layer, k_scale=cache.get("ks"),
+                    v_scale=cache.get("vs"),
                     scale=scale).reshape(S, c.n_head * c.head_dim)
             from jax.sharding import PartitionSpec as P
             out = self._on_model_axis(
-                paged_flash_decode, q, P(None, "model", None), kcl, vcl,
-                ksl, vsl, block_tables, positions, P(None), scale)
+                paged_flash_decode, q, P(None, "model", None), cache,
+                layer, block_tables, positions, P(None), scale)
             return out.reshape(S, c.n_head * c.head_dim)
-        # dense-gather reference: materialize cache[block_table], widen
-        # and mask — the PR 7 path, kept as the off-TPU fallback and
-        # the token-identity anchor for the kernel
+        # dense-gather reference: materialize cache[layer, block_table],
+        # widen and mask — the PR 7 path, kept as the off-TPU fallback
+        # and the token-identity anchor for the kernel
         ctx = self.max_blocks_per_seq * self.block_size
         live = jnp.arange(ctx)[None, :] <= positions[:, None]  # (S, ctx)
-        keys = self._widen_gather(kcl, ksl, block_tables)
-        vals = self._widen_gather(vcl, vsl, block_tables)
+        keys = self._widen_gather(cache, "k", layer, block_tables)
+        vals = self._widen_gather(cache, "v", layer, block_tables)
         return self._masked_gather_attention(q, keys, vals, live)
 
     @jax.named_scope("zoo.paged_attend")
-    def _prefill_attend(self, q, kcl, vcl, ksl, vsl, block_tables,
-                        positions):
+    def _prefill_attend(self, q, cache, layer, block_tables, positions):
         """Chunk-of-rows attention over the resident paged cache:
         ``q`` (B, R, H, D) rows at cache ``positions`` (B, R), routed by
         per-sequence ``block_tables`` (B, W) — each row attends every
-        resident column ``<= its position`` (causal within the chunk
-        plus everything earlier ticks wrote; the chunk's own K/V land
-        in the cache before this runs). B is 1 for a prefill chunk and
-        ``num_slots`` for a verify pass. Dispatches to the paged
-        flash-prefill Pallas kernel or the dense gather per
+        resident column of layer ``layer`` ``<= its position`` (causal
+        within the chunk plus everything earlier ticks wrote; the
+        chunk's own K/V land in the cache before this runs). B is 1 for
+        a prefill chunk and ``num_slots`` for a verify pass. Dispatches
+        to the paged flash-prefill Pallas kernel or the dense gather per
         ``prefill_attention_impl``; both widen an int8 cache the same
         way, so token parity stays testable off-TPU. Returns
         (B, R, n_head * head_dim)."""
@@ -1294,22 +1337,23 @@ class PagedLlamaModel(PagedDecoderModel):
             )
             if self.mesh is None:
                 out = paged_flash_prefill(
-                    q, kcl, vcl, block_tables, positions,
-                    k_scale=ksl, v_scale=vsl, scale=scale)
+                    q, cache["k"], cache["v"], block_tables, positions,
+                    layer=layer, k_scale=cache.get("ks"),
+                    v_scale=cache.get("vs"), scale=scale)
                 return out.reshape(B, R, c.n_head * c.head_dim)
             from jax.sharding import PartitionSpec as P
             out = self._on_model_axis(
-                paged_flash_prefill, q, P(None, None, "model", None), kcl,
-                vcl, ksl, vsl, block_tables, positions, P(None, None),
+                paged_flash_prefill, q, P(None, None, "model", None),
+                cache, layer, block_tables, positions, P(None, None),
                 scale)
             return out.reshape(B, R, c.n_head * c.head_dim)
-        # dense anchor: materialize cache[block_table] per sequence,
-        # widen, broadcast over the rows, and run the shared masked
-        # attention body — exactly what the kernel streams in VMEM
+        # dense anchor: materialize cache[layer, block_table] per
+        # sequence, widen, broadcast over the rows, and run the shared
+        # masked attention body — exactly what the kernel streams in VMEM
         ctx = self.max_blocks_per_seq * self.block_size
         kv = (B, ctx, c.n_kv_head, c.head_dim)
-        keys = self._widen_gather(kcl, ksl, block_tables)
-        vals = self._widen_gather(vcl, vsl, block_tables)
+        keys = self._widen_gather(cache, "k", layer, block_tables)
+        vals = self._widen_gather(cache, "v", layer, block_tables)
         keys = jnp.broadcast_to(keys[:, None], (B, R) + kv[1:]).reshape(
             (B * R,) + kv[1:])
         vals = jnp.broadcast_to(vals[:, None], (B, R) + kv[1:]).reshape(

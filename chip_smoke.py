@@ -137,7 +137,8 @@ def _ints(spec: str) -> dict:
 # --------------------------------------------------------------------- train
 
 def _bert_model(sz: Sizes):
-    """BERT-base as the repo trains it (bench.py ``bench_bert``)."""
+    """BERT-base as the repo trains it (the benchmark's
+    ``bert-base.fit-resident`` cell builds the same stack)."""
     from zoo_tpu.pipeline.api.keras import Sequential
     from zoo_tpu.pipeline.api.keras.layers import BERT, Dense, Lambda
     from zoo_tpu.pipeline.api.keras.optimizers import AdamWeightDecay

@@ -120,15 +120,6 @@ def collect_metrics(n_devices: int = N_DEVICES, verbose: bool = True
     assert len(devices) == n_devices, (
         f"need {n_devices} devices, have {len(jax.devices())}")
     m = {"n_devices": n_devices}
-    # the same provenance stamp BENCH_* lines carry (bench._bench_meta:
-    # git rev + PR), so the MULTICHIP_r0*.json trajectory is
-    # attributable to the code state that produced it
-    try:
-        from bench import _bench_meta
-        m["bench_meta"] = _bench_meta()
-    except Exception:  # noqa: BLE001 — a stripped deploy image may
-        # ship without bench.py; the smoke still scores
-        m["bench_meta"] = {"git_rev": "unknown", "pr": None}
 
     # 1. sharded fit matches the single-device loss curve ----------------
     # full-width ZeRO: params sharded n_devices ways (the batch rides

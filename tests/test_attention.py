@@ -114,7 +114,7 @@ def test_tiny_bert_classifier_trains(orca_ctx):
 
 @pytest.mark.parametrize("remat", ["dots", True])
 def test_transformer_remat_trains(orca_ctx, remat):
-    """remat policies compile and train (the bench BERT row runs
+    """remat policies compile and train (the benchmark's BERT cell runs
     remat='dots'); loss matches the no-remat path step-for-step
     (remat changes memory, never math)."""
     from zoo_tpu.pipeline.api.keras import Sequential

@@ -79,7 +79,7 @@ def test_one_function_owns_the_setting():
                         and rel != "zoo_tpu/common/compile_cache.py" \
                         and "jax_compilation_cache_dir" in read(rel):
                     offenders.append(rel)
-    for rel in ("chip_smoke.py", "bench.py", "__graft_entry__.py"):
+    for rel in ("chip_smoke.py", "__graft_entry__.py"):
         if "jax_compilation_cache_dir" in read(rel):
             offenders.append(rel)
     assert offenders == []

@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from llm_tick import tick
 from zoo_tpu.models.llm.glm_moe_lite import GlmMoeLiteConfig
 from zoo_tpu.obs.metrics import counter
 from zoo_tpu.ops.moe import moe_ffn_dropless, route_topk
@@ -97,8 +98,7 @@ def test_served_tokens_follow_the_reference(ref_params):
                for n in (11, 10)]
     handles = [eng.submit(p, 14) for p in prompts]
     for _ in range(200):
-        eng._sweep(); eng._admit(); eng._prefill_tick()   # noqa: E702
-        eng._grow_or_preempt(); eng._decode_tick()        # noqa: E702
+        tick(eng)
         if all(h.done for h in handles):
             break
     assert [h.outcome for h in handles] == ["ok", "ok"]

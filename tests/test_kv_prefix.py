@@ -14,6 +14,7 @@ import time
 import numpy as np
 import pytest
 
+from llm_tick import HostStepped, tick
 from zoo_tpu.serving.llm.engine import LLMEngine
 from zoo_tpu.serving.llm.kv_cache import (
     BlockAllocator,
@@ -295,7 +296,7 @@ def test_drop_cached_reclaims_only_parked_blocks():
 
 # ------------------------------------------ engine admission (fake model)
 
-class _PrefixFakeModel:
+class _PrefixFakeModel(HostStepped):
     """Deterministic jax-free model with the PagedLlamaModel surface:
     next token is a pure function of (last token, position[, seed]) —
     so streams are byte-comparable across prefix-cache on/off and
@@ -361,7 +362,7 @@ def _drain(handles, budget=60.0):
 def _run_streams(prefix_cache, prompts, max_new=8, sampling=None,
                  sequential=True, **model_kw):
     m = _PrefixFakeModel(**model_kw)
-    eng = LLMEngine(m, overlap=False, prefix_cache=prefix_cache).start()
+    eng = LLMEngine(m, prefix_cache=prefix_cache).start()
     try:
         outs = []
         if sequential:
@@ -401,14 +402,6 @@ def test_engine_prefix_cache_streams_byte_identical(chunk):
         st["num_blocks"] - 1
 
 
-def _tick(eng):
-    eng._sweep()
-    eng._admit()
-    eng._prefill_tick()
-    eng._grow_or_preempt()
-    eng._decode_tick()
-
-
 def test_engine_cow_fork_copies_device_block():
     """Two LIVE streams on the same aligned prompt: the second must
     fork the final shared block (ref 2) and the engine must issue the
@@ -418,11 +411,11 @@ def test_engine_cow_fork_copies_device_block():
     eng = LLMEngine(m, prefix_cache=True)   # not started: manual ticks
     h1 = eng.submit(SHARED, 10, rid="a")
     for _ in range(3):                      # a prefilled + decoding
-        _tick(eng)
+        tick(eng)
     assert not h1.done and len(h1.tokens) >= 1
     h2 = eng.submit(SHARED, 4, rid="b")
     for _ in range(20):
-        _tick(eng)
+        tick(eng)
         if h1.done and h2.done:
             break
     assert h1.outcome == "ok" and h2.outcome == "ok"
@@ -447,11 +440,11 @@ def test_cow_without_copy_block_fails_stream_loudly():
     eng = LLMEngine(_NoCopy(), prefix_cache=True)
     h1 = eng.submit(SHARED, 10, rid="a")
     for _ in range(3):
-        _tick(eng)
+        tick(eng)
     assert not h1.done
     h2 = eng.submit(SHARED, 4, rid="b")   # aligned hit -> fork owed
     for _ in range(20):
-        _tick(eng)
+        tick(eng)
         if h1.done and h2.done:
             break
     assert h1.outcome == "ok"             # the writer is untouched
@@ -472,7 +465,7 @@ def test_seed_replay_across_preempt_resume_on_cache_hit():
     # tight pool + a competing stream forces preemption; prefix cache
     # on means the resume re-matches its own re-registered prefix
     m = _PrefixFakeModel(num_blocks=10, num_slots=2)
-    eng = LLMEngine(m, overlap=False, prefix_cache=True).start()
+    eng = LLMEngine(m, prefix_cache=True).start()
     try:
         h1 = eng.submit(SHARED, 12, rid="victim", sampling=sampling)
         h2 = eng.submit(list(range(20, 28)), 16, rid="hog",
@@ -500,7 +493,7 @@ def test_resumed_stream_rematches_prefix_cache():
     hog = eng.submit(list(range(60, 68)), 20, rid="hog")
     victim = eng.submit(SHARED, 8, rid="victim")
     for _ in range(80):
-        _tick(eng)
+        tick(eng)
         if hog.done and victim.done:
             break
     assert hog.outcome == "ok" and victim.outcome == "ok"

@@ -25,6 +25,8 @@ from zoo_tpu.serving.llm.kv_cache import BlockAllocator
 from zoo_tpu.serving.llm.spec import parse_llm_spec
 from zoo_tpu.util.resilience import Deadline
 
+from llm_tick import HostStepped, tick
+
 
 # ------------------------------------------------------- block allocator
 
@@ -113,7 +115,7 @@ def test_allocator_publishes_gauges():
 
 # ------------------------------------------------ scheduler (fake model)
 
-class _FakeModel:
+class _FakeModel(HostStepped):
     """Deterministic 'llm' with the PagedLlamaModel surface but no jax.
 
     Greedy lanes: next token = ``(2*tok + pos) % 97``, a pure function
@@ -172,18 +174,6 @@ class _FakeModel:
         return np.array([self._next(t, p + 1, tt, s)
                          for t, p, tt, s in zip(tokens, positions,
                                                 temps, seeds)], np.int32)
-
-    # the async dispatch surface the overlapped pipeline drives: the
-    # fake 'device' is synchronous, so the batch is just the array
-    def decode_step(self, prev, host_tokens, use_host, block_tables,
-                    positions, sampling):
-        prev = np.zeros_like(host_tokens) if prev is None else \
-            np.asarray(prev)
-        toks = np.where(np.asarray(use_host), host_tokens, prev)
-        return self.decode(toks, block_tables, positions, sampling)
-
-    def read_tokens(self, batch):
-        return np.asarray(batch)
 
 
 def _reference(prompt, n, temp=0.0, seed=0):
@@ -271,28 +261,6 @@ def test_engine_continuous_admits_into_freed_slots_midflight():
         assert long_h.tokens == _reference([1], 25)
     finally:
         eng.stop()
-
-
-def test_engine_oneshot_waits_for_batch_to_drain():
-    """The request-level baseline the bench compares against: a wave is
-    admitted only on an EMPTY batch, so a late request waits for every
-    member of the running wave."""
-    eng = LLMEngine(_FakeModel(num_slots=2, num_blocks=64,
-                               max_blocks_per_seq=8), mode="oneshot")
-    # white-box: tick the scheduler by hand for determinism
-    h1 = eng.submit([1], 4)
-    h2 = eng.submit([2], 4)
-    h3 = eng.submit([3], 2)   # wave 2
-    for _ in range(3):
-        eng._sweep(); eng._admit(); eng._prefill_tick()
-        eng._grow_or_preempt(); eng._decode_tick()
-    assert h1.done and h2.done
-    assert not h3.tokens, "oneshot admitted into a non-empty batch"
-    for _ in range(2):
-        eng._sweep(); eng._admit(); eng._prefill_tick()
-        eng._grow_or_preempt(); eng._decode_tick()
-    assert h3.done and h3.tokens == _reference([3], 2)
-    eng.stop()
 
 
 def test_engine_deadline_dead_in_queue():
@@ -400,8 +368,7 @@ def test_engine_preempts_youngest_and_resumes_exactly():
     a = eng.submit([1, 2], 9)
     b = eng.submit([3, 4], 9)
     for _ in range(60):
-        eng._sweep(); eng._admit(); eng._prefill_tick()
-        eng._grow_or_preempt(); eng._decode_tick()
+        tick(eng)
         if a.done and b.done:
             break
     assert a.outcome == "ok" and b.outcome == "ok"
@@ -488,27 +455,79 @@ def test_engine_stop_frees_everything():
 
 # --------------------------------------------- overlapped tick pipeline
 
-def test_overlap_engine_matches_sync_engine():
+def test_running_engine_matches_hand_stepped_engine():
     """The double-buffered pipeline is a pure latency optimization: for
-    every stream it must emit exactly the tokens the synchronous
-    (pre-overlap) loop emits."""
+    every stream the running engine (two threads, two ticks in flight)
+    emits exactly the tokens an engine stepped by hand emits (one pass,
+    then its landing, nothing else in flight), and both emit what any
+    correct schedule must."""
     prompts = [[3, 5], [7], [1, 2, 3], [9, 9], [4], [8, 1]]
-    outs = []
-    for overlap in (False, True):
-        eng = LLMEngine(_FakeModel(num_slots=2, num_blocks=32,
-                                   max_blocks_per_seq=8),
-                        overlap=overlap).start()
-        try:
-            assert eng.overlap is overlap
-            hs = [eng.submit(p, 5) for p in prompts]
-            _drain(hs)
-            outs.append([h.tokens for h in hs])
-            assert eng.allocator.used_blocks == 0
-        finally:
-            eng.stop()
-    assert outs[0] == outs[1]
-    for p, toks in zip(prompts, outs[1]):
-        assert toks == _reference(p, 5)
+
+    def engine():
+        return LLMEngine(_FakeModel(num_slots=2, num_blocks=32,
+                                    max_blocks_per_seq=8))
+
+    eng = engine()
+    stepped = [eng.submit(p, 5) for p in prompts]
+    for _ in range(200):
+        tick(eng)
+        if all(h.done for h in stepped):
+            break
+    assert all(h.outcome == "ok" for h in stepped)
+    assert eng.allocator.used_blocks == 0
+    eng.stop()
+
+    eng = engine().start()
+    try:
+        running = [eng.submit(p, 5) for p in prompts]
+        _drain(running)
+        assert eng.allocator.used_blocks == 0
+    finally:
+        eng.stop()
+    assert [h.tokens for h in running] == [h.tokens for h in stepped]
+    for p, h in zip(prompts, running):
+        assert h.tokens == _reference(p, 5)
+
+
+@pytest.mark.parametrize("kwargs", [{"mode": "oneshot"},
+                                    {"overlap": False}],
+                         ids=["mode-oneshot", "overlap-false"])
+def test_engine_refuses_the_schedulers_that_went(kwargs):
+    """The constructor still takes ``mode`` and ``overlap`` (the
+    benchmark's harness passes them) and accepts only the one value
+    each that names the scheduler there is."""
+    with pytest.raises(ValueError, match="one"):
+        LLMEngine(_FakeModel(), **kwargs)
+    eng = LLMEngine(_FakeModel(), mode="continuous", overlap=None)
+    assert "mode" not in eng.stats() and "overlap" not in eng.stats()
+    LLMEngine(_FakeModel(), overlap=True)
+
+
+def test_engine_refuses_a_model_without_the_dispatch_surface():
+    """``decode_step`` / ``read_tokens`` are what the engine requires
+    of a model: one with ``decode`` alone is refused by name, not
+    handed another scheduler."""
+
+    class _DecodeOnly:
+        num_slots, block_size, num_blocks = 2, 4, 8
+        max_blocks_per_seq, max_prompt_len = 4, 12
+
+        def prefill(self, prompt, row, sampling=None):
+            return 0
+
+        def decode(self, tokens, tables, positions, sampling=None):
+            return np.zeros((len(tokens),), np.int32)
+
+    with pytest.raises(TypeError, match="decode_step and read_tokens"):
+        LLMEngine(_DecodeOnly())
+
+
+@pytest.mark.parametrize("prefix", ["llama:", "glm_moe_lite:"])
+def test_spec_string_with_overlap_is_an_unknown_key(prefix):
+    with pytest.raises(ValueError, match=r"unknown llm spec keys "
+                                         r"\['overlap'\]"):
+        parse_llm_spec(prefix + "tiny:slots=2,overlap=0")
+    parse_llm_spec(prefix + "tiny:slots=2")
 
 
 def test_overlap_eos_discards_speculative_tokens():
@@ -567,7 +586,7 @@ def test_overlap_readback_failure_fails_streams_loudly():
             return super().read_tokens(batch)
 
     model = _FlakyModel(num_slots=2, num_blocks=32, max_blocks_per_seq=8)
-    eng = LLMEngine(model, overlap=True).start()
+    eng = LLMEngine(model).start()
     try:
         h = eng.submit([4], 30)
         _drain([h])
@@ -663,15 +682,14 @@ def test_sampled_stream_survives_preemption_deterministically():
     white-box setup as the greedy preemption test."""
     model = _FakeModel(num_slots=2, block_size=2, num_blocks=7,
                        max_blocks_per_seq=6, max_prompt_len=8)
-    eng = LLMEngine(model, overlap=False)
+    eng = LLMEngine(model)
     from zoo_tpu.obs.metrics import counter
     preempts0 = counter("zoo_llm_preempt_total").value
     samp = dict(temperature=1.0, seed=5)
     a = eng.submit([1, 2], 9, sampling=samp)
     b = eng.submit([3, 4], 9, sampling=samp)
     for _ in range(60):
-        eng._sweep(); eng._admit(); eng._prefill_tick()
-        eng._grow_or_preempt(); eng._decode_tick()
+        tick(eng)
         if a.done and b.done:
             break
     assert a.outcome == "ok" and b.outcome == "ok"
@@ -716,21 +734,17 @@ def test_chunked_prefill_interleaves_with_decode():
     whole-prompt stall the chunk executable removes."""
     model = _FakeModel(num_slots=2, num_blocks=64, max_blocks_per_seq=8,
                        max_prompt_len=32, prefill_chunk=4)
-    eng = LLMEngine(model, overlap=False)
-
-    def tick():
-        eng._sweep(); eng._admit(); eng._prefill_tick()
-        eng._grow_or_preempt(); eng._decode_tick()
+    eng = LLMEngine(model)
 
     a = eng.submit([1], 30)
     for _ in range(3):
-        tick()
+        tick(eng)
     before = len(a.tokens)
     assert before > 0
     long_h = eng.submit(list(range(1, 13)), 4)   # 12 tokens = 3 chunks
     progress = []
     for _ in range(2):
-        tick()
+        tick(eng)
         progress.append(len(a.tokens))
     # two ticks in: the long prompt is still mid-prefill (2 of 3 chunks
     # fed), yet the short stream gained a token EVERY tick
@@ -738,7 +752,7 @@ def test_chunked_prefill_interleaves_with_decode():
     assert model.chunks[-2:] == [(0, 4), (4, 4)]
     assert progress == [before + 1, before + 2]
     for _ in range(40):
-        tick()
+        tick(eng)
         if a.done and long_h.done:
             break
     assert a.tokens == _reference([1], 30)
@@ -778,7 +792,7 @@ def test_only_a_prompts_last_chunk_is_waited_for():
     model = _Model(num_slots=2, num_blocks=64, max_blocks_per_seq=16,
                    max_prompt_len=32, prefill_chunk=4,
                    decode_delay=0.002)
-    eng = LLMEngine(model, overlap=True).start()
+    eng = LLMEngine(model).start()
     try:
         a = eng.submit([1], 60)
         while len(a.tokens) < 3:
@@ -805,12 +819,11 @@ def test_chunked_prefill_preemption_resets_cleanly():
     model = _FakeModel(num_slots=2, block_size=2, num_blocks=7,
                        max_blocks_per_seq=6, max_prompt_len=8,
                        prefill_chunk=2)
-    eng = LLMEngine(model, overlap=False)
+    eng = LLMEngine(model)
     a = eng.submit([1, 2], 9)
     b = eng.submit([3, 4], 9)
     for _ in range(80):
-        eng._sweep(); eng._admit(); eng._prefill_tick()
-        eng._grow_or_preempt(); eng._decode_tick()
+        tick(eng)
         if a.done and b.done:
             break
     assert a.tokens == _reference([1, 2], 9)

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 import zoo_tpu.obs as obs
+from llm_tick import tick
 from zoo_tpu.obs import (
     MetricsExporter,
     MetricsRegistry,
@@ -495,8 +496,7 @@ def test_metrics_end_to_end_serving_fit_checkpoint(orca_ctx, tmp_path):
     # zoo_llm_prefix_cache_{hit,miss}_* and the shared/cached gauges),
     # and the cyclic prompt makes the prompt-lookup drafter propose
     # tokens the (x+1)%4 fake accepts — all jax-free
-    llm_eng = LLMEngine(_TickModel(), overlap=True,
-                        prefix_cache=True).start()
+    llm_eng = LLMEngine(_TickModel(), prefix_cache=True).start()
     try:
         for rid in ("scrape-a", "scrape-b"):
             h = llm_eng.submit([1, 2, 3, 1, 2, 3], 6, rid=rid)
@@ -523,7 +523,7 @@ def test_metrics_end_to_end_serving_fit_checkpoint(orca_ctx, tmp_path):
     # deterministic: a live engine loop finishes the best-effort
     # streams before the paid submit could ever contend for a slot
     qos_eng = LLMEngine(
-        _TickModel(), overlap=False, prefix_cache=False,
+        _TickModel(), prefix_cache=False,
         tenancy=TenantRegistry(
             spec="gold:class=0,rate=0;brz:class=1,rate=0;"
                  "free:class=1,rate=0.001,burst=1",
@@ -533,11 +533,7 @@ def test_metrics_end_to_end_serving_fit_checkpoint(orca_ctx, tmp_path):
         for _ in range(ticks):
             if handles and all(h.done for h in handles):
                 return
-            qos_eng._sweep()
-            qos_eng._admit()
-            qos_eng._prefill_tick()
-            qos_eng._grow_or_preempt()
-            qos_eng._decode_tick()
+            tick(qos_eng)
 
     f1 = qos_eng.submit([1, 2, 3], 4, rid="ten-f1", tenant="free")
     with pytest.raises(AdmissionError):   # burst of 1 is spent

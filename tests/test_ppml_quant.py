@@ -197,7 +197,7 @@ def test_quantize_mode_off_and_env_override(monkeypatch):
     monkeypatch.setenv("ZOO_INT8_MODE", "off")
     out2 = im.quantize_model(_small_model())
     assert out2._quant_path == "bf16"
-    # ...but an explicit call-site mode always wins (bench relies on
+    # ...but an explicit call-site mode always wins (an A/B relies on
     # mode="force" measuring real int8 whatever the ambient env says)
     out3 = im.quantize_model(_small_model(), mode="force")
     assert out3._quant_path == "int8"

@@ -22,6 +22,7 @@ import time
 import numpy as np
 import pytest
 
+from llm_tick import tick
 from zoo_tpu.serving.llm.engine import LLMEngine
 from zoo_tpu.serving.llm.speculative import (
     PromptLookup,
@@ -127,12 +128,11 @@ NOISE = np.array([9, 17, 23], np.int32)
 
 
 class TestEngineSpecFake:
-    @pytest.mark.parametrize("overlap", [True, False])
-    def test_spec_stream_identical_to_plain(self, overlap):
-        ref = _drain([LLMEngine(_SpecFake(spec_k=0), overlap=overlap)
+    def test_spec_stream_identical_to_plain(self):
+        ref = _drain([LLMEngine(_SpecFake(spec_k=0))
                       .start().submit(CYCLIC, 10, rid="p")])
         fake = _SpecFake(spec_k=3)
-        eng = LLMEngine(fake, overlap=overlap).start()
+        eng = LLMEngine(fake).start()
         try:
             got = _drain([eng.submit(CYCLIC, 10, rid="s")])
             assert got == ref
@@ -146,6 +146,33 @@ class TestEngineSpecFake:
                 "tokens")
         finally:
             eng.stop()
+
+    def test_hand_stepped_verify_pass_matches_the_running_engine(self):
+        """``tick(eng)`` on a ``spec_k > 0`` engine is one pass that
+        dispatches a VERIFY batch and its landing: the stream it emits
+        is the running engine's, token for token."""
+        eng = LLMEngine(_SpecFake(spec_k=3)).start()
+        try:
+            running = _drain([eng.submit(CYCLIC, 10, rid="r"),
+                              eng.submit(NOISE, 6, rid="n")])
+        finally:
+            eng.stop()
+        fake = _SpecFake(spec_k=3)
+        eng = LLMEngine(fake)
+        hs = [eng.submit(CYCLIC, 10, rid="r"),
+              eng.submit(NOISE, 6, rid="n")]
+        assert tick(eng), "the first pass admits, prefills and verifies"
+        assert fake.verify_calls == 1
+        assert eng.stats()["decode_steps"] == 1      # landed inline
+        assert not any(s.spec_inflight for s in eng._slots)
+        for _ in range(50):
+            if all(h.done for h in hs):
+                break
+            tick(eng)
+        assert [list(h.tokens) for h in hs] == running
+        assert eng.stats()["spec_accepted_tokens"] > 0
+        assert eng.allocator.used_blocks == 0
+        eng.stop()
 
     def test_acyclic_prompt_degenerates_to_plain_decode(self):
         fake = _SpecFake(spec_k=3, mod=50)

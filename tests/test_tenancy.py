@@ -24,6 +24,7 @@ import time
 import numpy as np
 import pytest
 
+from llm_tick import HostStepped, tick
 from zoo_tpu.serving.llm.engine import (
     AdmissionError,
     LLMEngine,
@@ -158,7 +159,7 @@ def test_retry_hint_is_per_tenant():
 
 # --------------------------------------------------- fake engine harness
 
-class _FakeModel:
+class _FakeModel(HostStepped):
     """Deterministic jax-free model with the PagedLlamaModel surface
     (same contract as test_kv_prefix's): the next token is a pure
     function of (last token, position), so streams are byte-comparable
@@ -194,17 +195,9 @@ class _FakeModel:
                          for t, p in zip(tokens, positions)], np.int32)
 
 
-def _tick(eng):
-    eng._sweep()
-    eng._admit()
-    eng._prefill_tick()
-    eng._grow_or_preempt()
-    eng._decode_tick()
-
-
 def _run_to_completion(eng, handles, ticks=400):
     for _ in range(ticks):
-        _tick(eng)
+        tick(eng)
         if all(h.done for h in handles):
             return
     raise AssertionError(
@@ -309,7 +302,7 @@ def test_slot_quota_skips_tenant_without_blocking_queue():
     c1 = eng.submit([1, 2, 3], 6, rid="c1", tenant="capped")
     c2 = eng.submit([1, 2, 3], 6, rid="c2", tenant="capped")
     o1 = eng.submit([4, 5, 6], 3, rid="o1", tenant="other")
-    _tick(eng)
+    tick(eng)
     live = {s.handle.id for s in eng._slots if s.handle is not None}
     assert live == {"c1", "o1"}
     assert eng.stats()["tenants"]["capped"]["waiting"] == 1
@@ -328,7 +321,7 @@ def test_kv_quota_skips_tenant_without_blocking_queue():
     ok = eng.submit([4, 5, 6], 3, rid="ok", tenant="other")
     small = eng.submit([7, 8], 3, rid="small", tenant="capped")
     for _ in range(200):
-        _tick(eng)
+        tick(eng)
         if ok.done and small.done:
             break
     # over-quota stream parks; within-quota traffic flows around it
@@ -368,11 +361,11 @@ def test_class_preemption_resumes_victim_byte_identical():
     f1 = eng.submit([1, 2, 3, 4], 8, rid="f1", tenant="free")
     f2 = eng.submit([5, 6, 7, 8], 8, rid="f2", tenant="free")
     for _ in range(3):
-        _tick(eng)
+        tick(eng)
     assert not f1.done and not f2.done       # both decoding
     p = eng.submit([9, 10, 11], 6, rid="p", tenant="paid")
-    _tick(eng)                               # preempts f2 at admit end
-    _tick(eng)                               # the freed slot admits p
+    tick(eng)                               # preempts f2 at admit end
+    tick(eng)                               # the freed slot admits p
     # the YOUNGEST best-effort stream lost its slot to the paid class
     live = {s.handle.id for s in eng._slots if s.handle is not None}
     assert live == {"p", "f1"}
@@ -394,9 +387,9 @@ def test_class_preemption_never_evicts_a_peer():
                     tenancy=reg)
     a = eng.submit([1, 2, 3], 6, rid="a", tenant="a")
     for _ in range(2):
-        _tick(eng)
+        tick(eng)
     b = eng.submit([4, 5, 6], 6, rid="b", tenant="b")
-    _tick(eng)
+    tick(eng)
     assert eng._slots[0].handle is not None
     assert eng._slots[0].handle.id == "a"    # undisturbed
     _run_to_completion(eng, [a, b])

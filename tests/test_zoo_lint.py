@@ -9,7 +9,7 @@ Three layers:
   allowlist, the linter itself never imports jax, and the knob
   registry round-trips every ``ZOO_*`` name greppable in the tree;
 * the in-suite strict gate — runs every AST pass over the repo and
-  writes ``LINT.json`` beside the ``BENCH_*.json`` trajectory files.
+  writes ``LINT.json`` at the root of the repo.
 
 The compiled-HLO passes are fixture-tested here on synthetic module
 text; their real-executable wiring lives in the compile-census tests
@@ -529,7 +529,7 @@ class TestSelfApplication:
         from zoo_tpu.common.knobs import KNOBS
         tokens = set()
         roots = ["zoo_tpu", "scripts"]
-        files = ["bench.py", "__graft_entry__.py"]
+        files = ["__graft_entry__.py"]
         for root in roots:
             for dirpath, dirnames, filenames in os.walk(
                     os.path.join(REPO, root)):

@@ -82,8 +82,8 @@ class Context:
     Parses each source file once (``ast_of``/``source_of`` are
     cached); passes see repo-relative POSIX paths. ``py_files`` is
     the library surface (``zoo_tpu/``); ``aux_py_files`` adds the
-    entry-point surface (``scripts/``, ``bench.py``) that knob-usage
-    scans also cover.
+    entry-point surface (``scripts/``, ``__graft_entry__.py``) that
+    knob-usage scans also cover.
     """
 
     def __init__(self, root: str,
@@ -112,14 +112,13 @@ class Context:
         return self._walk_py("zoo_tpu")
 
     def aux_py_files(self) -> List[str]:
-        """Entry points outside the library: ``scripts/``,
-        ``bench.py``, ``__graft_entry__.py`` (knob reads there count
-        as usage; parse-site discipline is not enforced on them)."""
+        """Entry points outside the library: ``scripts/`` and
+        ``__graft_entry__.py`` (knob reads there count as usage;
+        parse-site discipline is not enforced on them)."""
         out = self._walk_py("scripts") if os.path.isdir(
             os.path.join(self.root, "scripts")) else []
-        for single in ("bench.py", "__graft_entry__.py"):
-            if os.path.exists(os.path.join(self.root, single)):
-                out.append(single)
+        if os.path.exists(os.path.join(self.root, "__graft_entry__.py")):
+            out.append("__graft_entry__.py")
         return out
 
     # -- cached access -----------------------------------------------------

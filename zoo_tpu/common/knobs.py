@@ -8,8 +8,8 @@ PR touched. This module is the single declarative source of truth the
 ``zoo-lint`` knob-contract pass (:mod:`zoo_tpu.analysis.knob_pass`)
 checks the tree against:
 
-* every ``ZOO_*`` name read anywhere in ``zoo_tpu/`` / ``scripts/`` /
-  ``bench.py`` must be registered here (rule ``KNOB-UNDECLARED``);
+* every ``ZOO_*`` name read anywhere in ``zoo_tpu/`` / ``scripts/``
+  must be registered here (rule ``KNOB-UNDECLARED``);
 * every registered knob must still be read somewhere (``KNOB-DEAD``);
 * every non-``internal`` knob must appear in its owning doc page
   (``KNOB-UNDOCUMENTED``), and the marked knob tables in
@@ -268,9 +268,6 @@ _k("ZOO_LLM_PREFILL_CHUNK", "int", 0,
 _k("ZOO_LLM_PREFILL_BUDGET", "int", 0,
    "prompt tokens fed per tick when chunking", _LLM, "llm",
    show="chunk size")
-_k("ZOO_LLM_OVERLAP", "bool", True,
-   "the double-buffered async tick pipeline (0 = the synchronous "
-   "pre-PR-10 loop)", _LLM, "llm")
 _k("ZOO_LLM_PREFIX_CACHE", "bool", False,
    "content-hash block reuse with copy-on-write (spec: "
    "`prefix_cache=1`): a shared prompt prefix costs its KV blocks "
@@ -307,9 +304,6 @@ _k("ZOO_LLM_SEED", "int", 0,
    "weight seed for spec-built params", _LLM, "llm")
 _k("ZOO_LLM_EOS", "int", None,
    "eos token id (stops a stream early)", _LLM, "llm", show="unset")
-_k("ZOO_LLM_MODE", "str", "continuous",
-   "`oneshot` = request-level baseline", _LLM, "llm",
-   show="`continuous`")
 _k("ZOO_LLM_MAX_WAITING", "int", 256,
    "waiting-queue bound (overflow sheds retryable)", _LLM, "llm")
 _k("ZOO_LLM_FINISHED_CACHE", "int", 256,

@@ -3,9 +3,9 @@ dispatch point.
 
 The training-side roofline stalls on convs: the XLA conv path measures
 ~0.197 MFU at ResNet-50's dominant shapes (BENCH_r05) while the MXU
-sits idle between im2col materializations. These kernels lower the
-exact 1x1/3x3 shapes ``bench_conv_roofline`` measures to implicit GEMM
-— no im2col buffer ever exists in HBM:
+sits idle between im2col materializations. These kernels lower
+ResNet-50's 1x1/3x3 shapes to implicit GEMM — no im2col buffer ever
+exists in HBM:
 
 * **1x1**: a tiled matmul over the flattened spatial axis (stride
   handled by pre-slicing rows/cols, which for k=1 is exactly SAME and

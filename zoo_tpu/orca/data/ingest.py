@@ -14,8 +14,8 @@ feeding BigDL's per-executor miniBatch queues.
 Every stage records its busy time into the
 ``zoo_shard_pipeline_stage_seconds{stage=...}`` histogram, and a
 :class:`PipelineStats` passed to :func:`staged_pipeline` accumulates
-per-stage busy seconds so callers (``bench.py``,
-``scripts/check_data_plane.py``) can report the **overlap ratio** —
+per-stage busy seconds so callers
+(``scripts/check_data_plane.py``) can report the **overlap ratio** —
 total stage-busy seconds divided by pipeline wall time; 1.0 means the
 stages ran back-to-back serially, above 1.0 means real overlap.
 

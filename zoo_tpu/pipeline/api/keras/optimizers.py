@@ -102,8 +102,7 @@ class AdamWeightDecay(Optimizer):
     silently keeps the optax path rather than erroring, so one env var
     can cover a whole job). Inside a >1-device mesh the update runs as
     the partitionable elementwise form; off-TPU the kernel interprets —
-    either way the fallback is clean (``bench_fused_optim`` measures the
-    A/B)."""
+    either way the fallback is clean."""
 
     def __init__(self, lr: float = 0.001, beta_1: float = 0.9,  # zoo-lint: config-parse
                  beta_2: float = 0.999, epsilon: float = 1e-6,

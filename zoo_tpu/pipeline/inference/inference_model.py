@@ -166,8 +166,7 @@ def save_encrypted(model, path: str, secret: str, salt: str,
 
 
 # auto mode keeps int8 only when it beats the float forward by this
-# factor (also the reference point bench.py reports the chosen path
-# against — one constant, one decision rule)
+# factor (one constant, one decision rule)
 INT8_MIN_SPEEDUP = 1.05
 
 

@@ -12,8 +12,8 @@ sequences the slots hold (Orca's in-flight batching, OSDI '22).
 
 This revision rebuilds the tick itself around the device:
 
-* **Overlapped tick pipeline** (``ZOO_LLM_OVERLAP``, default on) — the
-  loop no longer blocks on each tick's result. Tick N+1's input tokens
+* **Overlapped tick pipeline** (the engine's one loop) — the
+  scheduler never blocks on a tick's result. Tick N+1's input tokens
   are tick N's ON-DEVICE output batch (``model.decode_step`` chains
   them without a host round trip; freshly admitted slots override
   their lane with the prefill token via a host mask), so the scheduler
@@ -72,6 +72,10 @@ The model behind the engine is any adapter with the
 :class:`~zoo_tpu.serving.llm.model.PagedLlamaModel` surface
 (``prefill`` / ``decode_step`` / ``read_tokens`` / shape attrs), so
 scheduler tests run against a pure-python fake without importing jax.
+The scheduler thread runs :meth:`LLMEngine._pass` and hands what it
+dispatched to the readback thread's :meth:`LLMEngine._land`; a test
+that wants a deterministic schedule calls the same two on its own
+thread, engine not started (``tests/llm_tick.py``).
 """
 
 from __future__ import annotations
@@ -79,7 +83,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import gc
-import os
 import queue as _queue
 import threading
 import time
@@ -227,8 +230,7 @@ _tenant_slots = gauge(
 #: catalogue"): disjoint, and together they cover one pass of the loop,
 #: so a device-idle gap under the scheduler always has one name. The
 #: ``llm.model.*`` spans nest inside ``prefill`` / ``dispatch``; the
-#: two ``llm.readback.*`` spans are the readback thread's under
-#: overlap and the scheduler's own on the synchronous path.
+#: two ``llm.readback.*`` spans are the readback thread's.
 TICK_LEAF_SPANS = (
     "llm.tick.lock_wait", "llm.tick.sweep_admit", "llm.tick.prefill",
     "llm.tick.grow_build", "llm.tick.inflight_wait", "llm.tick.dispatch",
@@ -499,13 +501,12 @@ class LLMEngine:
     """``LLMEngine(model).start()`` → ``submit()`` streams until
     ``stop()``.
 
-    ``mode="continuous"`` (default) admits into free slots every
-    iteration; ``mode="oneshot"`` is the request-level baseline the
-    bench compares against — a wave is admitted only when every slot is
-    empty and drains completely before the next wave. ``overlap=None``
-    reads ``ZOO_LLM_OVERLAP`` (default on): the double-buffered async
-    tick pipeline, continuous mode only, and only for models exposing
-    the ``decode_step``/``read_tokens`` dispatch surface."""
+    One scheduler: free slots are filled every pass and the tick
+    pipeline is double-buffered against the device. ``model`` must
+    expose the ``decode_step`` / ``read_tokens`` dispatch surface.
+    ``mode`` and ``overlap`` take the one value each that names this
+    scheduler (``"continuous"``; ``None`` / ``True``) and are neither
+    stored nor reported."""
 
     def __init__(self, model, mode: str = "continuous",
                  max_waiting: Optional[int] = None,
@@ -515,11 +516,27 @@ class LLMEngine:
                  spec_ngram: Optional[int] = None,
                  role: Optional[str] = None,
                  tenancy=None):
-        if mode not in ("continuous", "oneshot"):
-            raise ValueError(f"unknown scheduling mode {mode!r}")
+        # mode / overlap: kept because the benchmark's harness passes
+        # them (benchmarks/harness/serve_cell.py); they leave the
+        # signature when it stops (ROADMAP D13)
+        if mode != "continuous":
+            raise ValueError(
+                f"unknown scheduling mode {mode!r}: the engine has one "
+                "scheduler, \"continuous\"")
+        if overlap not in (None, True):
+            raise ValueError(
+                f"overlap={overlap!r}: the engine has one tick "
+                "pipeline, the overlapped one (pass None or True)")
+        missing = [m for m in ("decode_step", "read_tokens")
+                   if not hasattr(model, m)]
+        if missing:
+            raise TypeError(
+                f"{type(model).__name__} lacks {' and '.join(missing)}: "
+                "the engine dispatches every tick through "
+                "model.decode_step and reads it back through "
+                "model.read_tokens")
         watch_compiles()    # no-op for the jax-free synthetic model
         self.model = model
-        self.mode = mode
         # disaggregated serving (docs/disaggregated_serving.md): the
         # replica's role in a mixed pool. "prefill" parks finished
         # prompts for kv_migrate handoff instead of decoding them,
@@ -541,9 +558,7 @@ class LLMEngine:
         if spec_k is None:
             spec_k = model_k
         self.spec_k = max(0, min(int(spec_k), model_k))
-        self._spec = self.spec_k > 0 and \
-            hasattr(model, "verify_step") and \
-            hasattr(model, "read_tokens")
+        self._spec = self.spec_k > 0 and hasattr(model, "verify_step")
         if spec_ngram is None:
             spec_ngram = env_int("ZOO_LLM_SPEC_NGRAM", 3)
         self.spec_ngram = max(1, int(spec_ngram))
@@ -553,11 +568,6 @@ class LLMEngine:
         self._spec_drafted_lanes = 0   # ... with a non-empty draft
         self._spec_proposed_n = 0
         self._spec_accepted_n = 0
-        if overlap is None:
-            overlap = knob_value("ZOO_LLM_OVERLAP")
-        self.overlap = bool(overlap) and mode == "continuous" and \
-            hasattr(model, "decode_step") and hasattr(model,
-                                                     "read_tokens")
         if prefix_cache is None:
             prefix_cache = knob_value("ZOO_LLM_PREFIX_CACHE")
         self.prefix_cache = bool(prefix_cache)
@@ -612,10 +622,14 @@ class LLMEngine:
         self._chunk = int(getattr(model, "prefill_chunk_size", 0) or 0)
         self._prefill_budget = env_int("ZOO_LLM_PREFILL_BUDGET",
                                        self._chunk) if self._chunk else 0
-        # overlap bookkeeping
+        # tick pipeline: what a pass dispatched waits on _rbq for its
+        # landing; at most two ticks in flight; _prev_batch is the last
+        # decode tick's ON-DEVICE output, the next tick's input (owned
+        # by whoever runs the passes: the scheduler thread)
         self._rbq: "_queue.Queue" = _queue.Queue()
         self._inflight = threading.Semaphore(2)
         self._rb_thread: Optional[threading.Thread] = None
+        self._prev_batch = None
         self._busy_win: Deque[Tuple[float, float]] = \
             collections.deque(maxlen=64)
         # set (under the lock) when a dispatch or readback failed: the
@@ -895,13 +909,6 @@ class LLMEngine:
                     if now - p["staged_at"] > self._handoff_ttl]:
             self._adopted.pop(rid, None)
 
-    def _admit_ready(self) -> bool:
-        if self.mode == "oneshot":
-            # request-level baseline: a new wave only starts on an
-            # EMPTY batch (what serving did before this engine)
-            return all(s.handle is None for s in self._slots)
-        return True
-
     def _slots_by_tenant(self) -> Dict[str, int]:
         out: Dict[str, int] = {}
         for s in self._slots:
@@ -954,8 +961,6 @@ class LLMEngine:
         return best
 
     def _admit(self):
-        if not self._admit_ready():
-            return
         for slot in self._slots:
             if slot.handle is not None:
                 continue
@@ -1616,12 +1621,13 @@ class LLMEngine:
         with self._lock:
             self._wait.appendleft(h)
 
-    def _build_tick(self, device_chain: bool):
+    def _build_tick(self):
         """Assemble the fixed-shape decode operands for every decoding
         slot (one lane per slot; idle/prefilling lanes write to the
-        trash block and are never read). ``device_chain`` feeds
-        continuing lanes from the previous tick's on-device batch;
-        the sync path host-feeds every lane from ``slot.last_token``.
+        trash block and are never read). Continuing lanes are fed from
+        the previous tick's on-device batch; a lane fresh from prefill
+        (or re-seeded after a failed tick) overrides its own with
+        ``slot.host_token`` once.
         Advances positions/sched counters — the caller WILL dispatch.
         Returns None when no lane decodes this tick."""
         S = self.model.num_slots
@@ -1649,14 +1655,10 @@ class LLMEngine:
             tables[i] = self._table_row(
                 self.allocator.blocks_of(h.id))
             positions[i] = slot.position
-            if device_chain:
-                if slot.use_host:
-                    use[i] = True
-                    host[i] = slot.host_token
-                    slot.use_host = False
-            else:
+            if slot.use_host:
                 use[i] = True
-                host[i] = slot.last_token
+                host[i] = slot.host_token
+                slot.use_host = False
             t, k, p, s = h.sampling
             temps[i], topks[i], topps[i], seeds[i] = t, k, p, s
             slot.position += 1
@@ -1723,7 +1725,7 @@ class LLMEngine:
             h.lookup_len = len(h.tokens)
         return h.lookup.propose(k)
 
-    def _build_spec_tick(self):
+    def _build_verify_tick(self):
         """Under the lock: assemble ONE fixed-shape verify batch —
         (slots, spec_k + 1) candidate rows, row 0 the incoming token,
         rows 1.. the drafter's proposals, zero-padded. The draft span
@@ -1824,81 +1826,6 @@ class LLMEngine:
                                self._spec_lanes)
         self._publish()
 
-    def _spec_tick(self) -> bool:
-        """The SYNCHRONOUS verify tick (overlap-off runs, oneshot
-        baseline, white-box tests): build, dispatch, block on
-        readback, apply inline."""
-        with self._held("llm.tick.grow_build"):
-            built = self._build_spec_tick()
-        if built is None:
-            return False
-        tokens, tables, positions, lanes, snapshot = built
-        t0 = time.perf_counter()
-        try:
-            with span("llm.tick.dispatch"):
-                batch = self.model.verify_step(tokens, tables,
-                                               positions, lanes)
-            with span("llm.readback.device"):
-                arr = self.model.read_tokens(batch)
-        except Exception as e:  # noqa: BLE001 — lost verify lanes end
-            # their streams loudly, same contract as a decode tick
-            with self._lock:
-                self._fail_lanes([(i, h, ep) for i, h, ep, _
-                                  in snapshot], e)
-            return True
-        t1 = time.perf_counter()
-        self._span_tick_decode(t0, t1)
-        _tick_seconds.labels(phase="decode").observe(t1 - t0)
-        self._note_busy(t0, t1)
-        self._decode_steps += 1
-        _steps.inc()
-        self._tick_flight()
-        with self._applying():
-            self._apply_spec(snapshot, np.asarray(arr))
-        _tick_seconds.labels(phase="readback").observe(
-            time.perf_counter() - t1)
-        return True
-
-    def _decode_tick(self):
-        """The SYNCHRONOUS tick (request-level baseline, overlap-off
-        runs, and white-box tests): host-fed lanes, blocking readback,
-        apply inline."""
-        with self._held("llm.tick.grow_build"):
-            built = self._build_tick(device_chain=False)
-        if built is None:
-            return False
-        host, use, tables, positions, lanes, snapshot = built
-        t0 = time.perf_counter()
-        try:
-            if hasattr(self.model, "decode_step"):
-                with span("llm.tick.dispatch"):
-                    batch = self.model.decode_step(
-                        None, host, use, tables, positions, lanes)
-                with span("llm.readback.device"):
-                    arr = self.model.read_tokens(batch)
-            else:
-                with span("llm.tick.dispatch"):
-                    arr = self.model.decode(host, tables, positions,
-                                            lanes)
-        except Exception as e:  # noqa: BLE001 — same contract as the
-            # overlap pipeline: lost tokens end their streams loudly
-            # instead of leaving a silent hole + wedged slot
-            with self._lock:
-                self._fail_lanes(snapshot, e)
-            return True
-        t1 = time.perf_counter()
-        self._span_tick_decode(t0, t1)
-        _tick_seconds.labels(phase="decode").observe(t1 - t0)
-        self._note_busy(t0, t1)
-        self._decode_steps += 1
-        _steps.inc()
-        self._tick_flight()
-        with self._applying():
-            self._apply_tokens(snapshot, arr)
-        _tick_seconds.labels(phase="readback").observe(
-            time.perf_counter() - t1)
-        return True
-
     # -- the loop's own spans (docs/observability.md) ----------------------
     @contextlib.contextmanager
     def _held(self, leaf: str):
@@ -1951,7 +1878,7 @@ class LLMEngine:
         emit_span("llm.tick.schedule",
                   time.time() - (time.perf_counter() - t0), dur, t0=t0)
 
-    # -- overlap pipeline --------------------------------------------------
+    # -- the tick pipeline -------------------------------------------------
     def _note_busy(self, t_start: float, t_ready: float):
         """Record one tick's device-busy interval and refresh the
         overlap gauge over the recent window (busy intervals are
@@ -1977,199 +1904,189 @@ class LLMEngine:
             return None
         return min(1.0, sum(b for _, b in win[1:]) / wall)
 
+    def _land(self, item):
+        """A landing: one dispatched tick's tokens come to the host and
+        reach their streams. The readback thread runs it for every item
+        the scheduler put on ``_rbq``."""
+        kind, batch, snapshot, t_dispatch = item
+        try:
+            with span("llm.readback.device"):
+                arr = self.model.read_tokens(batch)
+        except Exception as e:  # noqa: BLE001 — these lanes'
+            # tokens are gone (and the donated-cache chain may be
+            # poisoned): end the streams loudly and tell the
+            # dispatcher to re-seed the device token chain
+            with self._lock:
+                self._fail_lanes(
+                    snapshot if kind == "decode" else
+                    [(i, h, ep) for i, h, ep, _ in snapshot], e)
+            self._inflight.release()
+            self._wake.set()
+            return
+        t_ready = time.perf_counter()
+        self._span_tick_decode(t_dispatch, t_ready)
+        _tick_seconds.labels(phase="decode").observe(
+            t_ready - t_dispatch)
+        self._note_busy(t_dispatch, t_ready)
+        with self._applying():
+            if kind == "spec":
+                self._apply_spec(snapshot, np.asarray(arr))
+            else:
+                self._apply_tokens(snapshot, arr)
+        _tick_seconds.labels(phase="readback").observe(
+            time.perf_counter() - t_ready)
+        self._decode_steps += 1
+        _steps.inc()
+        self._tick_flight()
+        self._inflight.release()
+        self._wake.set()
+
     def _readback_loop(self):
         while True:
             item = self._rbq.get()
             if item is None:
                 return
-            kind, batch, snapshot, t_dispatch = item
-            try:
-                with span("llm.readback.device"):
-                    arr = self.model.read_tokens(batch)
-            except Exception as e:  # noqa: BLE001 — these lanes'
-                # tokens are gone (and the donated-cache chain may be
-                # poisoned): end the streams loudly and tell the
-                # dispatcher to re-seed the device token chain
-                with self._lock:
-                    self._fail_lanes(
-                        snapshot if kind == "decode" else
-                        [(i, h, ep) for i, h, ep, _ in snapshot], e)
-                self._inflight.release()
-                self._wake.set()
-                continue
-            t_ready = time.perf_counter()
-            self._span_tick_decode(t_dispatch, t_ready)
-            _tick_seconds.labels(phase="decode").observe(
-                t_ready - t_dispatch)
-            self._note_busy(t_dispatch, t_ready)
-            with self._applying():
-                if kind == "spec":
-                    self._apply_spec(snapshot, np.asarray(arr))
-                else:
-                    self._apply_tokens(snapshot, arr)
-            _tick_seconds.labels(phase="readback").observe(
-                time.perf_counter() - t_ready)
-            self._decode_steps += 1
-            _steps.inc()
-            self._tick_flight()
-            self._inflight.release()
-            self._wake.set()
+            self._land(item)
 
-    def _loop_overlapped(self):
-        """The double-buffered tick pipeline: dispatch tick N, then run
-        the host scheduler for tick N+1 while the device executes and
-        the readback thread streams tick N-1's tokens out. Tick N+1's
-        continuing lanes consume tick N's ON-DEVICE output batch, so
+    def _pass(self):
+        """A pass of the scheduler: sweep and admit, feed one budget of
+        prompt chunks, grow or preempt, build and dispatch ONE decode
+        (or verify) batch. Returns what the landing needs — ``(kind,
+        batch, snapshot, t_dispatch)``, which the scheduler thread puts
+        on ``_rbq`` — or None when nothing was dispatched (no decodable
+        lane, a failed dispatch, or the engine is stopping).
+
+        The pipeline is double-buffered: tick N+1's continuing lanes
+        consume tick N's ON-DEVICE output batch (``_prev_batch``), so
         the steady-state hot path moves slots x 1 ids to the host and
-        nothing to the device but block tables and positions."""
+        nothing to the device but block tables and positions, and the
+        host schedules tick N+1 while the device executes tick N and
+        the readback thread streams tick N-1's tokens out."""
+        with span("llm.tick.lock_wait"), self._lock:
+            broken = self._chain_broken
+        if broken:
+            # drain the pipeline first — every still-in-flight
+            # batch chained on the failed computation will fail
+            # its own readback and error-finish its own lanes —
+            # then re-seed the SURVIVING decode slots (streams
+            # never in a failed batch) from their last APPLIED
+            # token and restart the device chain from host state
+            with span("llm.tick.reseed"):
+                grabbed = 0
+                while grabbed < 2 and not self._stop.is_set():
+                    if self._inflight.acquire(timeout=0.5):
+                        grabbed += 1
+                with self._lock:
+                    self._chain_broken = False
+                    for slot in self._slots:
+                        if slot.handle is not None and \
+                                slot.phase == "decode":
+                            slot.use_host = True
+                            slot.host_token = slot.last_token
+                self._prev_batch = None
+                for _ in range(grabbed):
+                    self._inflight.release()
+            if self._stop.is_set():
+                return None
+        # bound the pipeline depth: at most 2 ticks in flight.
+        # The pass waits for its place BEFORE it feeds a prompt
+        # chunk, so that the chunk queues behind one tick and
+        # one chunk on the device and not behind two of each
+        with span("llm.tick.inflight_wait"):
+            while not self._inflight.acquire(timeout=0.5):
+                if self._stop.is_set():
+                    return None
+        t0 = time.perf_counter()
+        with self._held("llm.tick.sweep_admit"):
+            self._sweep()
+            self._admit()
+        t1 = time.perf_counter()
+        # device prefill runs UNLOCKED: submissions and token
+        # readback keep flowing while a long prompt feeds
+        self._timed_prefill_tick()
+        t2 = time.perf_counter()
+        with self._held("llm.tick.grow_build"):
+            self._grow_or_preempt()
+            built = self._build_verify_tick() if self._spec \
+                else self._build_tick()
+        self._span_tick_schedule(
+            t0, (t1 - t0) + (time.perf_counter() - t2))
+        if built is None:
+            self._inflight.release()
+            # no decodable lane: break the device token chain
+            # (every post-idle admission is host-fed anyway).
+            # The wait also parks the loop when the waiting queue is
+            # only KV-gated; submit() sets _wake, so a fresh request
+            # still admits at once
+            self._prev_batch = None
+            with span("llm.tick.idle"):
+                self._wake.wait(0.005)
+            self._wake.clear()
+            return None
+        t_d = time.perf_counter()
+        if self._spec:
+            # verify batches are host-fed (the accept length
+            # decides each seat's next base position, so spec
+            # lanes cannot chain on-device). In steady state
+            # every ready seat rides ONE batch and the next
+            # build waits for its apply — pipeline depth 1,
+            # NOT the decode path's double-buffering: a verify
+            # pass streams the weights once for ALL seats, so
+            # splitting seats across alternating batches would
+            # double the HBM bill per token. Speculation must
+            # win on accept amortization (which is why it is
+            # opt-in, not default); only seats entering decode
+            # mid-pass form a second in-flight batch.
+            tokens, tables, positions, lanes, snapshot = built
+            try:
+                with span("llm.tick.dispatch"):
+                    batch = self.model.verify_step(
+                        tokens, tables, positions, lanes)
+            except Exception as e:  # noqa: BLE001
+                with self._lock:
+                    self._fail_lanes([(i, h, ep) for i, h, ep,
+                                      _ in snapshot], e)
+                self._inflight.release()
+                return None
+            self._prev_batch = None
+            return ("spec", batch, snapshot, t_d)
+        host, use, tables, positions, lanes, snapshot = built
+        try:
+            with span("llm.tick.dispatch"):
+                self._prev_batch = self.model.decode_step(
+                    self._prev_batch, host, use, tables, positions,
+                    lanes)
+        except Exception as e:  # noqa: BLE001 — consuming a
+            # poisoned prev batch / cache raises here; fail the
+            # built lanes loudly and re-seed instead of letting
+            # the scheduler thread die with streams hanging
+            with self._lock:
+                self._fail_lanes(snapshot, e)
+            self._inflight.release()
+            return None
+        return ("decode", self._prev_batch, snapshot, t_d)
+
+    def _loop(self):
+        """The scheduler thread: a pass, and what it dispatched goes to
+        the readback thread for its landing."""
         self._rb_thread = threading.Thread(
             target=self._readback_loop, daemon=True,
             name="zoo-llm-readback")
         self._rb_thread.start()
-        prev_batch = None
         try:
             while not self._stop.is_set():
-                with span("llm.tick.lock_wait"), self._lock:
-                    broken = self._chain_broken
-                if broken:
-                    # drain the pipeline first — every still-in-flight
-                    # batch chained on the failed computation will fail
-                    # its own readback and error-finish its own lanes —
-                    # then re-seed the SURVIVING decode slots (streams
-                    # never in a failed batch) from their last APPLIED
-                    # token and restart the device chain from host state
-                    with span("llm.tick.reseed"):
-                        grabbed = 0
-                        while grabbed < 2 and not self._stop.is_set():
-                            if self._inflight.acquire(timeout=0.5):
-                                grabbed += 1
-                        with self._lock:
-                            self._chain_broken = False
-                            for slot in self._slots:
-                                if slot.handle is not None and \
-                                        slot.phase == "decode":
-                                    slot.use_host = True
-                                    slot.host_token = slot.last_token
-                        prev_batch = None
-                        for _ in range(grabbed):
-                            self._inflight.release()
-                    if self._stop.is_set():
-                        return
-                # bound the pipeline depth: at most 2 ticks in flight.
-                # The pass waits for its place BEFORE it feeds a prompt
-                # chunk, so that the chunk queues behind one tick and
-                # one chunk on the device and not behind two of each
-                with span("llm.tick.inflight_wait"):
-                    while not self._inflight.acquire(timeout=0.5):
-                        if self._stop.is_set():
-                            return
-                t0 = time.perf_counter()
-                with self._held("llm.tick.sweep_admit"):
-                    self._sweep()
-                    self._admit()
-                t1 = time.perf_counter()
-                # device prefill runs UNLOCKED: submissions and token
-                # readback keep flowing while a long prompt feeds
-                self._timed_prefill_tick()
-                t2 = time.perf_counter()
-                with self._held("llm.tick.grow_build"):
-                    self._grow_or_preempt()
-                    built = self._build_spec_tick() if self._spec \
-                        else self._build_tick(device_chain=True)
-                self._span_tick_schedule(
-                    t0, (t1 - t0) + (time.perf_counter() - t2))
-                if built is None:
-                    self._inflight.release()
-                    # no decodable lane: break the device token chain
-                    # (every post-idle admission is host-fed anyway)
-                    prev_batch = None
-                    with span("llm.tick.idle"):
-                        self._wake.wait(0.005)
-                    self._wake.clear()
-                    continue
-                t_d = time.perf_counter()
-                if self._spec:
-                    # verify batches are host-fed (the accept length
-                    # decides each seat's next base position, so spec
-                    # lanes cannot chain on-device). In steady state
-                    # every ready seat rides ONE batch and the next
-                    # build waits for its apply — pipeline depth 1,
-                    # NOT the decode path's double-buffering: a verify
-                    # pass streams the weights once for ALL seats, so
-                    # splitting seats across alternating batches would
-                    # double the HBM bill per token. Speculation must
-                    # win on accept amortization (which is why it is
-                    # opt-in, not default); only seats entering decode
-                    # mid-pass form a second in-flight batch.
-                    tokens, tables, positions, lanes, snapshot = built
-                    try:
-                        with span("llm.tick.dispatch"):
-                            batch = self.model.verify_step(
-                                tokens, tables, positions, lanes)
-                    except Exception as e:  # noqa: BLE001
-                        with self._lock:
-                            self._fail_lanes([(i, h, ep) for i, h, ep,
-                                              _ in snapshot], e)
-                        self._inflight.release()
-                        continue
-                    self._rbq.put(("spec", batch, snapshot, t_d))
-                    prev_batch = None
-                    continue
-                host, use, tables, positions, lanes, snapshot = built
-                try:
-                    with span("llm.tick.dispatch"):
-                        prev_batch = self.model.decode_step(
-                            prev_batch, host, use, tables, positions,
-                            lanes)
-                except Exception as e:  # noqa: BLE001 — consuming a
-                    # poisoned prev batch / cache raises here; fail the
-                    # built lanes loudly and re-seed instead of letting
-                    # the scheduler thread die with streams hanging
-                    with self._lock:
-                        self._fail_lanes(snapshot, e)
-                    self._inflight.release()
-                    continue
-                self._rbq.put(("decode", prev_batch, snapshot, t_d))
+                item = self._pass()
+                if item is not None:
+                    self._rbq.put(item)
         finally:
+            self._prev_batch = None
             self._rbq.put(None)
             if self._rb_thread is not None:
                 self._rb_thread.join(timeout=10)
 
-    def _loop_sync(self):
-        while not self._stop.is_set():
-            t0 = time.perf_counter()
-            with self._held("llm.tick.sweep_admit"):
-                self._sweep()
-                self._admit()
-            t1 = time.perf_counter()
-            self._timed_prefill_tick()
-            t2 = time.perf_counter()
-            with self._held("llm.tick.grow_build"):
-                self._grow_or_preempt()
-            self._span_tick_schedule(
-                t0, (t1 - t0) + (time.perf_counter() - t2))
-            progressed = self._spec_tick() if self._spec \
-                else self._decode_tick()
-            if not progressed:
-                # also parks the loop when the waiting queue is only
-                # KV-gated (head cannot be admitted yet): without the
-                # sleep that state busy-spins a core. submit() sets
-                # _wake, so a fresh request still admits immediately.
-                with span("llm.tick.idle"):
-                    self._wake.wait(0.005)
-                self._wake.clear()
-
-    def _loop(self):
-        if self.overlap:
-            self._loop_overlapped()
-        else:
-            self._loop_sync()
-
     # -- introspection -----------------------------------------------------
     def stats(self) -> Dict:
-        out = {"mode": self.mode,
-               "overlap": self.overlap,
-               "slots": self.model.num_slots,
+        out = {"slots": self.model.num_slots,
                # tensor-parallel ways the model spans (1 = replicated
                # single-device weights — the pre-mesh layout)
                "tp": getattr(self.model, "tp", 1),

@@ -917,8 +917,8 @@ class PagedDecoderModel:
 
     def decode(self, tokens: np.ndarray, block_tables: np.ndarray,
                positions: np.ndarray, sampling_lanes=None) -> np.ndarray:
-        """Synchronous decode tick (the pre-overlap contract, kept for
-        the request-level baseline and white-box tests): every slot's
+        """One decode tick for a caller outside the engine (the
+        benchmark's tools drive the model alone with it): every slot's
         incoming token comes from the host, the sampled batch is read
         straight back."""
         S = self.num_slots

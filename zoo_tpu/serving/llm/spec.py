@@ -12,9 +12,8 @@ is the llm half of that resolution:
   seamless.
 * ``llama:tiny:seed=3,slots=4,block=8,blocks=64,buckets=16/64`` —
   key=value overrides after the preset (also ``chunk=N`` for chunked
-  prefill, ``overlap=0/1`` for the async tick pipeline, ``spec_k=N`` /
-  ``spec_ngram=N`` for speculative decoding, and ``prefill_impl=`` for
-  the chunk/verify attention kernel).
+  prefill, ``spec_k=N`` / ``spec_ngram=N`` for speculative decoding,
+  and ``prefill_impl=`` for the chunk/verify attention kernel).
 * ``llama:vocab=256,hidden=64,n_block=2,n_head=4,n_kv_head=2,``
   ``intermediate=128`` — explicit architecture, no preset.
 
@@ -34,7 +33,7 @@ overrides it; the env names are documented in docs/llm_serving.md.
 from __future__ import annotations
 
 import os
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 LLM_PREFIX = "llama:"
 GLM_PREFIX = "glm_moe_lite:"
@@ -62,8 +61,7 @@ _ARCH_KEYS = ("vocab", "hidden", "n_block", "n_head", "n_kv_head",
 _ENGINE_KEYS = {"slots": "num_slots", "block": "block_size",
                 "blocks": "num_blocks", "tables": "max_blocks_per_seq",
                 "seed": "seed", "eos": "eos_id", "tp": "tp",
-                "chunk": "prefill_chunk", "overlap": "overlap",
-                "prefix_cache": "prefix_cache",
+                "chunk": "prefill_chunk", "prefix_cache": "prefix_cache",
                 "spec_k": "spec_k", "spec_ngram": "spec_ngram"}
 # string-valued engine/model keys (everything in _ENGINE_KEYS is int)
 _STR_KEYS = {"kv": "kv_dtype", "prefill_impl": "prefill_impl",
@@ -159,8 +157,7 @@ def _env_engine_defaults() -> Dict:  # zoo-lint: config-parse
     return out
 
 
-def build_synthetic_engine(spec: str, mode: Optional[str] = None,
-                           start: bool = True, **overrides):
+def build_synthetic_engine(spec: str, start: bool = True, **overrides):
     """A jax-free :class:`LLMEngine` over a deterministic
     :class:`~zoo_tpu.serving.llm.synthetic.SyntheticLLMModel` from a
     ``synthllm:...`` spec — real allocator, scheduler, deadlines and
@@ -177,22 +174,19 @@ def build_synthetic_engine(spec: str, mode: Optional[str] = None,
     if kvs:
         raise ValueError(f"unknown synthllm spec keys {sorted(kvs)}")
     kwargs.update({k: v for k, v in overrides.items()
-                   if k not in ("mode", "max_waiting")})
+                   if k != "max_waiting"})
     model = SyntheticLLMModel(**kwargs)
-    engine = LLMEngine(model, mode=mode or "continuous",
-                       max_waiting=overrides.get("max_waiting"),
+    engine = LLMEngine(model, max_waiting=overrides.get("max_waiting"),
                        role=role)
     return engine.start() if start else engine
 
 
-def build_llm_engine(spec: str, mode: Optional[str] = None,
-                     start: bool = True, **overrides):
+def build_llm_engine(spec: str, start: bool = True, **overrides):
     """An :class:`LLMEngine` (started unless ``start=False``) from a
     ``llama:...``, ``glm_moe_lite:...`` or ``synthllm:...`` spec. ``overrides`` are
     engine/model kwargs that win over both the spec and the env."""
     if spec.startswith(SYNTH_LLM_PREFIX):
-        return build_synthetic_engine(spec, mode=mode, start=start,
-                                      **overrides)
+        return build_synthetic_engine(spec, start=start, **overrides)
     from zoo_tpu.serving.llm.engine import LLMEngine
     if spec.startswith(GLM_PREFIX):
         from zoo_tpu.models.llm.glm_moe_lite import (
@@ -209,14 +203,10 @@ def build_llm_engine(spec: str, mode: Optional[str] = None,
     merged = dict(_env_engine_defaults())
     merged.update(eng_kwargs)
     merged.update({k: v for k, v in overrides.items()
-                   if k not in ("mode", "max_waiting")})
-    # overlap and prefix_cache are ENGINE knobs (the async tick
-    # pipeline / content-hash block reuse), not model shapes: spec
-    # `overlap=0/1` / `prefix_cache=0/1` < their ZOO_LLM_* env
+                   if k != "max_waiting"})
+    # prefix_cache is an ENGINE knob (content-hash block reuse), not a
+    # model shape: spec `prefix_cache=0/1` < its ZOO_LLM_* env
     # resolution in the engine itself
-    overlap = merged.pop("overlap", None)
-    if overlap is not None:
-        overlap = bool(int(overlap))
     prefix_cache = merged.pop("prefix_cache", None)
     if prefix_cache is not None:
         prefix_cache = bool(int(prefix_cache))
@@ -244,10 +234,7 @@ def build_llm_engine(spec: str, mode: Optional[str] = None,
                 "local device(s) are visible")
         merged["mesh"] = build_mesh(devs[:tp], axis_sizes={"model": tp})
     model = Model(cfg, **merged)
-    from zoo_tpu.common.knobs import value as knob_value
-    mode = mode or knob_value("ZOO_LLM_MODE")
-    engine = LLMEngine(model, mode=mode,
-                       max_waiting=overrides.get("max_waiting"),
-                       overlap=overlap, prefix_cache=prefix_cache,
+    engine = LLMEngine(model, max_waiting=overrides.get("max_waiting"),
+                       prefix_cache=prefix_cache,
                        spec_ngram=spec_ngram, role=role)
     return engine.start() if start else engine

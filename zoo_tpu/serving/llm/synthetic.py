@@ -112,7 +112,7 @@ class SyntheticLLMModel:
              for t, p, tt, s in zip(tokens, positions, temps, seeds)],
             np.int32)
 
-    # the async dispatch surface the overlapped tick pipeline drives;
+    # the dispatch surface the engine's tick pipeline drives;
     # the fake "device" is synchronous so the batch IS the array
     def decode_step(self, prev, host_tokens, use_host, block_tables,
                     positions, sampling):

@@ -480,10 +480,12 @@ def _paged_cases(sz: Sizes, interpret) -> List[KernelCase]:
     T = sz.llm_spec_k + 1
     ctx = W * bs
 
-    def make(n_seq, C, kv, starts, layers=()):
+    def make(n_seq, C, kv, starts, layers=(), heads=(H, n_kv, D)):
         """``layers=(n,)`` stacks n layers' caches (and scale planes)
-        and appends the layer to attend as the last argument."""
+        and appends the layer to attend as the last argument; ``heads``
+        = (query heads, kv heads, head_dim) other than the spec's."""
         def _make():
+            H, n_kv, D = heads
             rs = np.random.RandomState(C)
             q = rs.randn(n_seq, C, H, D).astype(np.float32)
             kc = rs.randn(*layers, nb, n_kv, bs, D).astype(np.float32)
@@ -542,6 +544,13 @@ def _paged_cases(sz: Sizes, interpret) -> List[KernelCase]:
             KernelCase(f"paged_flash_decode[{kv}]",
                        make(S, 1, kv, spread), decode, _dense_paged_ref,
                        tol),
+            # Mistral's heads (32 on 8 of 128): rows and scale rows of
+            # whole 128-lane tiles, so the kernel copies several table
+            # entries a step out of HBM itself (the spec's 64-wide heads
+            # go an entry a step through the BlockSpec pipeline)
+            KernelCase(f"paged_flash_decode[32x8x128,{kv}]",
+                       make(S, 1, kv, spread, heads=(32, 8, 128)), decode,
+                       _dense_paged_ref, tol),
             KernelCase(f"paged_flash_prefill[chunk,{kv}]",
                        make(1, sz.llm_chunk, kv, [ctx // 2]), prefill,
                        _dense_paged_ref, tol),

@@ -52,6 +52,29 @@ def _case(S=3, H=4, n_kv=2, D=16, n_blocks=12, bs=4, W=4, seed=0,
     return q, kc, vc, bt, pos
 
 
+def _as_cache(kc, vc, kv):
+    """float32 blocks (..., nb, n_kv, bs, D) as the cache holds them
+    under ``kv``: (K, V, the kernel's scale arguments, the widened K and
+    V the dense reference reads, tolerance)."""
+    from zoo_tpu.util.quantize import absmax_scale, narrow_int8, \
+        widen_int8
+
+    if kv == "int8":
+        ks, vs = (np.asarray(absmax_scale(c, axis=-1)) for c in (kc, vc))
+        kq, vq = narrow_int8(kc, ks[..., None]), narrow_int8(vc, vs[..., None])
+        rows = ks.shape[:-2] + (1, ks.shape[-2] * ks.shape[-1])
+        kw = dict(k_scale=jnp.asarray(ks.reshape(rows)),
+                  v_scale=jnp.asarray(vs.reshape(rows)))
+        return (jnp.asarray(kq), jnp.asarray(vq), kw,
+                widen_int8(kq, ks[..., None]), widen_int8(vq, vs[..., None]),
+                2e-5)
+    if kv == "bf16":
+        kq, vq = jnp.asarray(kc, jnp.bfloat16), jnp.asarray(vc, jnp.bfloat16)
+        return (kq, vq, {}, np.asarray(kq, np.float32),
+                np.asarray(vq, np.float32), 2e-2)
+    return jnp.asarray(kc), jnp.asarray(vc), {}, kc, vc, 2e-5
+
+
 @pytest.mark.parametrize("splits", [1, 2, 4])
 def test_kernel_matches_dense_reference(splits):
     q, kc, vc, bt, pos = _case()
@@ -129,6 +152,56 @@ def test_kernel_int8_dequant_matches_dense_widen():
                                    err_msg=f"splits={splits}")
 
 
+# the multi-entry step's edges, at N = 4 entries a step (``MAX_ENTRIES``
+# held there so that every cache dtype meets the same steps) of 16 rows:
+# a step is 64 positions. name -> (n_kv, block, D, W, splits, positions)
+_STEP_EDGES = {
+    # tables that are not whole steps: the padding is dead entries
+    "width_5": (8, 16, 128, 5, 1, [0, 63, 64, 79]),
+    "width_7_two_splits": (8, 16, 128, 7, 2, [5, 64, 100, 111]),
+    # a position on a step's first and on its last row
+    "step_first_and_last_row": (8, 16, 128, 12, 1, [64, 63, 128, 127]),
+    # empty slots (position 0, the trash block) beside full ones
+    "zero_beside_full": (8, 16, 128, 8, 2, [0, 127, 0, 127]),
+    # four splits of one step each: the later ones hold no live row
+    "dead_splits": (8, 16, 128, 16, 4, [10, 70, 10, 130]),
+    # n_kv * block != 128: 8 key rows a block (an int8 cache's 8-lane
+    # scale row cannot be sliced out of HBM: an entry a step)
+    "narrow_blocks": (2, 4, 128, 7, 2, [0, 3, 16, 27]),
+    # rows of 16 values: an entry a step for every dtype
+    "narrow_rows": (4, 4, 16, 7, 1, [0, 4, 15, 27]),
+}
+
+
+@pytest.mark.parametrize("kv", ["int8", "bf16", "f32"])
+@pytest.mark.parametrize("edge", list(_STEP_EDGES))
+def test_kernel_step_edges(monkeypatch, edge, kv):
+    from zoo_tpu.ops.pallas import paged_decode as pd
+
+    monkeypatch.setattr(pd, "MAX_ENTRIES", 4)
+    n_kv, bs, D, W, splits, positions = _STEP_EDGES[edge]
+    q, kc, vc, bt, pos = _case(S=4, H=2 * n_kv, n_kv=n_kv, D=D,
+                               n_blocks=24, bs=bs, W=W, seed=len(edge),
+                               positions=positions)
+    kq, vq, kw, kd, vd, tol = _as_cache(np.asarray(kc), np.asarray(vc), kv)
+    ref = _dense_ref(q, jnp.asarray(kd), jnp.asarray(vd), bt, pos)
+    out = paged_flash_decode(q, kq, vq, bt, pos, num_splits=splits,
+                             interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=tol, rtol=tol)
+
+
+def test_entries_per_step_follows_the_block_bytes():
+    """N from the shapes: 16 int8 blocks of the cells' 8 x 16 x 128,
+    8 in bf16, 4 in f32, never more than the table holds."""
+    from zoo_tpu.ops.pallas.paged_decode import entries_per_step
+    assert entries_per_step(160, 8, 16, 128, 1) == 16
+    assert entries_per_step(160, 8, 16, 128, 2) == 8
+    assert entries_per_step(160, 8, 16, 128, 4) == 4
+    assert entries_per_step(5, 8, 16, 128, 1) == 5
+    assert entries_per_step(160, 8, 32, 256, 4) == 1
+
+
 def test_kernel_scales_must_travel_together():
     q, kc, vc, bt, pos = _case()
     with pytest.raises(ValueError):
@@ -143,9 +216,6 @@ def test_kernel_reads_a_stacked_cache_at_a_traced_layer(kv):
     in and the layer is a traced scalar inside ``lax.scan``; each
     layer's output is the dense reference of ``cache[layer]``. A layer
     index without a stacked cache, and the reverse, are refused."""
-    from zoo_tpu.util.quantize import absmax_scale, narrow_int8, \
-        widen_int8
-
     rs = np.random.RandomState(5)
     L, S, H, n_kv, D, nb, bs, W = 3, 3, 4, 2, 16, 12, 4, 4
     q = jnp.asarray(rs.randn(S, H, D).astype(np.float32))
@@ -153,18 +223,7 @@ def test_kernel_reads_a_stacked_cache_at_a_traced_layer(kv):
     vc = rs.randn(L, nb, n_kv, bs, D).astype(np.float32)
     bt = jnp.asarray(rs.randint(1, nb, (S, W)).astype(np.int32))
     pos = jnp.asarray([0, 7, 15], jnp.int32)
-    if kv == "int8":
-        ks, vs = (np.asarray(absmax_scale(c, axis=-1)) for c in (kc, vc))
-        kq, vq = narrow_int8(kc, ks[..., None]), narrow_int8(vc, vs[..., None])
-        kw = dict(k_scale=jnp.asarray(ks.reshape(L, nb, 1, -1)),
-                  v_scale=jnp.asarray(vs.reshape(L, nb, 1, -1)))
-        kd, vd = widen_int8(kq, ks[..., None]), widen_int8(vq, vs[..., None])
-        tol = 2e-5
-    else:
-        kq, vq = jnp.asarray(kc, jnp.bfloat16), jnp.asarray(vc, jnp.bfloat16)
-        kw, tol = {}, 2e-2
-        kd, vd = np.asarray(kq, np.float32), np.asarray(vq, np.float32)
-    kq, vq = jnp.asarray(kq), jnp.asarray(vq)
+    kq, vq, kw, kd, vd, tol = _as_cache(kc, vc, kv)
 
     def layer(_, i):
         return None, paged_flash_decode(q, kq, vq, bt, pos, layer=i,

@@ -117,6 +117,41 @@ def test_mla_decode_compiles_at_the_cell_widths(v5e):
     assert not whole, whole[:2]
 
 
+@pytest.mark.parametrize("entries", [160, 640])
+def test_paged_decode_compiles_at_the_cell_widths(v5e, entries):
+    """``zoo_paged_decode`` at the sizes of the Mistral cells (32 slots,
+    32 query heads on 8 kv heads of 128, an int8 cache of 8 layers x
+    5,120 blocks of 16 rows with its head-major scale planes, 160 table
+    entries) and at the long table of 640: Mosaic takes the multi-entry
+    step (the slabs and their DMAs out of HBM), and the cache and the
+    scale planes go in as they lie (no operation but a parameter yields
+    a leaf's whole shape)."""
+    from zoo_tpu.ops.pallas.paged_decode import paged_flash_decode
+    one = SingleDeviceSharding(v5e[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def call(q, kc, vc, ks, vs, layer, bt, pos):
+        return paged_flash_decode(q, kc, vc, bt, pos, layer=layer,
+                                  k_scale=ks, v_scale=vs, interpret=False)
+
+    hlo = jax.jit(call).lower(
+        sds((32, 32, 128), jnp.float32),
+        sds((8, 5120, 8, 16, 128), jnp.int8),
+        sds((8, 5120, 8, 16, 128), jnp.int8),
+        sds((8, 5120, 1, 128), jnp.float32),
+        sds((8, 5120, 1, 128), jnp.float32), sds((), jnp.int32),
+        sds((32, entries), jnp.int32), sds((32,), jnp.int32)
+    ).compile().as_text()
+    assert MOSAIC_CALL in hlo and "zoo_paged_decode" in hlo
+    whole = [ln for ln in hlo.splitlines()
+             if ("= s8[8,5120,8,16,128]" in ln
+                 or "= f32[8,5120,1,128]" in ln)
+             and " parameter(" not in ln]
+    assert not whole, whole[:2]
+
+
 @pytest.mark.parametrize("rows,k,n", [(128, 2048, 1536),
                                       (2048, 1536, 2048)])
 def test_moe_gmm_compiles_at_the_cell_widths(v5e, rows, k, n):

@@ -10,31 +10,57 @@ PagedAttention/flash-decoding rebuild (Kwon et al., SOSP '23; Dao et
 al., 2023):
 
 * **paged** — K/V blocks are read directly where they live, routed by a
-  scalar-prefetched block table in the ``BlockSpec`` index maps, so the
-  per-sequence gather copy never exists;
+  scalar-prefetched block table, so the per-sequence gather copy never
+  exists;
 * **stacked** — the cache operand is the WHOLE ``(n_layer, num_blocks,
-  H_kv, block, D)`` array and the layer is a third prefetched scalar,
-  the leading block index of the K/V and scale maps, so a layer loop
-  carries the cache and never slices a layer's slab out of it (a 4-D
-  cache is the same path at one layer);
-* **flash** — online-softmax accumulation in VMEM scratch, never a
-  ``(ctx,)`` score row in HBM;
-* **split-KV** — the sequence axis is cut into ``num_splits`` grid
+  H_kv, block, D)`` array, left in HBM (``memory_space=pltpu.HBM``), and
+  the layer is a third prefetched scalar, the leading index of every
+  DMA's source, so a layer loop carries the cache and never slices a
+  layer's slab out of it (a 4-D cache is the same path at one layer);
+* **several entries a step** — a block of 16 int8 rows of 8 kv heads is
+  16 KB, too small a DMA (and too little work) to amortise a step, so a
+  step fetches :func:`entries_per_step` consecutive table entries of
+  its slot — K, V and under int8 their two scale rows, one DMA each
+  into a double-buffered VMEM slab ``(2, N, H_kv, block, D)``, the next
+  step's in flight while this one is attended;
+* **all kv heads at once** — an entry's block arrives with ALL its kv
+  heads, ``H_kv * block`` key rows, and the slot's H query rows meet
+  them in ONE ``(H, D) @ (D, H_kv * block)`` product: block-diagonal,
+  a mask keeps row ``r`` to the columns of its own kv head
+  (``r // group == col // block``) at positions ``<= pos``. The MXU
+  does ``H_kv`` times the useful products and is idle anyway; the
+  score tile is full vregs, there is no loop over heads, and the
+  head-major scale row of an int8 block multiplies the tile as it
+  lies (K's on the scores, V's on the probabilities);
+* **flash** — one online-softmax update a step for every head, carried
+  in VMEM scratch, never a ``(ctx,)`` score row in HBM;
+* **time follows the live rows** — a (slot, split) program loops over
+  the steps that hold a live position and no further
+  (``fori_loop`` to ``ceil((pos + 1) / (N * block))``): a dead step
+  costs nothing, a dead split writes ``m=-inf, l=0`` and moves no
+  byte. Inside the last live step, entries past the position fetch
+  the trash block 0 and the mask hides them;
+* **split-KV** — the table's steps are cut into ``num_splits`` grid
   programs that each produce a partial ``(acc, m, l)``; a tiny jnp
-  epilogue merges them with the standard log-sum-exp correction. At
-  decode there is ONE query per sequence, so without the split the
-  kernel exposes only ``slots x kv_heads`` programs of parallelism —
-  splitting the KV length is what keeps the cores busy at low
-  occupancy (the flash-decoding observation);
-* **GQA-aware** — the ``group = n_head / n_kv_head`` query heads that
-  share a KV head are batched into one ``(group, d) @ (d, block)``
-  matmul, so each K/V block is streamed once per KV head, not once per
-  query head.
+  epilogue merges them with the standard log-sum-exp correction.
 
-Blocks past a sequence's live length are skipped via ``pl.when`` (no
-MXU work, no DMA consumed), and a fully-dead split contributes
-``m=-inf, l=0`` which the epilogue drops — inactive slots (position 0,
-table full of trash-block zeros) produce garbage that the engine never
+Mosaic slices an HBM ref in whole 128-lane tiles, so a cache whose rows
+are narrower (``head_dim`` 64) or, under int8, whose head-major scale
+row is (a tp = 2 shard's 64 lanes, 8 heads of 8 rows) cannot have its
+blocks copied out by the kernel: there the SAME step body runs one
+entry a grid step, the block brought by the BlockSpec pipeline and
+clamped by the index maps (a dead step is a grid step with no DMA and
+no product, as before PR 31).
+
+What the interpreter cannot show: it completes a DMA where it starts,
+so a read of a slab ahead of its wait passes every CPU test and races
+on the chip (V's scale rows did, until they were read behind V's
+wait). ``scripts/bench_paged_decode.py`` holds every form's result
+against the parent's on the chip; ``chip_smoke.py`` against the dense
+reference.
+
+Inactive slots (position 0, table full of trash-block zeros) attend one
+step of the trash block and produce garbage that the engine never
 reads, exactly like the dense path.
 
 Off-TPU the kernel runs under the Pallas interpreter (exact, slow), so
@@ -56,45 +82,12 @@ from jax.experimental.pallas import tpu as pltpu
 from zoo_tpu.ops.pallas import LANES as _LANES
 from zoo_tpu.ops.pallas import resolve_interpret as _resolve_interpret
 
-
-def attend_block(h, q, k, v, k_scale, v_scale, start, limit, scale,
-                 m_scr, l_scr, a_scr):
-    """One kv head's share of one cache block, folded into the online
-    softmax carried in VMEM scratch (shared by the paged decode and
-    prefill kernels). ``q`` (rows, D) against ``k``/``v`` (block, D);
-    column ``c`` of the block is cache index ``start + c`` and a row
-    attends it iff that is ``<= limit`` (a scalar position, or a
-    (rows, 1) column of per-row positions). An int8 block comes with
-    its (1, block) scale rows (the head's stretch of the block's
-    head-major scale row): it is widened in register and the scales
-    land on the (rows, block) score tile — K's on the scores, V's on
-    the probabilities, both row broadcasts with no relayout — so HBM
-    moves the int8 bytes and the math stays f32."""
-    if k_scale is not None:
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-    s_ = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale       # (rows, block)
-    if k_scale is not None:
-        s_ = s_ * k_scale
-    col = start + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
-    mask = col <= limit
-    s_ = jnp.where(mask, s_, -jnp.inf)
-    m_prev = m_scr[h][:, :1]                              # (rows, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-    safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(jnp.where(mask, s_ - safe, -jnp.inf))
-    corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe), 0.0)
-    l_new = corr * l_scr[h][:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-    if v_scale is not None:
-        p = p * v_scale
-    a_scr[h] = a_scr[h] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    # full-lane stores: every lane of a row carries the value
-    m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-    l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+# the K + V bytes one grid step fetches, at most: 16 int8 entries of
+# the serving cell (8 kv heads x 16 rows x 128), 4 of an f32 cache
+STEP_BYTES = 512 * 1024
+# the most table entries a step attends (each is four DMAs and two
+# products in the unrolled body)
+MAX_ENTRIES = 16
 
 
 def stacked_cache(k_cache, v_cache, k_scale, v_scale, layer):
@@ -128,58 +121,207 @@ def stacked_cache(k_cache, v_cache, k_scale, v_scale, layer):
             jnp.asarray(layer, jnp.int32).reshape(1))
 
 
-def scale_row(ref, h, block_size):
-    """Head ``h``'s (1, block) stretch of a block's head-major scale
-    row ``(1, 1, H_kv * block)``; None for an unquantized cache."""
-    if ref is None:
-        return None
-    return ref[0][:, h * block_size:(h + 1) * block_size]
+def entries_per_step(table_width: int, n_kv: int, block_size: int,
+                     head_dim: int, itemsize: int) -> int:
+    """Table entries one grid step fetches and attends: as many as
+    ``STEP_BYTES`` of K + V hold, between 1 and ``MAX_ENTRIES``, and no
+    more than the table has."""
+    entry = 2 * n_kv * block_size * head_dim * itemsize
+    return max(1, min(STEP_BYTES // entry, MAX_ENTRIES, table_width))
 
 
-def _kernel(bt_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref, *rest,
-            n_kv, block_size, bps, scale, quantized):
-    """One (slot, split) program; the innermost grid axis walks the
-    split's ``bps`` table entries with the online-softmax carry in VMEM
-    scratch. Each entry's block arrives with ALL its kv heads —
-    ``(n_kv, block_size, D)``, the cache's own minor dims, which is
-    what the TPU lowering can slice — and the heads are walked by a
-    static loop (the layer axis is squeezed out by the BlockSpec;
-    ``lay_ref`` is for the index maps alone). ``quantized`` adds two
-    per-(block, kv-head, row) scale refs after ``v_ref`` (see
-    :func:`attend_block`)."""
+def _attend(q, pos, firsts, keys, k_scales, values, *, scale, group,
+            m_scr, l_scr, a_scr):
+    """One online-softmax update of every head for a step's table
+    entries. ``q`` (H, D); entry ``e`` starts at cache index
+    ``firsts[e]`` and brings ``keys[e]`` (n_kv, block, D) and, for an
+    int8 cache, its (1, n_kv * block) head-major scale row
+    ``k_scales[e]`` (else the list is None); ``values()`` returns the
+    V blocks and their scale rows (or None) and is called after the
+    scores: they may arrive behind K and are not to be read before
+    their wait. Each block meets all H query rows in
+    ONE product against its ``n_kv * block`` rows; the mask keeps a row
+    to the columns of its own kv head at positions ``<= pos``. At
+    least one entry holds a position ``<= pos``."""
+    H = q.shape[0]
+    n_kv, block_size, _ = keys[0].shape
+    C = n_kv * block_size
+    # ``t``: a column's row inside its block, or past every position
+    # where the column is another kv head's (the block-diagonal)
+    r_head = jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (H, C), 0), group)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, C), 1)
+    t = jnp.where(r_head == jax.lax.div(col, block_size),
+                  jax.lax.rem(col, block_size), jnp.iinfo(jnp.int32).max)
+
+    def rows_of(x):
+        # (n_kv, block, D) -> (n_kv * block, D), widened first where the
+        # merge would split a packed tile (int8 always: it is dequantized)
+        if k_scales is not None or block_size % (32 // x.dtype.itemsize):
+            x = x.astype(jnp.float32)
+        return x.reshape(C, x.shape[-1])
+
+    tiles = []
+    for e, k in enumerate(keys):
+        s_ = jax.lax.dot_general(
+            q, rows_of(k), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale           # (H, C)
+        if k_scales is not None:
+            s_ = s_ * k_scales[e]
+        tiles.append(jnp.where(t <= pos - firsts[e], s_, -jnp.inf))
+    # a live entry gives every row a live column: m_new is finite
+    m_prev = m_scr[...][:, :1]                                    # (H, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(
+        functools.reduce(jnp.maximum, tiles), axis=-1, keepdims=True))
+    probs = [jnp.exp(s_ - m_new) for s_ in tiles]
+    corr = jnp.exp(m_prev - m_new)
+    l_new = corr * l_scr[...][:, :1] + jnp.sum(
+        functools.reduce(jnp.add, probs), axis=-1, keepdims=True)
+    acc = a_scr[...] * corr
+    vals, v_scales = values()
+    for e, v in enumerate(vals):
+        p, v = probs[e], rows_of(v)
+        if v_scales is not None:
+            p = p * v_scales[e]
+        acc = acc + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    a_scr[...] = acc
+    # full-lane stores: every lane of a row carries the value
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _init(m_scr, l_scr, a_scr):
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    a_scr[...] = jnp.zeros_like(a_scr)
+
+
+def _finish(acc_ref, m_ref, l_ref, m_scr, l_scr, a_scr):
+    acc_ref[0, 0] = a_scr[...]
+    m_ref[0, 0] = m_scr[...]
+    l_ref[0, 0] = l_scr[...]
+
+
+def _slab_kernel(bt_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, *rest,
+                 n_fetch, steps, quantized, **attend):
+    """One (slot, split) program: a loop over the split's LIVE steps of
+    ``n_fetch`` table entries, the next step's blocks in flight while
+    this one's are attended. ``k_hbm`` / ``v_hbm`` (and under
+    ``quantized`` the two scale planes after them) are the whole arrays
+    in HBM; the double-buffered slabs they are copied into are scratch
+    (``k_buf`` / ``v_buf`` (2, n_fetch, n_kv, block, D), the scale rows
+    (2, n_fetch, 1, n_kv * block))."""
+    ks_hbm = vs_hbm = ks_buf = vs_buf = None
+    if quantized:
+        ks_hbm, vs_hbm, *rest = rest
+    *outs, m_scr, l_scr, a_scr, k_buf, v_buf = rest[:8]
+    if quantized:
+        ks_buf, vs_buf = rest[8:10]
+    sem = rest[-1]
+    scr = (m_scr, l_scr, a_scr)
+    s = pl.program_id(0)
+    pos = pos_ref[s]
+    lay = lay_ref[0]
+    block_size = k_buf.shape[3]
+    first = pl.program_id(1) * steps
+    # the split's steps that hold a position <= pos
+    live = jnp.clip(pos // (n_fetch * block_size) + 1 - first, 0, steps)
+
+    def copies(step, buf, routed):
+        """The DMAs of one step into slab ``buf``: K's (with their
+        scale rows) on one semaphore, V's on the other. ``routed``
+        reads the table; a wait only needs the shapes."""
+        k_copies, v_copies = [], []
+        for e in range(n_fetch):
+            blk = 0
+            if routed:
+                idx = step * n_fetch + e
+                # entries past the position: the resident trash block
+                blk = jnp.where(idx * block_size <= pos, bt_ref[s, idx], 0)
+            for hbm, slab, out, kind in (
+                    (k_hbm, k_buf, k_copies, 0), (ks_hbm, ks_buf, k_copies, 0),
+                    (v_hbm, v_buf, v_copies, 1), (vs_hbm, vs_buf, v_copies, 1)):
+                if hbm is not None:
+                    out.append(pltpu.make_async_copy(
+                        hbm.at[lay, blk], slab.at[buf, e], sem.at[kind, buf]))
+        return k_copies, v_copies
+
+    def start(step, buf):
+        k_copies, v_copies = copies(step, buf, True)
+        for c in k_copies + v_copies:
+            c.start()
+
+    _init(*scr)
+
+    @pl.when(live > 0)
+    def _first():
+        start(first, 0)
+
+    def step(i, carry):
+        buf = jax.lax.rem(i, 2)
+        at = first + i
+
+        @pl.when(i + 1 < live)
+        def _next():
+            start(at + 1, 1 - buf)
+
+        k_copies, v_copies = copies(at, buf, False)
+        for c in k_copies:
+            c.wait()
+
+        def entries(slab):
+            if slab is not None:
+                return [slab[buf, e] for e in range(n_fetch)]
+
+        def values():
+            for c in v_copies:
+                c.wait()
+            return entries(v_buf), entries(vs_buf)
+
+        _attend(
+            q_ref[0], pos,
+            [(at * n_fetch + e) * block_size for e in range(n_fetch)],
+            entries(k_buf), entries(ks_buf), values,
+            m_scr=m_scr, l_scr=l_scr, a_scr=a_scr, **attend)
+        return carry
+
+    jax.lax.fori_loop(0, live, step, 0)
+    _finish(*outs, *scr)
+
+
+def _block_kernel(bt_ref, pos_ref, lay_ref, q_ref, k_ref, v_ref, *rest,
+                  steps, quantized, **attend):
+    """One (slot, split) program an entry a grid step: the innermost
+    grid axis walks the split's ``steps`` table entries, each block
+    ``(n_kv, block, D)`` (and its scale rows) brought by the BlockSpec
+    pipeline, routed and clamped by the index maps."""
     ks_ref = vs_ref = None
     if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
-    acc_ref, m_ref, l_ref, m_scr, l_scr, a_scr = rest
-    s = pl.program_id(0)
-    split = pl.program_id(1)
+        ks_ref, vs_ref, *rest = rest
+    *outs, m_scr, l_scr, a_scr = rest
+    scr = (m_scr, l_scr, a_scr)
     j = pl.program_id(2)
-    pos = pos_ref[s]
-    start = (split * bps + j) * block_size
+    pos = pos_ref[pl.program_id(0)]
+    first = (pl.program_id(1) * steps + j) * k_ref.shape[2]
 
     @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        a_scr[...] = jnp.zeros_like(a_scr)
+    def _first():
+        _init(*scr)
 
-    # whole block past the live length: skip — no matmul, and (because
-    # the index map clamps dead entries to block 0) no fresh DMA either
-    @pl.when(start <= pos)
+    # a block past the live length: no product, and (the index map
+    # clamped it to the resident trash block) no fresh DMA either
+    @pl.when(first <= pos)
     def _step():
-        for h in range(n_kv):
-            attend_block(
-                h, q_ref[0, h], k_ref[0, h], v_ref[0, h],
-                scale_row(ks_ref, h, block_size),
-                scale_row(vs_ref, h, block_size),
-                start, pos, scale, m_scr, l_scr, a_scr)
+        _attend(q_ref[0], pos, [first], [k_ref[0]],
+                [ks_ref[0]] if quantized else None,
+                lambda: ([v_ref[0]], [vs_ref[0]] if quantized else None),
+                m_scr=m_scr, l_scr=l_scr, a_scr=a_scr, **attend)
 
-    @pl.when(j == bps - 1)
-    def _finish():
-        acc_ref[0, 0] = a_scr[...].astype(acc_ref.dtype)
-        m_ref[0, 0] = m_scr[...]
-        l_ref[0, 0] = l_scr[...]
+    @pl.when(j == steps - 1)
+    def _last():
+        _finish(*outs, *scr)
 
 
 def resolve_num_splits(table_width: int,  # zoo-lint: config-parse
@@ -211,7 +353,7 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     (n_layer, num_blocks, H_kv, block_size, D), every layer's blocks,
     with ``layer`` the (traced) index of the layer attended —
     ``(block_size, D)`` are the minor dims so one block of every kv
-    head is a slab the TPU can DMA and index by head; a 4-D
+    head is one contiguous slab to DMA; a 4-D
     (num_blocks, H_kv, block_size, D) cache with no ``layer`` is one
     layer's; ``block_tables``: (S, W) int32; ``positions``: (S,) int32
     — the cache index the slot's incoming token was written at (tokens
@@ -232,97 +374,95 @@ def paged_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     if H % n_kv:
         raise ValueError(f"q heads ({H}) must be a multiple of kv heads "
                          f"({n_kv})")
-    group = H // n_kv
     W = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / float(D) ** 0.5
     interpret = _resolve_interpret(interpret)
-    splits = resolve_num_splits(W, num_splits)
-    bps = W // splits
-
-    q4 = q.reshape(S, n_kv, group, D)
-    bt = block_tables.astype(jnp.int32)
+    C = n_kv * block_size
+    # Mosaic slices an HBM ref in whole 128-lane tiles: a cache whose
+    # rows (or whose head-major scale row: a tp shard's, or 8 heads of
+    # 8 rows) are narrower goes an entry a step through the BlockSpec
+    # pipeline instead
+    sliceable = D % _LANES == 0 and not (quantized and C % _LANES)
+    n_fetch = entries_per_step(W, n_kv, block_size, D,
+                               k_cache.dtype.itemsize) if sliceable else 1
+    groups = -(-W // n_fetch)
+    splits = resolve_num_splits(groups, num_splits)
+    steps = groups // splits
+    # the table padded to whole steps: the padding is dead entries
+    bt = jnp.pad(block_tables.astype(jnp.int32),
+                 ((0, 0), (0, groups * n_fetch - W)))
     pos = positions.astype(jnp.int32)
+    scales = [k_scale, v_scale] if quantized else []
 
-    def _entry(s, sp, j, bt_ref, pos_ref):
-        # dead entries (whole block past the live length) are clamped to
-        # block 0 so the pipeline re-fetches the already-resident trash
-        # block instead of streaming a block the kernel will skip
-        idx = sp * bps + j
-        live = idx * block_size <= pos_ref[s]
-        return jnp.where(live, bt_ref[s, idx], 0)
+    def q_map(s, sp, *_):
+        return s, 0, 0
 
-    def _kv_map(s, sp, j, bt_ref, pos_ref, lay_ref):
-        return lay_ref[0], _entry(s, sp, j, bt_ref, pos_ref), 0, 0, 0
+    def out_map(s, sp, *_):
+        return s, sp, 0, 0
 
-    def _out_map(s, sp, j, bt_ref, pos_ref, lay_ref):
-        return s, sp, 0, 0, 0
+    scratch = [pltpu.VMEM((H, _LANES), jnp.float32),
+               pltpu.VMEM((H, _LANES), jnp.float32),
+               pltpu.VMEM((H, D), jnp.float32)]
+    static = dict(steps=steps, quantized=quantized, scale=scale,
+                  group=H // n_kv)
+    if sliceable:
+        # the cache stays in HBM and the kernel copies a step's blocks
+        # into its slabs itself; (slot, split) programs share nothing
+        kernel = functools.partial(_slab_kernel, n_fetch=n_fetch, **static)
+        grid, semantics = (S, splits), ("parallel", "parallel")
+        kv_specs = [pl.BlockSpec(memory_space=pltpu.HBM)] * (2 + len(scales))
+        slab = (2, n_fetch, n_kv, block_size, D)
+        scratch += [pltpu.VMEM(slab, k_cache.dtype),
+                    pltpu.VMEM(slab, v_cache.dtype)]
+        scratch += [pltpu.VMEM((2, n_fetch, 1, C), jnp.float32)] * len(scales)
+        scratch += [pltpu.SemaphoreType.DMA((2, 2))]
+    else:
+        kernel = functools.partial(_block_kernel, **static)
+        # only the innermost walk carries the softmax state
+        grid = (S, splits, steps)
+        semantics = ("parallel", "parallel", "arbitrary")
 
-    kernel = functools.partial(
-        _kernel, n_kv=n_kv, block_size=block_size, bps=bps, scale=scale,
-        quantized=quantized)
-    kv_spec = pl.BlockSpec((None, 1, n_kv, block_size, D), _kv_map)
-    in_specs = [
-        pl.BlockSpec((1, n_kv, group, D),
-                     lambda s, sp, j, bt_ref, pos_ref, lay_ref:
-                     (s, 0, 0, 0)),
-        kv_spec, kv_spec,
-    ]
-    operands = [q4, k_cache, v_cache]
-    if quantized:
-        # the scale rows ride the exact same layer and block-table
-        # routing as their K/V block (dead entries clamp to the trash
-        # block too)
-        in_specs += [pl.BlockSpec(
-            (None, 1, 1, n_kv * block_size),
-            lambda s, sp, j, bt_ref, pos_ref, lay_ref:
-            (lay_ref[0], _entry(s, sp, j, bt_ref, pos_ref), 0, 0))] * 2
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, splits, bps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, n_kv, group, D), _out_map),
-            pl.BlockSpec((1, 1, n_kv, group, _LANES), _out_map),
-            pl.BlockSpec((1, 1, n_kv, group, _LANES), _out_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, group, _LANES), jnp.float32),
-            pltpu.VMEM((n_kv, group, _LANES), jnp.float32),
-            pltpu.VMEM((n_kv, group, D), jnp.float32),
-        ],
-    )
-    # (slot, split) programs are independent — mark them parallel so
-    # Mosaic can spread them over cores (megacore); only the innermost
-    # block walk carries the VMEM softmax state and must stay
-    # sequential. Without this the whole grid serializes and the
-    # split-KV axis adds epilogue cost without its parallelism.
+        def entry(s, sp, j, bt_ref, pos_ref, lay_ref):
+            # dead entries clamp to block 0, so the pipeline re-fetches
+            # the resident trash block instead of streaming a block the
+            # kernel will skip
+            idx = sp * steps + j
+            live = idx * block_size <= pos_ref[s]
+            return lay_ref[0], jnp.where(live, bt_ref[s, idx], 0)
+
+        kv_specs = [pl.BlockSpec((None, 1, n_kv, block_size, D),
+                                 lambda *a: (*entry(*a), 0, 0, 0))] * 2
+        kv_specs += [pl.BlockSpec((None, 1, 1, C),
+                                  lambda *a: (*entry(*a), 0, 0))] * len(scales)
     acc, m, l = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[pl.BlockSpec((1, H, D), q_map)] + kv_specs,
+            out_specs=[pl.BlockSpec((1, 1, H, D), out_map),
+                       pl.BlockSpec((1, 1, H, _LANES), out_map),
+                       pl.BlockSpec((1, 1, H, _LANES), out_map)],
+            scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         out_shape=[
-            jax.ShapeDtypeStruct((S, splits, n_kv, group, D),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((S, splits, n_kv, group, _LANES),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((S, splits, n_kv, group, _LANES),
-                                 jnp.float32),
+            jax.ShapeDtypeStruct((S, splits, H, D), jnp.float32),
+            jax.ShapeDtypeStruct((S, splits, H, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((S, splits, H, _LANES), jnp.float32),
         ],
         interpret=interpret,
         name="zoo_paged_decode",
-    )(bt, pos, lay, *operands)
+    )(bt, pos, lay, q, k_cache, v_cache, *scales)
 
     # split-KV epilogue: merge the per-split partial softmaxes with the
     # log-sum-exp correction (dead splits carry m=-inf/l=0 and drop out)
-    m0 = m[..., 0]                                  # (S, splits, n_kv, G)
+    m0 = m[..., 0]                                  # (S, splits, H)
     l0 = l[..., 0]
     m_max = jnp.max(m0, axis=1, keepdims=True)
     m_safe = jnp.where(jnp.isfinite(m_max), m_max, 0.0)
     alpha = jnp.where(jnp.isfinite(m0), jnp.exp(m0 - m_safe), 0.0)
-    l_tot = jnp.sum(alpha * l0, axis=1)             # (S, n_kv, G)
+    l_tot = jnp.sum(alpha * l0, axis=1)             # (S, H)
     o = jnp.sum(alpha[..., None] * acc, axis=1) / \
         jnp.where(l_tot == 0.0, 1.0, l_tot)[..., None]
-    return o.astype(q.dtype).reshape(S, H, D)
+    return o.astype(q.dtype)

@@ -54,11 +54,55 @@ from zoo_tpu.ops.pallas import LANES as _LANES
 from zoo_tpu.ops.pallas import SUBLANES as _SUBLANES
 from zoo_tpu.ops.pallas import pad_dim as _pad_dim
 from zoo_tpu.ops.pallas import resolve_interpret as _resolve_interpret
-from zoo_tpu.ops.pallas.paged_decode import (
-    attend_block,
-    scale_row,
-    stacked_cache,
-)
+from zoo_tpu.ops.pallas.paged_decode import stacked_cache
+
+
+def attend_block(h, q, k, v, k_scale, v_scale, start, limit, scale,
+                 m_scr, l_scr, a_scr):
+    """One kv head's share of one cache block, folded into the online
+    softmax carried in VMEM scratch. ``q`` (rows, D) against ``k``/``v``
+    (block, D); column ``c`` of the block is cache index ``start + c``
+    and a row
+    attends it iff that is ``<= limit`` (a scalar position, or a
+    (rows, 1) column of per-row positions). An int8 block comes with
+    its (1, block) scale rows (the head's stretch of the block's
+    head-major scale row): it is widened in register and the scales
+    land on the (rows, block) score tile — K's on the scores, V's on
+    the probabilities, both row broadcasts with no relayout — so HBM
+    moves the int8 bytes and the math stays f32."""
+    if k_scale is not None:
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+    s_ = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # (rows, block)
+    if k_scale is not None:
+        s_ = s_ * k_scale
+    col = start + jax.lax.broadcasted_iota(jnp.int32, s_.shape, 1)
+    mask = col <= limit
+    s_ = jnp.where(mask, s_, -jnp.inf)
+    m_prev = m_scr[h][:, :1]                              # (rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
+    safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    p = jnp.exp(jnp.where(mask, s_ - safe, -jnp.inf))
+    corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe), 0.0)
+    l_new = corr * l_scr[h][:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
+    a_scr[h] = a_scr[h] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    # full-lane stores: every lane of a row carries the value
+    m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+    l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+
+def scale_row(ref, h, block_size):
+    """Head ``h``'s (1, block) stretch of a block's head-major scale
+    row ``(1, 1, H_kv * block)``; None for an unquantized cache."""
+    if ref is None:
+        return None
+    return ref[0][:, h * block_size:(h + 1) * block_size]
 
 
 def _kernel(bt_ref, last_ref, lay_ref, q_ref, pos_ref, k_ref, v_ref,

@@ -772,11 +772,93 @@ def _optim_cases(sz: Sizes, interpret) -> List[KernelCase]:
     ]
 
 
+def _sparse_state_cases(sz: Sizes, interpret) -> List[KernelCase]:
+    """The block-sparse decode kernel and the Lightning state's step at
+    the widths of ``minicpm-sala-d12`` (16 query heads a K/V head of
+    128, pages of 64; 32 heads of 128 x 128 float32 state), every slot
+    another number of live entries, one none."""
+    import numpy as np
+
+    import jax.numpy as jnp
+
+    from zoo_tpu.ops.pallas import lightning_decode, sparse_paged_decode
+    from zoo_tpu.ops.pallas.lightning import (
+        lightning_decode_reference,
+        write_state,
+    )
+    from zoo_tpu.ops.pallas.sparse_decode import sparse_decode_reference
+
+    S, G, Hg, D, bs, nb, E, L = 8, 2, 16, 128, 64, 48, 20, 2
+
+    def sparse(kv):
+        def _make():
+            rs = np.random.RandomState(E)
+            q = rs.randn(S, G, Hg, D).astype(np.float32)
+            kc = rs.randn(L, nb, G, bs, D).astype(np.float32)
+            vc = rs.randn(L, nb, G, bs, D).astype(np.float32)
+            n_live = rs.randint(0, E + 1, (S, G)).astype(np.int32)
+            n_live[0], n_live[1, 0] = E, 0
+            lens = np.where(np.arange(E) < n_live[..., None], bs, 0)
+            own = rs.randint(0, np.maximum(n_live, 1))
+            np.put_along_axis(
+                lens, own[..., None],
+                np.where(n_live > 0, rs.randint(1, bs + 1, (S, G)),
+                         0)[..., None], axis=-1)
+            tables = np.where(lens > 0, rs.randint(1, nb, (S, G, E)), 0)
+            if kv == "bf16":
+                kc, vc = (jnp.asarray(x, jnp.bfloat16) for x in (kc, vc))
+            return [q, kc, vc, tables.astype(np.int32),
+                    lens.astype(np.int32), n_live, np.int32(1)]
+        return _make
+
+    def sparse_fn(q, kc, vc, tables, lens, n_live, layer):
+        return sparse_paged_decode(q, kc, vc, tables, lens, n_live,
+                                   layer=layer, interpret=interpret)
+
+    def sparse_ref(q, kc, vc, tables, lens, n_live, layer):
+        return sparse_decode_reference(
+            q, kc.astype(jnp.float32), vc.astype(jnp.float32), tables,
+            lens, layer=layer)
+
+    H = 32
+
+    def state():
+        rs = np.random.RandomState(H)
+        q, k, v = (rs.randn(S, H, D).astype(np.float32) for _ in range(3))
+        live = np.arange(S) % 3 != 1
+        return [rs.randn(L, S, H, D, D).astype(np.float32), np.int32(1),
+                q, k, v, np.exp(-np.linspace(0.004, 0.9, H)).astype(
+                    np.float32), live]
+
+    def step_fn(leaf, layer, q, k, v, decay, live):
+        return lightning_decode(leaf, layer, q, k, v, decay, live,
+                                interpret=interpret)
+
+    def write_fn(leaf, layer, q, k, v, decay, live):
+        return write_state(leaf, layer, 5, leaf[0, 2] * 2.0,
+                           interpret=interpret)
+
+    return [
+        KernelCase("sparse_paged_decode[f32]", sparse("f32"), sparse_fn,
+                   sparse_ref, TOL_F32),
+        KernelCase("sparse_paged_decode[bf16]", sparse("bf16"), sparse_fn,
+                   sparse_ref, TOL_BF16),
+        # elementwise float32: the state is exact, the output row is a
+        # sum of 128 float32 products
+        KernelCase("lightning_decode[32x128]", state, step_fn,
+                   lightning_decode_reference, TOL_EXACT),
+        KernelCase("lightning_decode[write_state]", state, write_fn,
+                   lambda leaf, layer, *_: leaf.at[layer, 5].set(
+                       leaf[0, 2] * 2.0), TOL_EXACT),
+    ]
+
+
 def kernel_cases(sz: Sizes, interpret) -> List[KernelCase]:
     """Every Pallas kernel ``zoo_tpu.ops.pallas`` exports, at the
     smoke's shapes. ``tests/test_tpu_lowering.py`` compiles the same
     list for a v5e without a chip."""
     return (_flash_cases(sz, interpret) + _paged_cases(sz, interpret)
+            + _sparse_state_cases(sz, interpret)
             + _matmul_cases(sz, interpret) + _conv_cases(sz, interpret)
             + _optim_cases(sz, interpret))
 

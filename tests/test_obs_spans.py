@@ -361,6 +361,78 @@ def test_the_latent_moe_step_keeps_the_names_and_counts_its_experts():
     assert 2 <= _counter("zoo_llm_moe_expert_visits_total") - visits0 <= 4
 
 
+def test_the_sparse_and_state_step_keeps_the_names_and_counts_its_pages():
+    """The third architecture runs the same ``_decode_fn`` /
+    ``_prefill_chunk_fn`` under the scopes the accepted metric files
+    match, adds its own (``zoo.sparse_select``, ``zoo.sparse_attend``
+    inside ``zoo.paged_attend``, ``zoo.ck_append``, ``zoo.lightning``,
+    the kernels ``zoo_sparse_decode`` and ``zoo_lightning_decode``), and
+    its counters and gauge are in the catalog and move with a decode
+    tick that is read."""
+    from zoo_tpu.models.llm.minicpm_sala import tiny_minicpm_sala_config
+    from zoo_tpu.obs.catalog import METRICS
+    from zoo_tpu.obs.metrics import get_registry
+    from zoo_tpu.serving.llm.model import PagedDecoderModel
+    from zoo_tpu.serving.llm.model_sala import PagedMiniCpmSalaModel
+    for fn in ("_decode_fn", "_prefill_chunk_fn", "_prefill_fn",
+               "decode_step", "read_tokens", "copy_block",
+               "export_kv_blocks", "import_kv_blocks"):
+        # the skeleton's, not a copy of them
+        assert getattr(PagedMiniCpmSalaModel, fn) \
+            is getattr(PagedDecoderModel, fn), fn
+    model = PagedMiniCpmSalaModel(
+        tiny_minicpm_sala_config(64), num_slots=2, block_size=8,
+        num_blocks=16, max_blocks_per_seq=6, prefill_buckets=(8,),
+        prefill_chunk=8, kv_dtype="f32", decode_impl="flash", spec_k=0)
+    S, W, C = model.num_slots, model.max_blocks_per_seq, 8
+    lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
+             jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
+    decode = model._decode.lower(
+        model.params, model._cache, jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32), jnp.ones(S, bool),
+        jnp.zeros((S, W), jnp.int32), jnp.zeros(S, jnp.int32),
+        *lanes).as_text(debug_info=True)
+    chunk = model._prefill_chunked.lower(
+        model.params, model._cache, jnp.zeros((1, C), jnp.int32),
+        jnp.int32(0), jnp.int32(C), jnp.zeros(W, jnp.int32),
+        jnp.float32(0), jnp.int32(0), jnp.float32(1),
+        jnp.uint32(0), jnp.int32(1)).as_text(debug_info=True)
+    assert "jit(_decode_fn)" in decode
+    assert "jit(_prefill_chunk_fn)" in chunk
+    assert "zoo_sparse_decode" in decode and "zoo_lightning_decode" in decode
+    assert "zoo_paged_decode" not in decode and "zoo_state_write" in chunk
+    for scope in ("zoo.attn_proj", "zoo.kv_append", "zoo.paged_attend",
+                  "zoo.sparse_select", "zoo.sparse_attend", "zoo.ck_append",
+                  "zoo.lightning", "zoo.mlp", "zoo.lm_head", "zoo.sample"):
+        assert scope in decode and scope in chunk, scope
+    assert "zoo.paged_attend/zoo.sparse_attend" in decode
+    for name in ("zoo_llm_sparse_pages_attended_total",
+                 "zoo_llm_sparse_pages_resident_total",
+                 "zoo_llm_state_steps_total", "zoo_llm_state_resets_total"):
+        assert METRICS[name] == ("counter", ())
+    assert METRICS["zoo_llm_state_bytes"] == ("gauge", ())
+    gauges = {g["name"]: g["value"]
+              for g in get_registry().snapshot()["gauges"]}
+    assert gauges["zoo_llm_state_bytes"] == model.state_bytes \
+        == 2 * 2 * 4 * 16 * 16 * 4
+    before = {n: _counter(n) for n in METRICS if n.startswith(
+        ("zoo_llm_sparse_", "zoo_llm_state_")) and n.endswith("_total")}
+    tables = np.zeros((S, W), np.int32)
+    tables[0, :2] = (3, 4)                 # one live lane, one idle
+    args = (np.ones(S, np.int32), np.ones(S, bool), tables,
+            np.asarray([9, 0], np.int32),
+            tuple(np.asarray(x) for x in lanes))
+    first = model.decode_step(None, *args)
+    model.read_tokens(model.decode_step(first, *args))
+    moved = {n: _counter(n) - v for n, v in before.items()}
+    # of the tick read: one live lane at position 9 holds 2 pages, both
+    # attended (dense), by 2 K/V heads in 2 sparse layers; 2 states
+    assert moved == {"zoo_llm_sparse_pages_attended_total": 8,
+                     "zoo_llm_sparse_pages_resident_total": 8,
+                     "zoo_llm_state_steps_total": 2,
+                     "zoo_llm_state_resets_total": 0}
+
+
 def test_the_attributes_the_harness_wraps_are_there():
     """``benchmarks/harness/serve_cell.py`` wraps these by name and
     reads ``decode_step``'s positional arguments 3 and 4."""

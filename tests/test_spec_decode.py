@@ -129,8 +129,14 @@ NOISE = np.array([9, 17, 23], np.int32)
 
 class TestEngineSpecFake:
     def test_spec_stream_identical_to_plain(self):
-        ref = _drain([LLMEngine(_SpecFake(spec_k=0))
-                      .start().submit(CYCLIC, 10, rid="p")])
+        plain = LLMEngine(_SpecFake(spec_k=0)).start()
+        try:
+            ref = _drain([plain.submit(CYCLIC, 10, rid="p")])
+        finally:
+            # a scheduler left running idles in 5 ms spans for the rest
+            # of the worker's life, and whatever counts the ring's
+            # spans next (tests/test_obs_spans.py) counts them too
+            plain.stop()
         fake = _SpecFake(spec_k=3)
         eng = LLMEngine(fake).start()
         try:

@@ -81,7 +81,8 @@ def test_every_exported_kernel_is_covered():
     """The case list names each kernel ``zoo_tpu.ops.pallas`` exports
     (the other exports are jnp helpers and dispatch rules)."""
     kernels = {"flash_attention", "paged_flash_decode",
-               "paged_flash_prefill", "quantized_matmul",
+               "paged_flash_prefill", "sparse_paged_decode",
+               "lightning_decode", "quantized_matmul",
                "fused_quantized_matmul", "conv2d", "conv2d_int8",
                "fused_apply_sgd", "fused_apply_adam", "fused_bottleneck"}
     import zoo_tpu.ops.pallas as zp
@@ -406,6 +407,81 @@ def test_serving_step_moves_no_cache(v5e, monkeypatch, which, kv):
     planes = [a.shape for a in cache.values() if len(a.shape) == 4]
     assert cache_moves(text, kv_leaves) == ([], [])
     assert cache_moves(text, planes)[0] == []
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill_chunk"])
+def test_sparse_and_state_step_moves_no_cache(v5e, monkeypatch, which):
+    """The block with two kinds of per-sequence memory, at the head
+    widths and the cache geometry of ``minicpm-sala.ctx32k-closed``
+    (17,664 pages of 2 K/V heads x 64 rows x 128, 544 table entries; a
+    per-slot state of heads x 128 x 128 float32): all four leaves are
+    aliased input to output, and no operation copies, relays, slices out
+    or writes back a whole leaf (K, V, the compressed keys, the state).
+    Two things this test has caught: a gather of a window's keys with
+    the head axis left as a slice made the compiler relay the whole K
+    leaf, once a sparse layer and tick (1.7 GB each); writing a chunk's
+    state back with ``.at[layer, slot].set`` gave the update the layout
+    of the product that made it and relaid the whole state leaf, there
+    and back, which is why ``zoo_state_write`` exists."""
+    from zoo_tpu.analysis.hlo import input_output_aliases
+    from zoo_tpu.models.llm.minicpm_sala import MiniCpmSalaConfig
+    from zoo_tpu.serving.llm.model import narrow_dot_weights
+    from zoo_tpu.serving.llm.model_sala import PagedMiniCpmSalaModel
+
+    for mod in ("sparse_decode", "lightning"):
+        monkeypatch.setattr(sys.modules[f"zoo_tpu.ops.pallas.{mod}"],
+                            "_resolve_interpret", lambda i: False)
+    cfg = MiniCpmSalaConfig(
+        vocab=512, hidden=512, intermediate=512, lightning_heads=8,
+        mixer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"))
+    model = PagedMiniCpmSalaModel(
+        cfg, seed=0, num_slots=8, block_size=64, num_blocks=4,
+        max_blocks_per_seq=544, prefill_buckets=(512,), prefill_chunk=512,
+        kv_dtype="bf16", spec_k=0, decode_impl="flash")
+    one = SingleDeviceSharding(v5e[0])
+    cache = {name: jax.ShapeDtypeStruct(
+        a.shape if name in model.UNPAGED_LEAVES
+        else (a.shape[0], 17664) + a.shape[2:], a.dtype)
+        for name, a in model._cache.items()}
+    params = narrow_dot_weights(model.params, "tpu", model.DOT_LEAVES)
+    if which == "decode":
+        text = _step_hlo(model, which, params, cache, one)
+        assert "zoo_sparse_decode" in text
+        assert "zoo_lightning_decode" in text
+    else:
+        # the chunk of a stateful model takes its slot
+        def chunk(*args):
+            return model._prefill_chunk_fn(*args)
+        model_args = _step_args(model, one)
+        text = jax.jit(chunk, donate_argnums=(1,)).lower(
+            *model_args(params, cache)).compile().as_text()
+        assert "zoo_state_write" in text
+    assert model.donated_cache_leaves() == 4
+    assert len({p for _, p in input_output_aliases(text)}) == 4
+    # (at this test's 8 slots of 8 heads the compiler may prefetch the
+    # 8 MB state into VMEM, one layout on both sides: no relayout; the
+    # cell's 604 MB never fits)
+    assert cache_moves(text, [a.shape for a in cache.values()])[0] == []
+
+
+def _step_args(model, one):
+    """The chunk executable's operands from shapes, the slot last."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    def avals(tree):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
+
+    def args(params, cache):
+        lane = [sds((), dt) for dt in (jnp.float32, jnp.int32, jnp.float32,
+                                       jnp.uint32)]
+        return (avals(params), avals(cache),
+                sds((1, model.suffix_chunk_size), jnp.int32),
+                sds((), jnp.int32), sds((), jnp.int32),
+                sds((model.max_blocks_per_seq,), jnp.int32), *lane,
+                sds((), jnp.int32))
+    return args
 
 
 def test_flash_attention_compiles_on_a_mesh(v5e, monkeypatch):

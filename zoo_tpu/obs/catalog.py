@@ -131,6 +131,13 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "zoo_llm_host_transfer_bytes_total": ("counter", ("kind",)),
     "zoo_llm_moe_expert_visits_total": ("counter", ()),
     "zoo_llm_moe_rows_total": ("counter", ()),
+    # -- a per-slot recurrent state beside the paged cache, and pages
+    # selected inside it (serving/llm/model_sala.py) ----------------------
+    "zoo_llm_sparse_pages_attended_total": ("counter", ()),
+    "zoo_llm_sparse_pages_resident_total": ("counter", ()),
+    "zoo_llm_state_steps_total": ("counter", ()),
+    "zoo_llm_state_resets_total": ("counter", ()),
+    "zoo_llm_state_bytes": ("gauge", ()),
     "zoo_llm_spec_proposed_tokens_total": ("counter", ()),
     "zoo_llm_spec_accepted_tokens_total": ("counter", ()),
     "zoo_llm_spec_accept_len": ("histogram", ()),

@@ -571,6 +571,21 @@ class LLMEngine:
         if prefix_cache is None:
             prefix_cache = knob_value("ZOO_LLM_PREFIX_CACHE")
         self.prefix_cache = bool(prefix_cache)
+        # a model that keeps a per-slot recurrent state beside its
+        # paged cache (the skeleton's ``UNPAGED_LEAVES``, the one
+        # declaration of it): its prefills are handed the slot, and what
+        # would need a state stored, rolled back or shipped is refused
+        self._stateful = bool(getattr(model, "UNPAGED_LEAVES", ()))
+        if self._stateful and self.prefix_cache:
+            raise ValueError(
+                f"{type(model).__name__} keeps a per-slot recurrent "
+                "state: a prefix cache over it is not built (a shared "
+                "prefix has no stored state); prefix_cache must be off")
+        if self._stateful and self.role != "mixed":
+            raise ValueError(
+                f"{type(model).__name__} keeps a per-slot recurrent "
+                "state: kv_migrate of a stateful sequence is not built, "
+                f"so the replica role must be mixed (got {role!r})")
         self.max_waiting = max_waiting if max_waiting is not None else \
             env_int("ZOO_LLM_MAX_WAITING", 256)
         # multitenancy (docs/multitenancy.md): the QoS registry every
@@ -715,6 +730,11 @@ class LLMEngine:
         prefilling (docs/disaggregated_serving.md)."""
         if spec_k is not None and int(spec_k) < 0:
             raise ValueError("spec_k must be >= 0")
+        if self._stateful and (handoff or adopt is not None):
+            raise ValueError(
+                "kv_migrate of a sequence with a per-slot recurrent "
+                "state is not built: no handoff from, no adoption into "
+                f"{type(self.model).__name__}")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -1278,10 +1298,11 @@ class LLMEngine:
         allocator is untouched here, so a peer that dies after commit
         but before the generate lands leaks nothing. Refused (False)
         when the payload cannot be decoded faithfully here — block
-        geometry mismatch, or this model holds real KV state and the
-        payload carries none."""
+        geometry mismatch, this model holds real KV state and the
+        payload carries none, or this model keeps a per-slot recurrent
+        state, which no payload carries."""
         if int(payload.get("block_size") or 0) != \
-                self.allocator.block_size:
+                self.allocator.block_size or self._stateful:
             return False
         if hasattr(self.model, "import_kv_blocks") and \
                 payload.get("kv") is None:
@@ -1408,6 +1429,10 @@ class LLMEngine:
         for slot, h, epoch, prompt, start, take, n, row, copy in work:
             t0 = time.perf_counter()
             t0_wall = time.time()
+            # a stateful model builds the sequence's state where its
+            # decode ticks will find it: in its slot's row
+            kw = {"slot": self._slots.index(slot)} if self._stateful \
+                else {}
             try:
                 if copy is not None:
                     # the copy-on-write device copy owed from
@@ -1428,10 +1453,10 @@ class LLMEngine:
                 if self._chunk:
                     tok = self.model.prefill_chunk(
                         prompt[start:start + take], start, n, row,
-                        sampling=h.sampling)
+                        sampling=h.sampling, **kw)
                 elif start == 0:
                     tok = self.model.prefill(prompt, row,
-                                             sampling=h.sampling)
+                                             sampling=h.sampling, **kw)
                 else:
                     # cache-hit prompt in a bucketed config: feed the
                     # novel suffix through the ONE chunk executable
@@ -1444,7 +1469,7 @@ class LLMEngine:
                     for s0 in range(start, start + take, C):
                         tok = self.model.prefill_chunk(
                             prompt[s0:min(s0 + C, n)], s0, n, row,
-                            sampling=h.sampling)
+                            sampling=h.sampling, **kw)
                 if start + take >= n:
                     # the prompt's last chunk: wait for its first
                     # generated token, the one host sync of the prefill
@@ -2164,6 +2189,15 @@ class LLMEngine:
             # a live row and the rows computed, over all decode ticks
             out["moe_expert_visits"] = self.model.moe_expert_visits
             out["moe_rows"] = self.model.moe_rows
+        if self._stateful:
+            # a recurrent state beside the paged cache, and (where the
+            # model selects pages) what the decode ticks attended of
+            # what was resident
+            for key in ("state_bytes", "state_bytes_per_slot",
+                        "state_resets", "state_steps",
+                        "sparse_pages_attended", "sparse_pages_resident"):
+                if hasattr(self.model, key):
+                    out[key] = getattr(self.model, key)
         if hasattr(self.model, "compile_counts"):
             out["compiles"] = self.model.compile_counts()
         return out

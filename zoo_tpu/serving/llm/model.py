@@ -373,8 +373,12 @@ class PagedDecoderModel:
       weight tree, the leaves that are only ever dot operands, and one
       of them (``weight_dtype`` reports its dtype);
     * ``_rope_dim`` — the rotated width of a head;
-    * ``_init_cache`` / ``_cache_shardings`` — the cache pytree (block
-      axis at position 1 of every leaf) and its bytes per token;
+    * ``_init_cache`` / ``_cache_shardings`` — the cache pytree and its
+      bytes per token. Every PAGED leaf has its block axis at position
+      1; ``UNPAGED_LEAVES`` names the leaves that are not paged (a
+      per-slot recurrent state beside the paged K/V: slot axis at
+      position 1, addressed by a tick's row or a prefill's ``slot``),
+      which ``copy_block`` and KV migration leave alone;
     * ``_layers(params, cache, h, attend, at)`` — the layer stack: the
       scan (and any leading layer outside it), the attention half
       through ``attend`` — one of ``_attend_decode`` / ``_attend_bucket``
@@ -390,6 +394,7 @@ class PagedDecoderModel:
     """
 
     DOT_LEAVES = DOT_BLOCK_LEAVES
+    UNPAGED_LEAVES: Tuple[str, ...] = ()
 
     def __init__(self, config, *,
                  params=None, seed: int = 0,
@@ -554,11 +559,18 @@ class PagedDecoderModel:
                             "ids": [int(d.id) for d in devs]}
 
     def _copy_block_fn(self, cache, src, dst):
-        """Block ``src`` -> ``dst`` across every layer (K, V and scale
-        rows alike): the device half of copy-on-write — the allocator
-        forks the table entry, this moves the bytes."""
-        return {name: arr.at[:, dst].set(arr[:, src])
+        """Block ``src`` -> ``dst`` across every layer of every PAGED
+        leaf (K, V and scale rows alike): the device half of
+        copy-on-write — the allocator forks the table entry, this moves
+        the bytes. A leaf that is not paged passes through."""
+        return {name: arr if name in self.UNPAGED_LEAVES
+                else arr.at[:, dst].set(arr[:, src])
                 for name, arr in cache.items()}
+
+    def _paged(self) -> dict:
+        """The paged leaves of the cache pytree."""
+        return {name: arr for name, arr in self._cache.items()
+                if name not in self.UNPAGED_LEAVES}
 
     @jax.named_scope("zoo.lm_head")
     def _lm_head(self, params, h):
@@ -589,7 +601,9 @@ class PagedDecoderModel:
                   block_tables, (positions // self.block_size)[:, None],
                   axis=1)[:, 0],                              # (S,)
               "off": positions % self.block_size,
-              "tables": block_tables, "pos": positions, "real": None}
+              "tables": block_tables, "pos": positions, "real": None,
+              # a tick's row IS its slot
+              "slot": None}
         h, cache, aux = self._layers(params, cache, h,
                                      self._attend_decode, at)
         logits = self._lm_head(params, h)                     # (S, vocab)
@@ -599,13 +613,15 @@ class PagedDecoderModel:
         return (nxt, cache, *aux)
 
     def _prefill_fn(self, params, cache, ids, length, block_table,
-                    temp, topk, topp, seed):
+                    temp, topk, topp, seed, slot=None):
         """Causal forward over one padded prompt (1, L_bucket): scatter
         the prompt's cache rows into the paged cache and return the
         sampled first generated token. ``length`` is the true prompt
         length (dynamic); pad positions write to the trash block and
         are never attended by real tokens (they sit in the causal
-        future)."""
+        future). ``slot`` is the sequence's slot, for an architecture
+        that keeps a per-slot state beside the paged leaves (the others
+        are never handed one)."""
         L = ids.shape[1]
         pos = jnp.arange(L)
         # pad positions → trash block 0 (their rows must not land in the
@@ -616,7 +632,7 @@ class PagedDecoderModel:
                                block_table[pos // self.block_size], 0),
               "off": pos % self.block_size,
               "tables": block_table[None], "pos": pos[None],
-              "real": (pos < length)[None]}
+              "real": (pos < length)[None], "slot": slot}
         h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
         h, cache, _ = self._layers(params, cache, h,
                                    self._attend_bucket, at)
@@ -627,7 +643,7 @@ class PagedDecoderModel:
         return tok, cache
 
     def _prefill_chunk_fn(self, params, cache, ids, start, length,
-                          block_table, temp, topk, topp, seed):
+                          block_table, temp, topk, topp, seed, slot=None):
         """One fixed-size CHUNK of a prompt: write the chunk's cache
         rows through the block table at positions ``start..start+C-1``
         and attend each chunk token causally over everything already
@@ -655,7 +671,7 @@ class PagedDecoderModel:
                                block_table[pos // self.block_size], 0),
               "off": pos % self.block_size,
               "tables": block_table[None], "pos": pos[None],
-              "real": real[None]}
+              "real": real[None], "slot": slot}
         h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
         h, cache, _ = self._layers(params, cache, h,
                                    self._attend_chunk, at)
@@ -698,7 +714,8 @@ class PagedDecoderModel:
                                       pos // self.block_size, axis=1),
                   0),                                         # (S, T)
               "off": pos % self.block_size,
-              "tables": block_tables, "pos": pos, "real": real}
+              "tables": block_tables, "pos": pos, "real": real,
+              "slot": None}
         h = jnp.take(params["embed"], tokens, axis=0)   # (S, T, hidden)
         h, cache, _ = self._layers(params, cache, h,
                                    self._attend_verify, at)
@@ -719,11 +736,13 @@ class PagedDecoderModel:
         return float(t), int(k), float(p), int(s) & 0xFFFFFFFF
 
     def prefill(self, prompt: np.ndarray, block_table_row: np.ndarray,
-                sampling=None) -> int:
+                sampling=None, slot: Optional[int] = None) -> int:
         """Run one prompt through its bucket executable; the prompt's
         K/V land in the blocks listed in ``block_table_row``. Returns
         the first generated token (sampled per ``sampling`` =
-        ``(temperature, top_k, top_p, seed)``; None = greedy)."""
+        ``(temperature, top_k, top_p, seed)``; None = greedy). ``slot``:
+        the sequence's slot, which the engine hands to a model with a
+        per-slot state (``UNPAGED_LEAVES``) and to no other."""
         n = int(prompt.shape[0])
         bucket = _pick_bucket(self.prefill_buckets, n)
         if bucket is None:
@@ -740,14 +759,19 @@ class PagedDecoderModel:
             tok, self._cache = self._prefill(
                 self.params, self._cache, jnp.asarray(ids),
                 jnp.int32(n), jnp.asarray(bt), jnp.float32(t),
-                jnp.int32(k), jnp.float32(p), jnp.uint32(s))
+                jnp.int32(k), jnp.float32(p), jnp.uint32(s),
+                *self._slot_operand(slot))
             out = int(tok)
         _host_transfer.labels(kind="prefill").inc(4)
         return out
 
+    @staticmethod
+    def _slot_operand(slot) -> tuple:
+        return () if slot is None else (jnp.int32(slot),)
+
     def prefill_chunk(self, chunk: np.ndarray, start: int,
                       total_len: int, block_table_row: np.ndarray,
-                      sampling=None):
+                      sampling=None, slot: Optional[int] = None):
         """Dispatch ONE fixed-size chunk of a prompt (`start` = offset
         of ``chunk[0]`` in the sequence) WITHOUT a host sync. Every
         chunk call runs the same single executable regardless of prompt
@@ -774,15 +798,16 @@ class PagedDecoderModel:
                     self.params, self._cache, jnp.asarray(ids),
                     jnp.int32(start), jnp.int32(total_len),
                     jnp.asarray(bt), jnp.float32(t), jnp.int32(k),
-                    jnp.float32(p), jnp.uint32(s))
+                    jnp.float32(p), jnp.uint32(s),
+                    *self._slot_operand(slot))
             _host_transfer.labels(kind="prefill").inc(4)
         return tok
 
     def copy_block(self, src: int, dst: int):
         """Device half of copy-on-write: duplicate block ``src`` into
-        ``dst`` (K, V and int8 scale rows, every layer) before a
-        sequence writes into its forked copy. One tiny fixed-shape
-        executable, compiled once."""
+        ``dst`` (K, V and int8 scale rows: every paged leaf, every
+        layer) before a sequence writes into its forked copy. One tiny
+        fixed-shape executable, compiled once."""
         with self._lock:
             self._cache = self._copy(self._cache, jnp.int32(src),
                                      jnp.int32(dst))
@@ -790,8 +815,9 @@ class PagedDecoderModel:
     # -- KV migration (docs/disaggregated_serving.md) ----------------------
     def export_kv_blocks(self, blocks) -> dict:
         """Host copies of the cache rows for ``blocks``, keyed like the
-        cache pytree (``k``/``v`` and the int8 scale rows), block axis
-        at position 1 in the order given — exactly the bytes a decode
+        cache pytree's PAGED leaves (``k``/``v`` and the int8 scale
+        rows; a leaf that is not paged is no block's and stays), block
+        axis at position 1 in the order given — exactly the bytes a decode
         replica's :meth:`import_kv_blocks` writes back, so a migrated
         sequence decodes from bit-identical cache state. Under int8 the
         wire pays 1 byte/row-element + the f32 scales (the on-device
@@ -802,20 +828,21 @@ class PagedDecoderModel:
         idx = jnp.asarray(list(blocks), jnp.int32)
         with self._lock:
             parts = {name: arr[:, idx] for name, arr in
-                     self._cache.items()}
+                     self._paged().items()}
         return {name: np.asarray(part) for name, part in parts.items()}
 
     def import_kv_blocks(self, blocks, data: dict, start: int = 0):
         """Write exported cache rows into local ``blocks``:
         ``data[name][:, start : start + len(blocks)]`` lands in block
-        ``blocks[i]`` — the adopting engine skips ``start`` leading
+        ``blocks[i]``, for every paged leaf — the adopting engine skips
+        ``start`` leading
         blocks it aliased from its own prefix cache instead. Runs
         eagerly (plain scatters), so a pure-decode replica's traced
         executable census is untouched."""
         blocks = list(blocks)
         if not blocks:
             return
-        missing = set(self._cache) - set(data)
+        missing = set(self._paged()) - set(data)
         if missing:
             raise ValueError(
                 f"kv payload is missing cache planes {sorted(missing)} "
@@ -823,7 +850,7 @@ class PagedDecoderModel:
         idx = jnp.asarray(blocks, jnp.int32)
         stop = start + len(blocks)
         with self._lock:
-            for name, arr in self._cache.items():
+            for name, arr in self._paged().items():
                 rows = jnp.asarray(np.asarray(data[name])[:, start:stop],
                                    arr.dtype)
                 self._cache[name] = arr.at[:, idx].set(rows)

@@ -25,6 +25,23 @@ is the llm half of that resolution:
   ``v_dim``, ``intermediate``, ``moe_intermediate``, ``experts``,
   ``shared``, ``top_k``, ``dense``).
 
+* ``minicpm_sala:tiny:slots=4,block=8,blocks=96,tables=24,chunk=16`` —
+  the third architecture (``minicpm_sala``: block-sparse attention that
+  selects pages inside the paged cache among Lightning linear-attention
+  layers whose per-slot state lives beside it;
+  ``serving/llm/model_sala.py``): the same engine keys, its own
+  architecture keys (``vocab``, ``hidden``, ``n_head``, ``n_kv_head``,
+  ``head_dim``, ``l_heads``, ``l_head_dim``, ``intermediate``, ``depth``,
+  ``mixers`` as a string of ``s`` (sparse) and ``l`` (Lightning), one
+  letter a layer, and the selection's ``kernel``, ``stride``,
+  ``sel_block``, ``init_blocks``, ``window``, ``topk``, ``dense_len``).
+  ``block`` must equal ``sel_block`` (a page is one selection block).
+  Two kinds of per-sequence memory: paged K/V and compressed keys for
+  the sparse layers, one float32 state a slot for the Lightning layers.
+  Refused for this model, with a ``ValueError`` that says so:
+  ``prefix_cache=1``, ``spec_k>0``, ``kv=int8``, ``tp>1`` / ``mesh=``, a
+  ``role`` other than ``mixed`` (KV migration).
+
 Engine knobs resolve env (``ZOO_LLM_*``) < spec < explicit kwargs —
 the env is the deployment-wide default, an explicit spec component
 overrides it; the env names are documented in docs/llm_serving.md.
@@ -37,6 +54,18 @@ from typing import Dict, Tuple
 
 LLM_PREFIX = "llama:"
 GLM_PREFIX = "glm_moe_lite:"
+SALA_PREFIX = "minicpm_sala:"
+# spec key → MiniCpmSalaConfig field (``mixers`` apart: a string)
+_SALA_ARCH_KEYS = {"vocab": "vocab", "hidden": "hidden",
+                   "n_head": "n_head", "n_kv_head": "n_kv_head",
+                   "head_dim": "head_dim", "l_heads": "lightning_heads",
+                   "l_head_dim": "lightning_head_dim",
+                   "intermediate": "intermediate", "depth": "depth",
+                   "kernel": "kernel_size", "stride": "kernel_stride",
+                   "sel_block": "sparse_block",
+                   "init_blocks": "init_blocks", "window": "window_size",
+                   "topk": "topk", "dense_len": "dense_len"}
+_SALA_MIXERS = {"s": "minicpm4", "l": "lightning-attn"}
 # spec key → GlmMoeLiteConfig field
 _GLM_ARCH_KEYS = {"vocab": "vocab", "hidden": "hidden",
                   "n_block": "n_block", "n_head": "n_head",
@@ -70,7 +99,7 @@ _STR_KEYS = {"kv": "kv_dtype", "prefill_impl": "prefill_impl",
 
 def is_llm_spec(spec) -> bool:
     return isinstance(spec, str) and spec.startswith(
-        (LLM_PREFIX, GLM_PREFIX, SYNTH_LLM_PREFIX))
+        (LLM_PREFIX, GLM_PREFIX, SALA_PREFIX, SYNTH_LLM_PREFIX))
 
 
 def _parse_kv(parts) -> Dict[str, str]:
@@ -89,16 +118,19 @@ def _parse_kv(parts) -> Dict[str, str]:
 
 
 def parse_llm_spec(spec: str) -> Tuple[Dict, Dict]:
-    """``(config_kwargs, engine_kwargs)`` from a ``llama:...`` or
-    ``glm_moe_lite:...`` spec."""
+    """``(config_kwargs, engine_kwargs)`` from a ``llama:...``,
+    ``glm_moe_lite:...`` or ``minicpm_sala:...`` spec."""
     if not is_llm_spec(spec):
         raise ValueError(f"not an llm spec: {spec!r}")
     glm = spec.startswith(GLM_PREFIX)
-    body = spec[len(GLM_PREFIX if glm else LLM_PREFIX):]
+    sala = spec.startswith(SALA_PREFIX)
+    body = spec[len(GLM_PREFIX if glm else SALA_PREFIX if sala
+                    else LLM_PREFIX):]
     parts = body.split(":") if body else [""]
     preset = parts[0] if parts[0] and "=" not in parts[0] else None
     kvs = _parse_kv(parts[1:] if preset else parts)
-    arch_keys = _GLM_ARCH_KEYS if glm else {k: k for k in _ARCH_KEYS}
+    arch_keys = _GLM_ARCH_KEYS if glm else _SALA_ARCH_KEYS if sala \
+        else {k: k for k in _ARCH_KEYS}
 
     cfg_kwargs: Dict = {}
     if preset == "tiny" or preset is None and not any(
@@ -106,6 +138,10 @@ def parse_llm_spec(spec: str) -> Tuple[Dict, Dict]:
         if glm:
             from zoo_tpu.models.llm.glm_moe_lite import (
                 tiny_glm_moe_lite_config as tiny_config,
+            )
+        elif sala:
+            from zoo_tpu.models.llm.minicpm_sala import (
+                tiny_minicpm_sala_config as tiny_config,
             )
         else:
             from zoo_tpu.models.llm.llama import (
@@ -119,6 +155,12 @@ def parse_llm_spec(spec: str) -> Tuple[Dict, Dict]:
     for k, field in arch_keys.items():
         if k in kvs:
             cfg_kwargs[field] = int(kvs.pop(k))
+    if sala and "mixers" in kvs:
+        letters = kvs.pop("mixers")
+        if not letters or set(letters) - set(_SALA_MIXERS):
+            raise ValueError(f"mixers={letters!r}: one letter a layer, "
+                             "s (sparse) or l (Lightning)")
+        cfg_kwargs["mixer_types"] = tuple(_SALA_MIXERS[x] for x in letters)
 
     eng: Dict = {}
     for short, name in _ENGINE_KEYS.items():
@@ -183,8 +225,9 @@ def build_synthetic_engine(spec: str, start: bool = True, **overrides):
 
 def build_llm_engine(spec: str, start: bool = True, **overrides):
     """An :class:`LLMEngine` (started unless ``start=False``) from a
-    ``llama:...``, ``glm_moe_lite:...`` or ``synthllm:...`` spec. ``overrides`` are
-    engine/model kwargs that win over both the spec and the env."""
+    ``llama:...``, ``glm_moe_lite:...``, ``minicpm_sala:...`` or
+    ``synthllm:...`` spec. ``overrides`` are engine/model kwargs that
+    win over both the spec and the env."""
     if spec.startswith(SYNTH_LLM_PREFIX):
         return build_synthetic_engine(spec, start=start, **overrides)
     from zoo_tpu.serving.llm.engine import LLMEngine
@@ -194,6 +237,13 @@ def build_llm_engine(spec: str, start: bool = True, **overrides):
         )
         from zoo_tpu.serving.llm.model_mla import (
             PagedGlmMoeLiteModel as Model,
+        )
+    elif spec.startswith(SALA_PREFIX):
+        from zoo_tpu.models.llm.minicpm_sala import (
+            MiniCpmSalaConfig as Config,
+        )
+        from zoo_tpu.serving.llm.model_sala import (
+            PagedMiniCpmSalaModel as Model,
         )
     else:
         from zoo_tpu.models.llm.llama import LlamaConfig as Config

@@ -1526,32 +1526,36 @@ def test_narrowed_logits_within_bf16_activation_rounding(
     monkeypatch.setattr(M, "_sample_tokens", lambda logits, *a: logits)
     prompt = np.random.RandomState(5).randint(0, cfg.vocab, (8,))
     row = np.array([1, 2, 3, 0, 0, 0], np.int32)
-    greedy = (jnp.float32(0.0), jnp.int32(0), jnp.float32(1.0),
-              jnp.uint32(0))
 
     def logits(m):
         cache = {k: jnp.zeros_like(v) for k, v in m._cache.items()}
-        ids = jnp.asarray(prompt[None], jnp.int32)
         if which == "prefill_chunk":
+            lay = m._layouts["prefill_chunk"]
             for start in (0, 4):       # two chunks of 4 through the cache
+                ids = np.zeros(lay.fields[0][1], np.int32)
+                ids[0, :4] = prompt[start:start + 4]
+                # (a chunk narrower than the executable is a prompt's
+                # last: the first is fed as a prompt of 4)
                 out, cache = m._prefill_chunk_fn(
-                    m.params, cache, ids[:, start:start + 4],
-                    jnp.int32(start), jnp.int32(8), jnp.asarray(row),
-                    *greedy)
+                    m.params, cache, lay.pack(
+                        ids=ids, start=start, **m._row_operands(
+                            start + 4, row, None, None)))
             return np.asarray(out)
-        out, cache = m._prefill_fn(m.params, cache, ids, jnp.int32(8),
-                                   jnp.asarray(row), *greedy)
+        out, cache = m._prefill_fn(
+            m.params, cache, m._prefill_layouts[8].pack(
+                ids=prompt[None], **m._row_operands(8, row, None, None)))
         if which == "prefill":
             return np.asarray(out)
         S = m.num_slots
         tables = np.zeros((S, m.max_blocks_per_seq), np.int32)
         tables[0] = row
-        lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
-                 jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
         out, _ = m._decode_fn(
             m.params, cache, jnp.zeros(S, jnp.int32),
-            jnp.full((S,), 7, jnp.int32), jnp.ones(S, bool),
-            jnp.asarray(tables), jnp.asarray([8, 0], jnp.int32), *lanes)
+            m._layouts["decode"].pack(
+                host_tokens=np.full(S, 7), use_host=np.ones(S, bool),
+                block_tables=tables, positions=[8, 0],
+                temps=np.zeros(S), topks=np.zeros(S), topps=np.ones(S),
+                seeds=np.zeros(S)))
         return np.asarray(out)[0]
 
     ref, got = logits(wide), logits(narrow)

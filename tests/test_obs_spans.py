@@ -266,6 +266,18 @@ def _tiny_paged_model(**kw):
                            prefill_chunk=8, **kw)
 
 
+def _lowered_steps(model):
+    """The decode and the chunk executables as lowered text, each from
+    the previous tick's tokens (decode) and its one packed operand."""
+    def text(fn, *args):
+        return fn.lower(model.params, model._cache,
+                        *args).as_text(debug_info=True)
+    return (text(model._decode, model._zero_tokens,
+                 model._layouts["decode"].aval()),
+            text(model._prefill_chunked,
+                 model._layouts["prefill_chunk"].aval()))
+
+
 def test_jitted_steps_kernels_and_scopes_keep_their_names():
     """``benchmarks/metrics/*.json`` match executables by function name
     (``decode_fn``, ``epoch_fn``) and the paged decode kernel by its
@@ -279,19 +291,7 @@ def test_jitted_steps_kernels_and_scopes_keep_their_names():
         KerasNet._build_epoch_train_step)
     model = _tiny_paged_model(kv_dtype="int8", decode_impl="flash",
                               prefill_impl="flash")
-    S, W, C = model.num_slots, model.max_blocks_per_seq, 8
-    lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
-             jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
-    decode = model._decode.lower(
-        model.params, model._cache, jnp.zeros(S, jnp.int32),
-        jnp.zeros(S, jnp.int32), jnp.ones(S, bool),
-        jnp.zeros((S, W), jnp.int32), jnp.zeros(S, jnp.int32),
-        *lanes).as_text(debug_info=True)
-    chunk = model._prefill_chunked.lower(
-        model.params, model._cache, jnp.zeros((1, C), jnp.int32),
-        jnp.int32(0), jnp.int32(C), jnp.zeros(W, jnp.int32),
-        jnp.float32(0), jnp.int32(0), jnp.float32(1),
-        jnp.uint32(0)).as_text(debug_info=True)
+    decode, chunk = _lowered_steps(model)
     assert "jit(_decode_fn)" in decode
     assert "jit(_prefill_chunk_fn)" in chunk
     assert "zoo_paged_decode" in decode and "zoo_paged_prefill" in chunk
@@ -321,19 +321,10 @@ def test_the_latent_moe_step_keeps_the_names_and_counts_its_experts():
         tiny_glm_moe_lite_config(64), num_slots=2, block_size=4,
         num_blocks=16, max_blocks_per_seq=4, prefill_buckets=(8,),
         prefill_chunk=8, kv_dtype="f32", decode_impl="flash", spec_k=0)
-    S, W, C = model.num_slots, model.max_blocks_per_seq, 8
-    lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
-             jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
-    decode = model._decode.lower(
-        model.params, model._cache, jnp.zeros(S, jnp.int32),
-        jnp.zeros(S, jnp.int32), jnp.ones(S, bool),
-        jnp.zeros((S, W), jnp.int32), jnp.zeros(S, jnp.int32),
-        *lanes).as_text(debug_info=True)
-    chunk = model._prefill_chunked.lower(
-        model.params, model._cache, jnp.zeros((1, C), jnp.int32),
-        jnp.int32(0), jnp.int32(C), jnp.zeros(W, jnp.int32),
-        jnp.float32(0), jnp.int32(0), jnp.float32(1),
-        jnp.uint32(0)).as_text(debug_info=True)
+    S, W = model.num_slots, model.max_blocks_per_seq
+    lanes = (np.zeros(S, np.float32), np.zeros(S, np.int32),
+             np.ones(S, np.float32), np.zeros(S, np.uint32))
+    decode, chunk = _lowered_steps(model)
     assert "jit(_decode_fn)" in decode
     assert "jit(_prefill_chunk_fn)" in chunk
     assert "zoo_mla_decode" in decode and "zoo_paged_decode" not in decode
@@ -351,7 +342,7 @@ def test_the_latent_moe_step_keeps_the_names_and_counts_its_experts():
     tables = np.zeros((S, W), np.int32)
     tables[0, 0] = 3                       # one live lane, one idle
     args = (np.ones(S, np.int32), np.ones(S, bool), tables,
-            np.zeros(S, np.int32), tuple(np.asarray(x) for x in lanes))
+            np.zeros(S, np.int32), lanes)
     first = model.decode_step(None, *args)
     # the counts travel with the tick's batch: the second tick chains on
     # the first, and the first, never read, leaves nothing behind
@@ -384,19 +375,10 @@ def test_the_sparse_and_state_step_keeps_the_names_and_counts_its_pages():
         tiny_minicpm_sala_config(64), num_slots=2, block_size=8,
         num_blocks=16, max_blocks_per_seq=6, prefill_buckets=(8,),
         prefill_chunk=8, kv_dtype="f32", decode_impl="flash", spec_k=0)
-    S, W, C = model.num_slots, model.max_blocks_per_seq, 8
-    lanes = (jnp.zeros(S, jnp.float32), jnp.zeros(S, jnp.int32),
-             jnp.ones(S, jnp.float32), jnp.zeros(S, jnp.uint32))
-    decode = model._decode.lower(
-        model.params, model._cache, jnp.zeros(S, jnp.int32),
-        jnp.zeros(S, jnp.int32), jnp.ones(S, bool),
-        jnp.zeros((S, W), jnp.int32), jnp.zeros(S, jnp.int32),
-        *lanes).as_text(debug_info=True)
-    chunk = model._prefill_chunked.lower(
-        model.params, model._cache, jnp.zeros((1, C), jnp.int32),
-        jnp.int32(0), jnp.int32(C), jnp.zeros(W, jnp.int32),
-        jnp.float32(0), jnp.int32(0), jnp.float32(1),
-        jnp.uint32(0), jnp.int32(1)).as_text(debug_info=True)
+    S, W = model.num_slots, model.max_blocks_per_seq
+    lanes = (np.zeros(S, np.float32), np.zeros(S, np.int32),
+             np.ones(S, np.float32), np.zeros(S, np.uint32))
+    decode, chunk = _lowered_steps(model)
     assert "jit(_decode_fn)" in decode
     assert "jit(_prefill_chunk_fn)" in chunk
     assert "zoo_sparse_decode" in decode and "zoo_lightning_decode" in decode
@@ -421,7 +403,7 @@ def test_the_sparse_and_state_step_keeps_the_names_and_counts_its_pages():
     tables[0, :2] = (3, 4)                 # one live lane, one idle
     args = (np.ones(S, np.int32), np.ones(S, bool), tables,
             np.asarray([9, 0], np.int32),
-            tuple(np.asarray(x) for x in lanes))
+            lanes)
     first = model.decode_step(None, *args)
     model.read_tokens(model.decode_step(first, *args))
     moved = {n: _counter(n) - v for n, v in before.items()}

@@ -259,25 +259,16 @@ def _step_hlo(model, which, params, cache, one):
     def avals(tree):
         return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
 
-    S, W = model.num_slots, model.max_blocks_per_seq
-    lanes = [sds((S,), dt) for dt in (jnp.float32, jnp.int32, jnp.float32,
-                                      jnp.uint32)]
-    lane = [sds((), a.dtype) for a in lanes]
+    # every step takes its host operands as one packed word buffer
     fn, args = {
         "decode": (model._decode_fn, [
-            sds((S,), jnp.int32), sds((S,), jnp.int32),
-            sds((S,), jnp.bool_), sds((S, W), jnp.int32),
-            sds((S,), jnp.int32), *lanes]),
+            sds((model.num_slots,), jnp.int32),
+            model._layouts["decode"].aval(one)]),
         "prefill_chunk": (model._prefill_chunk_fn, [
-            sds((1, model.suffix_chunk_size), jnp.int32),
-            sds((), jnp.int32), sds((), jnp.int32), sds((W,), jnp.int32),
-            *lane]),
+            model._layouts["prefill_chunk"].aval(one)]),
         "prefill": (model._prefill_fn, [
-            sds((1, model.prefill_buckets[-1]), jnp.int32),
-            sds((), jnp.int32), sds((W,), jnp.int32), *lane]),
-        "verify": (model._verify_fn, [
-            sds((S, model.spec_k + 1), jnp.int32), sds((S, W), jnp.int32),
-            sds((S,), jnp.int32), *lanes]),
+            model._prefill_layouts[model.prefill_buckets[-1]].aval(one)]),
+        "verify": (model._verify_fn, [model._layouts["verify"].aval(one)]),
     }[which]
     return jax.jit(fn, donate_argnums=(1,)).lower(
         avals(params), avals(cache), *args).compile().as_text()
@@ -445,17 +436,12 @@ def test_sparse_and_state_step_moves_no_cache(v5e, monkeypatch, which):
         else (a.shape[0], 17664) + a.shape[2:], a.dtype)
         for name, a in model._cache.items()}
     params = narrow_dot_weights(model.params, "tpu", model.DOT_LEAVES)
+    text = _step_hlo(model, which, params, cache, one)
     if which == "decode":
-        text = _step_hlo(model, which, params, cache, one)
         assert "zoo_sparse_decode" in text
         assert "zoo_lightning_decode" in text
     else:
-        # the chunk of a stateful model takes its slot
-        def chunk(*args):
-            return model._prefill_chunk_fn(*args)
-        model_args = _step_args(model, one)
-        text = jax.jit(chunk, donate_argnums=(1,)).lower(
-            *model_args(params, cache)).compile().as_text()
+        # (the chunk of a stateful model reads its slot's word)
         assert "zoo_state_write" in text
     assert model.donated_cache_leaves() == 4
     assert len({p for _, p in input_output_aliases(text)}) == 4
@@ -463,25 +449,6 @@ def test_sparse_and_state_step_moves_no_cache(v5e, monkeypatch, which):
     # 8 MB state into VMEM, one layout on both sides: no relayout; the
     # cell's 604 MB never fits)
     assert cache_moves(text, [a.shape for a in cache.values()])[0] == []
-
-
-def _step_args(model, one):
-    """The chunk executable's operands from shapes, the slot last."""
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
-
-    def avals(tree):
-        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
-
-    def args(params, cache):
-        lane = [sds((), dt) for dt in (jnp.float32, jnp.int32, jnp.float32,
-                                       jnp.uint32)]
-        return (avals(params), avals(cache),
-                sds((1, model.suffix_chunk_size), jnp.int32),
-                sds((), jnp.int32), sds((), jnp.int32),
-                sds((model.max_blocks_per_seq,), jnp.int32), *lane,
-                sds((), jnp.int32))
-    return args
 
 
 def test_flash_attention_compiles_on_a_mesh(v5e, monkeypatch):

@@ -129,6 +129,7 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "zoo_llm_prefix_cache_hit_tokens_total": ("counter", ()),
     "zoo_llm_prefix_cache_miss_tokens_total": ("counter", ()),
     "zoo_llm_host_transfer_bytes_total": ("counter", ("kind",)),
+    "zoo_llm_operand_transfers_total": ("counter", ("call",)),
     "zoo_llm_moe_expert_visits_total": ("counter", ()),
     "zoo_llm_moe_rows_total": ("counter", ()),
     # -- a per-slot recurrent state beside the paged cache, and pages

@@ -2198,6 +2198,9 @@ class LLMEngine:
                         "sparse_pages_attended", "sparse_pages_resident"):
                 if hasattr(self.model, key):
                     out[key] = getattr(self.model, key)
+        if hasattr(self.model, "operand_transfers"):
+            # host -> device operand hand-offs by call: one a dispatch
+            out["operand_transfers"] = dict(self.model.operand_transfers)
         if hasattr(self.model, "compile_counts"):
             out["compiles"] = self.model.compile_counts()
         return out

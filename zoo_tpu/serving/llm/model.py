@@ -111,9 +111,89 @@ SUFFIX_CHUNK_DEFAULT = 64
 # this counter's per-tick delta.
 _host_transfer = counter(
     "zoo_llm_host_transfer_bytes_total",
-    "Bytes read back from the device by the LLM serving hot path, by "
-    "payload kind (tokens = the per-tick slots x 1 id batch)",
+    "Bytes read back from the device (device -> host only; the other "
+    "direction is zoo_llm_operand_transfers_total) by the LLM serving "
+    "hot path, by payload kind (tokens = the per-tick slots x 1 id "
+    "batch)",
     labels=("kind",))
+
+# the other direction: what a dispatch hands the device. One a call.
+_operand_transfers = counter(
+    "zoo_llm_operand_transfers_total",
+    "Host -> device operand hand-offs made by the LLM serving "
+    "executables, by call (decode / prefill_chunk / verify / prefill): "
+    "one packed word buffer a dispatch",
+    labels=("call",))
+
+
+class OperandLayout:
+    """One device call's host operands as ONE buffer of int32 words.
+
+    Every operand of a serving executable but the cache and the
+    previous tick's on-device tokens is small and made on the host:
+    token ids, table rows, positions, sampling lanes. Handed over one
+    by one, each is a transfer of its own and a trip through the
+    interpreter lock, which the connection threads contend for at the
+    very moment a tick's tokens land. So a call's operands lie in one
+    contiguous buffer, field after field in the order given, and cross
+    in one hand-off: :meth:`pack` on the host, :meth:`unpack` at the top
+    of the jitted body.
+
+    A field is ``(name, shape, dtype)``; ``int32`` words are stored as
+    they are, ``float32`` and ``uint32`` by their BITS (a ``view`` here,
+    ``bitcast_convert_type`` there: no value is converted, so nothing
+    can round and a seed over 2**31 survives), ``bool`` as 0 / 1.
+
+    **The buffer is fresh every call, never a ring.** The copy to the
+    device is asynchronous and the CPU backend may alias numpy memory
+    outright, and two ticks and a chunk are in flight by design: a
+    staging buffer filled again for tick N+1 while tick N's copy is
+    under way would corrupt N. jax holds a reference to the array it
+    was handed until the copy is done, so a buffer nobody writes twice
+    is safe on every backend (``tests/test_llm_operands.py``:
+    ``test_operands_in_flight_keep_their_own``)."""
+
+    def __init__(self, fields):
+        self.fields = []
+        at = 0
+        for name, shape, dtype in fields:
+            size = int(np.prod(shape, dtype=np.int64))
+            self.fields.append(
+                (name, tuple(shape), np.dtype(dtype), at, size))
+            at += size
+        self.words = at
+
+    def pack(self, **values) -> np.ndarray:
+        buf = np.empty((self.words,), np.int32)
+        for name, shape, dtype, at, size in self.fields:
+            value = np.asarray(values[name])
+            if value.size != size:
+                raise ValueError(
+                    f"operand {name!r} has shape {value.shape}, the "
+                    f"executable's is {shape}")
+            lane = buf[at:at + size]
+            if dtype != np.bool_:
+                lane = lane.view(dtype)
+            lane[:] = value.reshape(-1)
+        return buf
+
+    def unpack(self, words) -> dict:
+        """Inside a jitted body: the fields back out of the word buffer
+        by static slices, which the compiler fuses into their users."""
+        out = {}
+        for name, shape, dtype, at, size in self.fields:
+            lane = words[at:at + size].reshape(shape)
+            if dtype == np.bool_:
+                lane = lane != 0
+            elif dtype != np.int32:
+                lane = jax.lax.bitcast_convert_type(lane, dtype)
+            out[name] = lane
+        return out
+
+    def aval(self, sharding=None):
+        """The packed operand's shape, for lowering without data."""
+        return jax.ShapeDtypeStruct((self.words,), jnp.int32,
+                                    sharding=sharding)
 
 
 def resolve_decode_impl(impl: Optional[str] = "auto") -> str:
@@ -496,6 +576,35 @@ class PagedDecoderModel:
         # at one (a default-device zeros array would be a distinct
         # sharding layout and compile a second entry under a mesh)
         self._zero_tokens = jnp.zeros((self.num_slots,), jnp.int32)
+        # each executable's host operands as one word buffer: widths
+        # known here, nothing about them is a knob
+        S, W = self.num_slots, self.max_blocks_per_seq
+        lanes = [("temps", (S,), np.float32), ("topks", (S,), np.int32),
+                 ("topps", (S,), np.float32), ("seeds", (S,), np.uint32)]
+        # one sequence's: its table row, its sampling, its slot (read
+        # by an architecture with a per-slot state, a spare word else)
+        row = [("length", (), np.int32), ("block_table", (W,), np.int32),
+               ("temp", (), np.float32), ("topk", (), np.int32),
+               ("topp", (), np.float32), ("seed", (), np.uint32),
+               ("slot", (), np.int32)]
+        batch = [("block_tables", (S, W), np.int32),
+                 ("positions", (S,), np.int32)] + lanes
+        self._layouts = {
+            "decode": OperandLayout(
+                [("host_tokens", (S,), np.int32),
+                 ("use_host", (S,), np.bool_)] + batch),
+            "verify": OperandLayout(
+                [("tokens", (S, self.spec_k + 1), np.int32)] + batch),
+            "prefill_chunk": OperandLayout(
+                [("ids", (1, self.suffix_chunk_size), np.int32),
+                 ("start", (), np.int32)] + row)}
+        # a bucket executable finds its layout by its operand's length
+        self._row_words = OperandLayout(row).words
+        self._prefill_layouts = {
+            b: OperandLayout([("ids", (1, b), np.int32)] + row)
+            for b in self.prefill_buckets}
+        self.operand_transfers = dict.fromkeys(
+            ("decode", "prefill_chunk", "verify", "prefill"), 0)
         if self.mesh is None:
             # the cache pytree is arg 1 → donated: XLA aliases it in
             # place, leaf for leaf, through the layer loop's carry (K/V
@@ -524,24 +633,24 @@ class PagedDecoderModel:
                            for name, arr in self._cache.items()}
             p_sh = shardings_of(self.params, self.mesh)
             # identical donated in/out cache shardings keep the in-place
-            # alias on the mesh; token/table/position/sampling operands
-            # and the emitted token ids are replicated (the host round
-            # trip stays slots x 1)
+            # alias on the mesh; the packed operand buffer (and the
+            # previous tick's tokens) and the emitted token ids are
+            # replicated (the host round trip stays slots x 1)
             self._decode = jax.jit(
                 self._decode_fn, donate_argnums=(1,),
-                in_shardings=(p_sh, cache_sh) + (rep,) * 9,
+                in_shardings=(p_sh, cache_sh, rep, rep),
                 out_shardings=(rep, cache_sh))
             self._prefill = jax.jit(
                 self._prefill_fn, donate_argnums=(1,),
-                in_shardings=(p_sh, cache_sh) + (rep,) * 7,
+                in_shardings=(p_sh, cache_sh, rep),
                 out_shardings=(rep, cache_sh))
             self._prefill_chunked = jax.jit(
                 self._prefill_chunk_fn, donate_argnums=(1,),
-                in_shardings=(p_sh, cache_sh) + (rep,) * 8,
+                in_shardings=(p_sh, cache_sh, rep),
                 out_shardings=(rep, cache_sh))
             self._verify = jax.jit(
                 self._verify_fn, donate_argnums=(1,),
-                in_shardings=(p_sh, cache_sh) + (rep,) * 7,
+                in_shardings=(p_sh, cache_sh, rep),
                 out_shardings=(rep, cache_sh))
             self._copy = jax.jit(
                 self._copy_block_fn, donate_argnums=(0,),
@@ -581,10 +690,10 @@ class PagedDecoderModel:
         return _weight_dot(h, head)
 
     # -- compiled bodies: the skeleton ---------------------------------------
-    def _decode_fn(self, params, cache, prev_tokens, host_tokens,
-                   use_host, block_tables, positions,
-                   temps, topks, topps, seeds):
-        """One token for every slot. The incoming token per slot is
+    def _decode_fn(self, params, cache, prev_tokens, operands):
+        """One token for every slot; ``operands`` is the tick's packed
+        host operands (``_layouts["decode"]``). The incoming token per
+        slot is
         either ``host_tokens`` (freshly admitted stream: the prefill's
         first token) or ``prev_tokens`` — the PREVIOUS tick's on-device
         output, so back-to-back ticks chain without a host round trip.
@@ -593,7 +702,9 @@ class PagedDecoderModel:
         (device) and the updated cache pytree, and after them the
         arrays of the tick's ``aux`` counts where the architecture has
         any."""
-        tokens = jnp.where(use_host, host_tokens, prev_tokens)
+        op = self._layouts["decode"].unpack(operands)
+        block_tables, positions = op["block_tables"], op["positions"]
+        tokens = jnp.where(op["use_host"], op["host_tokens"], prev_tokens)
         h = jnp.take(params["embed"], tokens, axis=0)        # (S, hidden)
         at = {"cos": jnp.take(self._cos, positions, axis=0),  # (S, D/2)
               "sin": jnp.take(self._sin, positions, axis=0),
@@ -608,20 +719,24 @@ class PagedDecoderModel:
                                      self._attend_decode, at)
         logits = self._lm_head(params, h)                     # (S, vocab)
         # the token being drawn sits at sequence index position+1
-        nxt = _sample_tokens(logits, temps, topks, topps, seeds,
-                             positions + 1)
+        nxt = _sample_tokens(logits, op["temps"], op["topks"],
+                             op["topps"], op["seeds"], positions + 1)
         return (nxt, cache, *aux)
 
-    def _prefill_fn(self, params, cache, ids, length, block_table,
-                    temp, topk, topp, seed, slot=None):
+    def _prefill_fn(self, params, cache, operands):
         """Causal forward over one padded prompt (1, L_bucket): scatter
         the prompt's cache rows into the paged cache and return the
-        sampled first generated token. ``length`` is the true prompt
-        length (dynamic); pad positions write to the trash block and
-        are never attended by real tokens (they sit in the causal
-        future). ``slot`` is the sequence's slot, for an architecture
-        that keeps a per-slot state beside the paged leaves (the others
-        are never handed one)."""
+        sampled first generated token. ``operands`` is the packed
+        buffer of the bucket's layout, found by its length. ``length``
+        is the true prompt length (dynamic); pad positions write to the
+        trash block and are never attended by real tokens (they sit in
+        the causal future). ``slot`` is the sequence's slot, read by an
+        architecture that keeps a per-slot state beside the paged
+        leaves and by no other."""
+        op = self._prefill_layouts[
+            operands.shape[0] - self._row_words].unpack(operands)
+        ids, length, block_table = op["ids"], op["length"], \
+            op["block_table"]
         L = ids.shape[1]
         pos = jnp.arange(L)
         # pad positions → trash block 0 (their rows must not land in the
@@ -632,19 +747,25 @@ class PagedDecoderModel:
                                block_table[pos // self.block_size], 0),
               "off": pos % self.block_size,
               "tables": block_table[None], "pos": pos[None],
-              "real": (pos < length)[None], "slot": slot}
-        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
+              "real": (pos < length)[None], "slot": self._slot_of(op)}
+        h = jnp.take(params["embed"], ids, axis=0)
         h, cache, _ = self._layers(params, cache, h,
                                    self._attend_bucket, at)
         logits = self._lm_head(params, h)                  # (1, L, vocab)
         last = jnp.take(logits[0], length - 1, axis=0)     # (vocab,)
         # first generated token = sequence index ``length``
-        tok = _sample_row(last, temp, topk, topp, seed, length)
+        tok = _sample_row(last, op["temp"], op["topk"], op["topp"],
+                          op["seed"], length)
         return tok, cache
 
-    def _prefill_chunk_fn(self, params, cache, ids, start, length,
-                          block_table, temp, topk, topp, seed, slot=None):
-        """One fixed-size CHUNK of a prompt: write the chunk's cache
+    def _slot_of(self, op):
+        """A prefill's slot operand, for an architecture that has a use
+        for it."""
+        return op["slot"] if self.UNPAGED_LEAVES else None
+
+    def _prefill_chunk_fn(self, params, cache, operands):
+        """One fixed-size CHUNK of a prompt (``operands``: the packed
+        buffer of ``_layouts["prefill_chunk"]``): write the chunk's cache
         rows through the block table at positions ``start..start+C-1``
         and attend each chunk token causally over everything already
         resident (earlier chunks included) — the same math as the
@@ -652,6 +773,9 @@ class PagedDecoderModel:
         Returns the sampled first generated token, meaningful only on
         the chunk that contains the prompt's last real token (earlier
         chunks sample from a mid-prompt row the engine discards)."""
+        op = self._layouts["prefill_chunk"].unpack(operands)
+        ids, start, length, block_table = op["ids"], op["start"], \
+            op["length"], op["block_table"]
         C = ids.shape[1]
         ctx = self.max_blocks_per_seq * self.block_size
         pos = start + jnp.arange(C)                       # (C,)
@@ -671,20 +795,21 @@ class PagedDecoderModel:
                                block_table[pos // self.block_size], 0),
               "off": pos % self.block_size,
               "tables": block_table[None], "pos": pos[None],
-              "real": real[None], "slot": slot}
-        h = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
+              "real": real[None], "slot": self._slot_of(op)}
+        h = jnp.take(params["embed"], ids, axis=0)
         h, cache, _ = self._layers(params, cache, h,
                                    self._attend_chunk, at)
         logits = self._lm_head(params, h)                 # (1, C, vocab)
         last = jnp.take(logits[0],
                         jnp.clip(length - 1 - start, 0, C - 1), axis=0)
-        tok = _sample_row(last, temp, topk, topp, seed, length)
+        tok = _sample_row(last, op["temp"], op["topk"], op["topp"],
+                          op["seed"], length)
         return tok, cache
 
-    def _verify_fn(self, params, cache, tokens, block_tables,
-                   positions, temps, topks, topps, seeds):
+    def _verify_fn(self, params, cache, operands):
         """Speculative-decode VERIFY: score ``spec_k + 1`` candidate
-        tokens per slot in ONE device call. Row 0 of ``tokens`` (S, T)
+        tokens per slot in ONE device call (``operands``: the packed
+        buffer of ``_layouts["verify"]``). Row 0 of ``tokens`` (S, T)
         is the slot's incoming token (the last emitted one), rows 1..
         are the drafter's proposals; row ``j`` is written through the
         block table at cache index ``positions[s] + j`` and attends
@@ -699,6 +824,9 @@ class PagedDecoderModel:
         rollback is a pure length reset. Rows past the pageable
         context write to the trash block (their outputs are never
         accepted; the engine caps draft length to owned blocks)."""
+        op = self._layouts["verify"].unpack(operands)
+        tokens, block_tables, positions = op["tokens"], \
+            op["block_tables"], op["positions"]
         S, T = tokens.shape
         ctx = self.max_blocks_per_seq * self.block_size
         raw = positions[:, None] + jnp.arange(T)[None, :]     # (S, T)
@@ -722,8 +850,8 @@ class PagedDecoderModel:
         logits = self._lm_head(params, h)               # (S, T, vocab)
         nxt = _sample_tokens(
             logits.reshape(S * T, -1),
-            jnp.repeat(temps, T), jnp.repeat(topks, T),
-            jnp.repeat(topps, T), jnp.repeat(seeds, T),
+            jnp.repeat(op["temps"], T), jnp.repeat(op["topks"], T),
+            jnp.repeat(op["topps"], T), jnp.repeat(op["seeds"], T),
             (raw + 1).reshape(S * T)).reshape(S, T)
         return nxt, cache
 
@@ -734,6 +862,24 @@ class PagedDecoderModel:
             return GREEDY
         t, k, p, s = sampling
         return float(t), int(k), float(p), int(s) & 0xFFFFFFFF
+
+    def _hand_off(self, call: str, layout: OperandLayout, **values):
+        """A dispatch's host operands, packed for their ONE hand-off:
+        the numpy buffer goes to the jitted call as it is. Under the
+        dispatch lock."""
+        buf = layout.pack(**values)
+        self.operand_transfers[call] += 1
+        _operand_transfers.labels(call=call).inc()
+        return buf
+
+    def _row_operands(self, n, block_table_row, sampling, slot) -> dict:
+        """The operands every prefill of one sequence has."""
+        bt = np.asarray(block_table_row, np.int32)
+        if bt.shape != (self.max_blocks_per_seq,):
+            raise ValueError("block_table_row has the wrong width")
+        t, k, p, s = self._sampling_tuple(sampling)
+        return dict(length=n, block_table=bt, temp=t, topk=k, topp=p,
+                    seed=s, slot=0 if slot is None else int(slot))
 
     def prefill(self, prompt: np.ndarray, block_table_row: np.ndarray,
                 sampling=None, slot: Optional[int] = None) -> int:
@@ -751,23 +897,15 @@ class PagedDecoderModel:
                 f"bucket ({self.prefill_buckets[-1]})")
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :n] = prompt
-        bt = np.asarray(block_table_row, np.int32)
-        if bt.shape != (self.max_blocks_per_seq,):
-            raise ValueError("block_table_row has the wrong width")
-        t, k, p, s = self._sampling_tuple(sampling)
+        row = self._row_operands(n, block_table_row, sampling, slot)
         with self._lock:
             tok, self._cache = self._prefill(
-                self.params, self._cache, jnp.asarray(ids),
-                jnp.int32(n), jnp.asarray(bt), jnp.float32(t),
-                jnp.int32(k), jnp.float32(p), jnp.uint32(s),
-                *self._slot_operand(slot))
+                self.params, self._cache, self._hand_off(
+                    "prefill", self._prefill_layouts[bucket], ids=ids,
+                    **row))
             out = int(tok)
         _host_transfer.labels(kind="prefill").inc(4)
         return out
-
-    @staticmethod
-    def _slot_operand(slot) -> tuple:
-        return () if slot is None else (jnp.int32(slot),)
 
     def prefill_chunk(self, chunk: np.ndarray, start: int,
                       total_len: int, block_table_row: np.ndarray,
@@ -789,17 +927,13 @@ class PagedDecoderModel:
         with span("llm.model.prefill_chunk"):
             ids = np.zeros((1, C), np.int32)
             ids[0, :n] = chunk
-            bt = np.asarray(block_table_row, np.int32)
-            if bt.shape != (self.max_blocks_per_seq,):
-                raise ValueError("block_table_row has the wrong width")
-            t, k, p, s = self._sampling_tuple(sampling)
+            row = self._row_operands(total_len, block_table_row,
+                                     sampling, slot)
             with self._lock:
                 tok, self._cache = self._prefill_chunked(
-                    self.params, self._cache, jnp.asarray(ids),
-                    jnp.int32(start), jnp.int32(total_len),
-                    jnp.asarray(bt), jnp.float32(t), jnp.int32(k),
-                    jnp.float32(p), jnp.uint32(s),
-                    *self._slot_operand(slot))
+                    self.params, self._cache, self._hand_off(
+                        "prefill_chunk", self._layouts["prefill_chunk"],
+                        ids=ids, start=start, **row))
             _host_transfer.labels(kind="prefill").inc(4)
         return tok
 
@@ -873,19 +1007,14 @@ class PagedDecoderModel:
             elif isinstance(prev_batch, _TickBatch):
                 prev_batch = prev_batch.tokens
             with span("llm.model.h2d"):
-                operands = (
-                    jnp.asarray(prev_batch, jnp.int32),
-                    jnp.asarray(host_tokens, jnp.int32),
-                    jnp.asarray(use_host, bool),
-                    jnp.asarray(block_tables, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(topks, jnp.int32),
-                    jnp.asarray(topps, jnp.float32),
-                    jnp.asarray(seeds, jnp.uint32))
+                operands = self._hand_off(
+                    "decode", self._layouts["decode"],
+                    host_tokens=host_tokens, use_host=use_host,
+                    block_tables=block_tables, positions=positions,
+                    temps=temps, topks=topks, topps=topps, seeds=seeds)
             with span("llm.model.launch"):
                 out, self._cache, *aux = self._decode(
-                    self.params, self._cache, *operands)
+                    self.params, self._cache, prev_batch, operands)
             # the tick's device counts travel with its token batch and
             # are read with it (:meth:`read_tokens`); a tick that is
             # dropped unread takes them along
@@ -913,13 +1042,10 @@ class PagedDecoderModel:
         temps, topks, topps, seeds = sampling_lanes
         with self._lock:
             out, self._cache = self._verify(
-                self.params, self._cache, jnp.asarray(tokens),
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(temps, jnp.float32),
-                jnp.asarray(topks, jnp.int32),
-                jnp.asarray(topps, jnp.float32),
-                jnp.asarray(seeds, jnp.uint32))
+                self.params, self._cache, self._hand_off(
+                    "verify", self._layouts["verify"], tokens=tokens,
+                    block_tables=block_tables, positions=positions,
+                    temps=temps, topks=topks, topps=topps, seeds=seeds))
             return out
 
     def read_tokens(self, batch) -> np.ndarray:
@@ -967,35 +1093,28 @@ class PagedDecoderModel:
 
     def compiled_hlo(self, which: str = "decode") -> Optional[str]:
         """Optimized HLO text of the ``decode`` or ``verify``
-        executable, lowered with this model's exact census signature
-        (and explicit shardings under tp=N) — the input to the
-        zoo-lint donation / host-transfer / sharding checks. Returns
-        None when the executable does not exist (``verify`` with
-        spec_k=0)."""
-        S = self.num_slots
-
-        def sds(shape, dt):
-            return jax.ShapeDtypeStruct(shape, dt)
-
+        executable, lowered with this model's exact census signature:
+        weights, the donated cache, (decode) the previous tick's
+        ``(slots,)`` tokens and the ONE packed operand buffer of
+        ``_layouts[which]`` (and explicit shardings under tp=N) — the
+        input to the zoo-lint donation / host-transfer / sharding
+        checks. Returns None when the executable does not exist
+        (``verify`` with spec_k=0)."""
         def avals(tree):
             return jax.tree_util.tree_map(
-                lambda x: sds(jnp.shape(x), x.dtype), tree)
+                lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype),
+                tree)
 
-        lanes = (sds((S,), jnp.float32), sds((S,), jnp.int32),
-                 sds((S,), jnp.float32), sds((S,), jnp.uint32))
-        tables = sds((S, self.max_blocks_per_seq), jnp.int32)
-        positions = sds((S,), jnp.int32)
         if which == "decode":
             args = (avals(self.params), avals(self._cache),
-                    sds((S,), jnp.int32), sds((S,), jnp.int32),
-                    sds((S,), jnp.bool_), tables, positions, *lanes)
+                    avals(self._zero_tokens),
+                    self._layouts["decode"].aval())
             fn = self._decode
         elif which == "verify":
             if self.spec_k < 1:
                 return None
             args = (avals(self.params), avals(self._cache),
-                    sds((S, self.spec_k + 1), jnp.int32), tables,
-                    positions, *lanes)
+                    self._layouts["verify"].aval())
             fn = self._verify
         else:
             raise ValueError(f"unknown executable {which!r} "

@@ -225,9 +225,14 @@ def test_exporter_metrics_healthz_cluster(tmp_path, monkeypatch):
         assert code == 200
         assert json.loads(body)["heartbeat_age"] < 5
 
-        # stale heartbeat: 503, same staleness rule ProcessMonitor uses
-        with open(hb, "w") as f:
-            f.write(repr(time.monotonic() - 3600))
+        # stale heartbeat: 503, same staleness rule ProcessMonitor uses.
+        # A foreign (empty) payload stamped an hour back by its mtime:
+        # a monotonic stamp an hour old is negative on a host up for
+        # less than an hour, and heartbeat_age rejects it as junk
+        with open(hb, "w"):
+            pass
+        stale = time.time() - 3600
+        os.utime(hb, (stale, stale))
         with pytest.raises(urllib.error.HTTPError) as ei:
             _get(ex.url + "/healthz")
         assert ei.value.code == 503

@@ -7,6 +7,8 @@ import glob
 import inspect
 import os
 import statistics
+import subprocess
+import sys
 import threading
 import time
 
@@ -18,7 +20,11 @@ import jax.numpy as jnp
 
 from zoo_tpu.obs import tracing
 from zoo_tpu.obs.metrics import get_registry
-from zoo_tpu.serving.llm.engine import TICK_LEAF_SPANS, LLMEngine
+from zoo_tpu.serving.llm.engine import (
+    HOST_LEAF_SPANS,
+    TICK_LEAF_SPANS,
+    LLMEngine,
+)
 from zoo_tpu.serving.llm.synthetic import SyntheticLLMModel
 from zoo_tpu.util.resilience import clear_faults, inject
 
@@ -69,12 +75,19 @@ def test_emit_span_reaches_the_ring_only_with_a_perf_counter_start():
     assert s[1:3] == (t, 0.25) and s[4] == {"rid": "y"}
 
 
+def _fill(name, n):
+    """``n`` spans of ``name`` through the ring's recording path (a whole
+    ``span()`` costs twice as much), each started now."""
+    record, now = tracing._record, time.perf_counter
+    for _ in range(n):
+        record(name, now(), 0.0, None)
+
+
 def test_ring_state_shows_a_wrap():
     before = tracing.ring_state()
-    assert before["capacity"] == tracing.RING_CAPACITY
+    assert before["capacity"] == tracing.RING_CAPACITY == 1 << 20
     t = time.perf_counter()
-    for _ in range(tracing.RING_CAPACITY + 10):
-        tracing.emit_span("t.wrap", 0.0, 0.0, t0=time.perf_counter())
+    _fill("t.wrap", tracing.RING_CAPACITY + 10)
     after = tracing.ring_state()
     assert after["written"] == before["written"] \
         + tracing.RING_CAPACITY + 10
@@ -82,6 +95,82 @@ def test_ring_state_shows_a_wrap():
     # the oldest spans are gone: what is left began after the flood did
     assert after["oldest_t0"] > t
     assert len(tracing.recent_spans()) == tracing.RING_CAPACITY
+
+
+def test_a_copy_taken_while_a_thread_writes_holds_whole_spans():
+    """A span written while the ring is copied is whole in the copy or
+    absent: never a record beside another span's attrs, never half a
+    record. The writer overwrites the oldest slots of a full ring while
+    the main thread copies all of it and its tail, the interpreter
+    switching threads every 100 us."""
+    _fill("t.torn.old", tracing.RING_CAPACITY)
+    stop = threading.Event()
+
+    def write():
+        i = 0
+        while not stop.is_set():
+            i += 1
+            tracing._record("t.torn.new", float(i), 2.0 * i,
+                            {"i": i} if i % 3 == 0 else None)
+
+    writer = threading.Thread(target=write)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    writer.start()
+    try:
+        copies = [tracing.recent_spans() for _ in range(2)]
+        copies += [tracing.last_spans(4096) for _ in range(50)]
+    finally:
+        stop.set()
+        writer.join(timeout=30)
+        sys.setswitchinterval(switch)
+    assert not writer.is_alive()
+    seen = 0
+    for copy in copies:
+        last = 0.0
+        for name, t0, dur, tid, attrs in copy:
+            if name == "t.torn.old":
+                assert dur == 0.0 and attrs is None
+                continue
+            assert name == "t.torn.new" and tid == writer.ident
+            assert dur == 2.0 * t0 and t0 > last
+            assert attrs == ({"i": int(t0)} if int(t0) % 3 == 0 else None)
+            last = t0
+            seen += 1
+    assert seen > 0
+
+
+def test_a_span_being_written_or_overwritten_during_a_copy_is_absent(
+        monkeypatch):
+    """The two races of a copy, made to happen: a span whose number is
+    claimed but whose record is not yet written, and spans that
+    overwrite the oldest slots between the copy of the records and the
+    copy of their attrs. Neither leaves a span in the copy, and no
+    record comes back beside another span's attrs."""
+    next(tracing._written)              # claimed, never written
+    with tracing.span("t.race.after"):
+        pass
+    assert [s[0] for s in tracing.last_spans(2)][-1] == "t.race.after"
+    assert len(tracing.last_spans(2)) == 1
+    _fill("t.race.old", tracing.RING_CAPACITY)
+
+    class Overwritten:
+        """The ring's buffer, with ten spans written by 'another thread'
+        right after its records are copied."""
+        def __init__(self, buf):
+            self.buf = buf
+
+        def __getitem__(self, key):
+            got = self.buf[key]
+            monkeypatch.setattr(tracing, "_buf", self.buf)
+            for j in range(10):
+                tracing._record("t.race.new", 0.0, 0.0, {"j": j})
+            return got
+
+    monkeypatch.setattr(tracing, "_buf", Overwritten(tracing._buf))
+    held = tracing.recent_spans()
+    assert all(s[0] == "t.race.old" and s[4] is None for s in held)
+    assert len(held) == tracing.RING_CAPACITY - 10
 
 
 def test_a_span_costs_microseconds_with_no_sink():
@@ -94,6 +183,57 @@ def test_a_span_costs_microseconds_with_no_sink():
             pass
         costs.append(time.perf_counter() - t)
     assert statistics.median(costs) < 20e-6, statistics.median(costs)
+
+
+def test_a_host_span_costs_microseconds_with_no_sink():
+    """The same floor for a span that also reads the thread's CPU clock
+    twice and adds to two counters (measured ~3 us)."""
+    costs = []
+    for _ in range(5000):
+        t = time.perf_counter()
+        with tracing.host_span("t.cost.host"):
+            pass
+        costs.append(time.perf_counter() - t)
+    assert statistics.median(costs) < 20e-6, statistics.median(costs)
+
+
+def _host_seconds(span_name):
+    """(CPU seconds, off-CPU seconds) the two counters hold for one
+    span name, or None where a family has no such child."""
+    got = {}
+    for c in get_registry().snapshot()["counters"]:
+        if c["labels"].get("span") == span_name and c["name"] in (
+                "zoo_llm_host_cpu_seconds_total",
+                "zoo_llm_host_offcpu_seconds_total"):
+            got[c["name"]] = c["value"]
+    if len(got) < 2:
+        return None
+    return (got["zoo_llm_host_cpu_seconds_total"],
+            got["zoo_llm_host_offcpu_seconds_total"])
+
+
+@pytest.mark.parametrize("work", ["sleep", "spin"])
+def test_a_host_span_counts_its_seconds_on_and_off_the_cpu(work):
+    """A leaf that sleeps is off the CPU and a leaf that spins is on it,
+    each within 20% of its wall time; the ring keeps the CPU seconds
+    as attr ``cpu_s`` and the counters add the same."""
+    name = "t.host." + work
+    tracing.account_host_spans(name)
+    assert _host_seconds(name) == (0.0, 0.0)
+    t = time.perf_counter()
+    with tracing.host_span(name, k=1):
+        if work == "sleep":
+            time.sleep(0.2)
+        else:
+            while time.perf_counter() - t < 0.2:
+                pass
+    ((_, _, wall, _, attrs),) = tracing.recent_spans(name, since=t)
+    cpu = attrs.pop("cpu_s")
+    assert attrs == {"k": 1} and 0.15 <= wall < 1.0
+    on, off = _host_seconds(name)
+    assert on == pytest.approx(cpu, rel=1e-6)
+    assert on + off == pytest.approx(wall, rel=1e-6)
+    assert (off if work == "sleep" else on) >= 0.8 * wall, (on, off)
 
 
 # ------------------------------------------------- the engine's tick, a request
@@ -430,6 +570,62 @@ def test_the_attributes_the_harness_wraps_are_there():
     assert "trace_id" in inspect.signature(LLMEngine.submit).parameters
 
 
+def test_the_host_leaves_never_overlap_and_the_counters_add_them_up():
+    """On a stepped engine (``tests/llm_tick.py``) over a real paged
+    model every leaf of ``HOST_LEAF_SPANS`` runs, no two of them
+    overlap, and each counter grows by the CPU and off-CPU seconds the
+    ring holds of that leaf."""
+    from llm_tick import tick
+    eng = LLMEngine(_tiny_paged_model(spec_k=0))
+    assert eng.model.prefill_chunk_size == 8
+    before = {n: _host_seconds(n) for n in HOST_LEAF_SPANS}
+    assert None not in before.values()
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(0)
+    handles = [eng.submit(rng.integers(1, 60, 11), 4) for _ in range(2)]
+    while not all(h.done for h in handles):
+        tick(eng)
+    spans = [s for s in tracing.recent_spans(since=t0)
+             if s[0] in HOST_LEAF_SPANS]
+    assert {s[0] for s in spans} == set(HOST_LEAF_SPANS)
+    ivs = sorted((s[1], s[1] + s[2], s[0]) for s in spans)
+    for (_, end, a), (start, _, b) in zip(ivs, ivs[1:]):
+        assert start >= end, f"{a} overlaps {b}"
+    for name in HOST_LEAF_SPANS:
+        mine = [s for s in spans if s[0] == name]
+        cpu = sum(s[4]["cpu_s"] for s in mine)
+        wall = sum(s[2] for s in mine)
+        (on0, off0), (on1, off1) = before[name], _host_seconds(name)
+        assert on1 - on0 == pytest.approx(cpu, rel=1e-5, abs=1e-9)
+        assert on1 - on0 + off1 - off0 == pytest.approx(
+            wall, rel=1e-5, abs=1e-9)
+
+
+def test_an_engine_that_does_nothing_reads_zero_host_seconds():
+    """The counters' children exist from the engine's construction on,
+    so a process that has run no leaf reads 0 (and not an absent
+    family, which is what a program without them reads)."""
+    code = (
+        "from zoo_tpu.obs.metrics import get_registry\n"
+        "from zoo_tpu.serving.llm.engine import HOST_LEAF_SPANS, LLMEngine\n"
+        "from zoo_tpu.serving.llm.synthetic import SyntheticLLMModel\n"
+        "fams = ('zoo_llm_host_cpu_seconds_total',\n"
+        "        'zoo_llm_host_offcpu_seconds_total')\n"
+        "def got():\n"
+        "    return sorted((c['name'], c['labels']['span'], c['value'])\n"
+        "                  for c in get_registry().snapshot()['counters']\n"
+        "                  if c['name'] in fams)\n"
+        "assert got() == [], got()\n"
+        "LLMEngine(SyntheticLLMModel(num_slots=1, block_size=4,\n"
+        "                            num_blocks=8, max_blocks_per_seq=4))\n"
+        "assert got() == sorted((f, s, 0.0) for f in fams\n"
+        "                       for s in HOST_LEAF_SPANS), got()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   cwd=os.path.dirname(os.path.dirname(__file__)),
+                   timeout=120)
+
+
 # ------------------------------------------------------ the ring's operator
 
 def test_the_postmortem_bundle_holds_the_last_spans():
@@ -443,6 +639,35 @@ def test_the_postmortem_bundle_holds_the_last_spans():
     assert last["name"] == "t.bundle.last" and last["attrs"] == {"step": 7}
     assert last["ago_s"] >= last["dur_s"] >= 0
     assert "active_spans" in bundle
+
+
+def test_the_bundle_reads_the_ring_s_tail_alone(monkeypatch):
+    """``last_spans`` gives the newest spans as ``recent_spans`` does
+    and copies their slots alone: with 2**20 slots a postmortem bundle
+    must not build a million tuples."""
+    from zoo_tpu.obs.flight import RECENT_SPANS, _recent_spans_tail
+    for i in range(3):
+        with tracing.span("t.tail", i=i):
+            pass
+    assert tracing.last_spans(3) == tracing.recent_spans()[-3:]
+    assert [s[4] for s in tracing.last_spans(2)] == [{"i": 1}, {"i": 2}]
+    assert tracing.last_spans(0) == []
+    copied = []
+
+    class Watched:
+        def __init__(self, buf):
+            self.buf = buf
+
+        def __getitem__(self, key):
+            got = self.buf[key]
+            copied.append(len(got))
+            return got
+
+    monkeypatch.setattr(tracing, "_buf", Watched(tracing._buf))
+    tail = _recent_spans_tail()
+    assert 0 < sum(copied) <= RECENT_SPANS * tracing._REC.size
+    assert tail[-1]["name"] == "t.tail" and tail[-1]["attrs"] == {"i": 2}
+    assert len(tail) == min(RECENT_SPANS, tracing.ring_state()["written"])
 
 
 def test_the_timeline_says_whether_a_first_token_queued_or_prefilled(

@@ -71,6 +71,9 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- compiles (obs.tracing.watch_compiles) ------------------------------
     "zoo_jit_compiles_total": ("counter", ()),
     "zoo_jit_compile_seconds_total": ("counter", ()),
+    # -- host work on and off the CPU (obs.tracing.host_span) ---------------
+    "zoo_llm_host_cpu_seconds_total": ("counter", ("span",)),
+    "zoo_llm_host_offcpu_seconds_total": ("counter", ("span",)),
     # -- serving (single server) -------------------------------------------
     "zoo_serving_queue_depth": ("gauge", ()),
     "zoo_serving_batch_occupancy": ("histogram", ()),

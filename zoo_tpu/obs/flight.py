@@ -43,7 +43,7 @@ import time
 from typing import Dict, List, Optional
 
 from zoo_tpu.obs.metrics import counter, get_registry
-from zoo_tpu.obs.tracing import active_spans, iter_jsonl, recent_spans
+from zoo_tpu.obs.tracing import active_spans, iter_jsonl, last_spans
 
 __all__ = [
     "FlightRecorder", "flight_recorder", "record_event",
@@ -78,11 +78,12 @@ RECENT_SPANS = 2048
 def _recent_spans_tail() -> List[dict]:
     """The tail of the span ring as JSON: ``ago_s`` is how long before
     this dump the span STARTED (the ring's clock is ``perf_counter``,
-    which means nothing outside the process)."""
+    which means nothing outside the process). Copies those slots of the
+    ring alone, not the whole ring."""
     now = time.perf_counter()
     return [{"name": name, "ago_s": now - t0, "dur_s": dur, "tid": tid,
              "attrs": attrs}
-            for name, t0, dur, tid, attrs in recent_spans()[-RECENT_SPANS:]]
+            for name, t0, dur, tid, attrs in last_spans(RECENT_SPANS)]
 
 
 class FlightRecorder:

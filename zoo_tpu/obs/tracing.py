@@ -20,21 +20,24 @@ The JSONL file is off until a sink exists: call :func:`trace_to` or set
 times itself with no configuration (docs/observability.md "The span
 ring"):
 
-* a process-wide bounded ring of the last ``RING_CAPACITY`` finished
-  spans, ``(name, t0, dur_s, thread_id, attrs_or_None)`` with ``t0`` on
-  ``time.perf_counter()`` — read it with :func:`recent_spans`; the
-  flight recorder dumps its tail into every postmortem bundle and the
-  benchmark's ``program_spans`` readers turn it into per-layer metrics;
+* a process-wide bounded ring of the last ``RING_CAPACITY`` (2**20)
+  finished spans, ``(name, t0, dur_s, thread_id, attrs_or_None)`` with
+  ``t0`` on ``time.perf_counter()`` — read it with :func:`recent_spans`
+  (its tail with :func:`last_spans`); the flight recorder dumps its tail
+  into every postmortem bundle and the benchmark's ``program_spans``
+  readers turn it into per-layer metrics;
 * while jax is imported, ``jax.profiler.TraceAnnotation("zoo:" +
   name)`` — a no-op outside a profiler session, and inside one the span
   lies in the ``.xplane.pb`` on the profiler's clock beside the
   device's operations.
 
-A :func:`span` with no JSONL sink costs two clock reads, one ring append
+A :func:`span` with no JSONL sink costs two clock reads, one ring write
 and the no-op annotation (under 3 us; ``tests/test_obs_spans.py`` holds
-it under 20) — safe to leave in hot paths. :func:`watch_compiles` puts
-every XLA compile and lowering into the same ring as ``jit.compile`` /
-``jit.lower`` spans.
+it under 20) — safe to leave in hot paths. :class:`host_span` is the
+opt-in variant for host work that never waits for the device: it also
+reads the thread's CPU clock and counts the span's seconds on and off
+the CPU. :func:`watch_compiles` puts every XLA compile and lowering into
+the same ring as ``jit.compile`` / ``jit.lower`` spans.
 
 Request-scoped tracing (docs/observability.md): a serving client mints
 one trace id per logical request and it rides the wire; the server
@@ -50,20 +53,23 @@ scheduler thread, the batcher) where thread-local nesting cannot apply.
 
 from __future__ import annotations
 
-import collections
 import contextlib
 import itertools
 import json
 import logging
+import math
+import mmap
 import os
 import socket
+import struct
 import sys
 import threading
 import time
 import uuid
-from typing import Deque, Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 from zoo_tpu.obs.coordination import coordination_client
+from zoo_tpu.obs.metrics import counter
 
 __all__ = [
     "span", "trace_to", "stop_tracing", "tracing_enabled",
@@ -72,7 +78,8 @@ __all__ = [
     "trace_context", "ambient_trace_id", "current_span_id",
     "new_trace_id", "emit_span", "emit_event", "active_spans",
     "iter_jsonl", "trace_file_path",
-    "recent_spans", "ring_state", "watch_compiles", "RING_CAPACITY",
+    "recent_spans", "last_spans", "ring_state", "watch_compiles",
+    "RING_CAPACITY", "host_span", "account_host_spans",
 ]
 
 logger = logging.getLogger(__name__)
@@ -92,20 +99,61 @@ _tls = threading.local()  # .stack: span-id stack per thread
 _live_spans: dict = {}
 _live_lock = threading.Lock()
 
-# the always-on ring: finished spans only, appended at span END (so the
-# ring is ordered by end time). deque.append and next(count) are single
-# C calls — no lock, no allocation beyond the tuple.
-RING_CAPACITY = 65536
-_ring: Deque[tuple] = collections.deque(maxlen=RING_CAPACITY)
-_written = itertools.count()
+# the always-on ring: finished spans only, written at span END (so the
+# ring is ordered by end time) into one preallocated buffer of records
+# of 36 bytes: key = sequence number << 16 | name index, t0, dur_s,
+# thread id, cpu_s (float32; NaN but for a host_span). A write claims
+# its sequence number with next() and packs the whole record with ONE
+# struct call, so with no lock no reader ever sees half of one; attrs
+# (None for nearly every span) sit in a side list, set before the
+# record. 2**20 records are 37.7 MB and the list 8.4 MB; the buffer's
+# pages cost nothing until a span is written into them.
+RING_CAPACITY = 1 << 20
+_MASK = RING_CAPACITY - 1
+_NAME_BITS = 16
+_NAME_MASK = (1 << _NAME_BITS) - 1
+_REC = struct.Struct("<QddQf")
+_RECSZ = _REC.size
+_buf = mmap.mmap(-1, _RECSZ * RING_CAPACITY, flags=mmap.MAP_PRIVATE)
+_attrs: List[Optional[dict]] = [None] * RING_CAPACITY
+_pack = _REC.pack_into
+_written = itertools.count(1)   # the next sequence number; key 0: empty
+_names: List[str] = []          # name index -> name
+_name_ids: Dict[str, int] = {}
+_NO_CPU = math.nan
 _get_ident = threading.get_ident
 _perf_counter = time.perf_counter
+_thread_time = time.thread_time
 _TraceAnnotation = None     # jax.profiler.TraceAnnotation, once jax is in
 
 
-def _record(name: str, t0: float, dur_s: float, attrs: Optional[dict]):
-    _ring.append((name, t0, dur_s, _get_ident(), attrs))
-    next(_written)
+def _record(name: str, t0: float, dur_s: float, attrs: Optional[dict],
+            cpu_s: float = _NO_CPU):
+    n = next(_written)
+    i = _name_ids.get(name)
+    if i is None:
+        i = _name_id(name)
+    s = n & _MASK
+    _attrs[s] = attrs
+    _pack(_buf, s * _RECSZ, n << _NAME_BITS | i, t0, dur_s, _get_ident(),
+          cpu_s)
+
+
+def _name_id(name: str) -> int:
+    """The ring's index of ``name``; past 65,535 names every new one
+    shares the last index, read back as ``ring.other``."""
+    with _lock:
+        i = _name_ids.get(name)
+        if i is None:
+            i = len(_names)
+            if i < _NAME_MASK:
+                _names.append(name)
+                _name_ids[name] = i
+            else:
+                if i == _NAME_MASK:
+                    _names.append("ring.other")
+                i = _NAME_MASK
+        return i
 
 
 def _annotation(name: str):
@@ -383,6 +431,69 @@ class span:
                     "dur_s": dur, "ok": ok})
 
 
+# the opt-in accounting of host work (docs/observability.md "Host CPU
+# accounting"): seconds a host_span's thread ran, and seconds it spent
+# off the CPU (the wall time less those), by span name
+_host_cpu = counter(
+    "zoo_llm_host_cpu_seconds_total",
+    "CPU seconds of the host_span leaves that never wait for the "
+    "device, by span: the interpreter work of the serving host's pass",
+    labels=("span",))
+_host_offcpu = counter(
+    "zoo_llm_host_offcpu_seconds_total",
+    "Wall seconds less CPU seconds of the same leaves, by span: the "
+    "thread waited off the CPU (for the interpreter lock, a lock, or "
+    "a block inside the runtime's enqueue)", labels=("span",))
+_host_children: Dict[str, Tuple[object, object]] = {}
+
+
+def account_host_spans(*names: str) -> None:
+    """Create both counters' children for these :class:`host_span`
+    names now, so that a process which never runs one reads 0 (and not
+    an absent family)."""
+    for name in names:
+        _host_pair(name)
+
+
+def _host_pair(name: str) -> Tuple[object, object]:
+    pair = _host_children.get(name)
+    if pair is None:
+        pair = _host_children[name] = (_host_cpu.labels(span=name),
+                                       _host_offcpu.labels(span=name))
+    return pair
+
+
+class host_span(span):
+    """A :class:`span` round host work that never waits for the device.
+    It also reads the thread's CPU clock (``time.thread_time``) at entry
+    and exit: the ring keeps the CPU seconds (``recent_spans`` shows
+    them as attr ``cpu_s``), ``zoo_llm_host_cpu_seconds_total{span}``
+    adds them and ``zoo_llm_host_offcpu_seconds_total{span}`` the rest
+    of the wall time. Two such spans must not nest on one thread, or
+    the sums count the inner one twice."""
+
+    __slots__ = ("_c0",)
+
+    def __enter__(self) -> Optional[str]:
+        sid = span.__enter__(self)
+        self._c0 = _thread_time()
+        return sid
+
+    def __exit__(self, exc_type, exc, tb):
+        cpu = _thread_time() - self._c0
+        t0 = self._t0
+        dur = _perf_counter() - t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _record(self.name, t0, dur, self.attrs, cpu)
+        if self._sink is not None:
+            self._end(dur, exc_type is None)
+        on, off = _host_pair(self.name)
+        on.inc(cpu)
+        off.inc(max(0.0, dur - cpu))
+        return False
+
+
 def active_spans() -> List[dict]:
     """Begin events of every span currently OPEN in this process (any
     thread) — the "where was it" a flight-recorder bundle captures."""
@@ -441,6 +552,54 @@ def emit_event(name: str, trace: Optional[str] = None,
 
 # ------------------------------------------------------------- the ring
 
+def _written_count() -> int:
+    """Spans ever recorded (the last sequence number claimed). count's
+    repr ("count(41)") is the one read of its value that neither
+    advances it nor is deprecated (copy/pickle)."""
+    return int(repr(_written)[6:-1]) - 1
+
+
+def _held(first: int, last: Optional[int] = None,
+          name: Optional[str] = None) -> List[tuple]:
+    """The ring's spans of sequence numbers ``first`` to ``last``
+    (default: the newest), clipped to what it holds, oldest end first.
+    Copies only those slots: the records first, then their attrs, then
+    it reads the count again. A record is kept only where it holds the
+    span its slot should (a slot still being written holds an older
+    one) and where no later span can have claimed that slot meanwhile:
+    each span is whole in the result or absent."""
+    newest = _written_count()
+    last = newest if last is None else min(last, newest)
+    first = max(first, newest - RING_CAPACITY + 1, 1)
+    if first > last:
+        return []
+    want = None
+    if name is not None:
+        want = _name_ids.get(name)
+        if want is None:
+            return []
+    a, b = first & _MASK, (last & _MASK) + 1
+    if a < b:
+        raw = _buf[a * _RECSZ:b * _RECSZ]
+        attrs = _attrs[a:b]
+    else:
+        raw = _buf[a * _RECSZ:] + _buf[:b * _RECSZ]
+        attrs = _attrs[a:] + _attrs[:b]
+    floor = _written_count() - RING_CAPACITY + 1
+    names = _names
+    out = []
+    seq = first
+    for k, (key, t0, dur, tid, cpu) in enumerate(_REC.iter_unpack(raw)):
+        if key >> _NAME_BITS == seq and seq >= floor and (
+                want is None or key & _NAME_MASK == want):
+            at = attrs[k]
+            if cpu == cpu:      # a host_span's CPU seconds
+                at = {**at, "cpu_s": cpu} if at else {"cpu_s": cpu}
+            out.append((names[key & _NAME_MASK], t0, dur, tid, at))
+        seq += 1
+    return out
+
+
 def recent_spans(name: Optional[str] = None, since: Optional[float] = None,
                  until: Optional[float] = None) -> List[tuple]:
     """The ring's spans ``(name, t0, dur_s, thread_id, attrs_or_None)``
@@ -448,9 +607,7 @@ def recent_spans(name: Optional[str] = None, since: Optional[float] = None,
     end first; ``name`` keeps one span name. Check :func:`ring_state`
     before trusting a window: spans older than the ring's reach are
     simply gone."""
-    out = list(_ring)       # one C call: a consistent copy under the GIL
-    if name is not None:
-        out = [r for r in out if r[0] == name]
+    out = _held(1, name=name)
     if since is not None:
         out = [r for r in out if r[1] >= since]
     if until is not None:
@@ -458,20 +615,22 @@ def recent_spans(name: Optional[str] = None, since: Optional[float] = None,
     return out
 
 
+def last_spans(n: int) -> List[tuple]:
+    """The newest ``n`` spans of the ring, as :func:`recent_spans` gives
+    them, copying only their slots."""
+    return _held(_written_count() - n + 1) if n > 0 else []
+
+
 def ring_state() -> Dict[str, object]:
     """``capacity``, ``written`` (spans ever recorded) and ``oldest_t0``
     (start of the oldest span still held; None when empty). The ring
     has dropped spans iff ``written > capacity``; what it dropped ENDED
     before the oldest held span did."""
-    try:
-        oldest = _ring[0][1]
-    except IndexError:
-        oldest = None
-    return {"capacity": RING_CAPACITY,
-            # count's repr ("count(41)") is the one read of its value
-            # that neither advances it nor is deprecated (copy/pickle)
-            "written": int(repr(_written)[6:-1]),
-            "oldest_t0": oldest}
+    written = _written_count()
+    first = max(1, written - RING_CAPACITY + 1)
+    oldest = _held(first, first + 63)
+    return {"capacity": RING_CAPACITY, "written": written,
+            "oldest_t0": oldest[0][1] if oldest else None}
 
 
 _COMPILE_EVENTS = {

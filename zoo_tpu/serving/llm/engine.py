@@ -94,8 +94,10 @@ import numpy as np
 from zoo_tpu.obs.flight import record_event
 from zoo_tpu.obs.metrics import counter, gauge, histogram
 from zoo_tpu.obs.tracing import (
+    account_host_spans,
     emit_event,
     emit_span,
+    host_span,
     span,
     watch_compiles,
 )
@@ -236,6 +238,15 @@ TICK_LEAF_SPANS = (
     "llm.tick.grow_build", "llm.tick.inflight_wait", "llm.tick.dispatch",
     "llm.tick.idle", "llm.tick.reseed",
     "llm.readback.device", "llm.readback.apply",
+)
+
+#: The leaves of host work that by design never wait for the device,
+#: each a ``tracing.host_span``: its CPU seconds and the rest of its wall
+#: time go to ``zoo_llm_host_cpu_seconds_total`` /
+#: ``zoo_llm_host_offcpu_seconds_total{span}``. None nests in another.
+HOST_LEAF_SPANS = (
+    "llm.tick.sweep_admit", "llm.tick.grow_build", "llm.model.h2d",
+    "llm.model.launch", "llm.model.prefill_chunk", "llm.readback.apply",
 )
 
 
@@ -536,6 +547,7 @@ class LLMEngine:
                 "model.decode_step and reads it back through "
                 "model.read_tokens")
         watch_compiles()    # no-op for the jax-free synthetic model
+        account_host_spans(*HOST_LEAF_SPANS)
         self.model = model
         # disaggregated serving (docs/disaggregated_serving.md): the
         # replica's role in a mixed pool. "prefill" parks finished
@@ -1856,12 +1868,13 @@ class LLMEngine:
     def _held(self, leaf: str):
         """``with self._lock`` for the scheduler loop, the wait for the
         lock (``llm.tick.lock_wait``) timed apart from what runs under
-        it (``leaf``): a scheduler stalled behind the readback thread's
-        apply reads differently from one doing its own work."""
+        it (``leaf``, a host span): a scheduler stalled behind the
+        readback thread's apply reads differently from one doing its
+        own work."""
         with span("llm.tick.lock_wait"):
             self._lock.acquire()
         try:
-            with span(leaf):
+            with host_span(leaf):
                 yield
         finally:
             self._lock.release()
@@ -1869,9 +1882,10 @@ class LLMEngine:
     @contextlib.contextmanager
     def _applying(self):
         """``with self._lock`` round the landing of a ready batch, as
-        one ``llm.readback.apply`` span whose ``lock_wait_s`` says how
-        much of it was the wait for the scheduler to let go."""
-        sp = span("llm.readback.apply")
+        one ``llm.readback.apply`` host span whose ``lock_wait_s`` says
+        how much of it was the wait for the scheduler to let go (that
+        wait is off the CPU too)."""
+        sp = host_span("llm.readback.apply")
         with sp:
             t = time.perf_counter()
             with self._lock:

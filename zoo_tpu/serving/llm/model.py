@@ -93,7 +93,7 @@ from zoo_tpu.models.llm.llama import (
 )
 from zoo_tpu.common.knobs import value as knob_value
 from zoo_tpu.obs.metrics import counter
-from zoo_tpu.obs.tracing import span
+from zoo_tpu.obs.tracing import host_span
 from zoo_tpu.ops.attention import dot_product_attention
 from zoo_tpu.util.quantize import absmax_scale, narrow_int8
 
@@ -924,7 +924,7 @@ class PagedDecoderModel:
         n = int(chunk.shape[0])
         if n < 1 or n > C:
             raise ValueError(f"chunk of {n} tokens (chunk size {C})")
-        with span("llm.model.prefill_chunk"):
+        with host_span("llm.model.prefill_chunk"):
             ids = np.zeros((1, C), np.int32)
             ids[0, :n] = chunk
             row = self._row_operands(total_len, block_table_row,
@@ -1006,13 +1006,13 @@ class PagedDecoderModel:
                 prev_batch = self._zero_tokens
             elif isinstance(prev_batch, _TickBatch):
                 prev_batch = prev_batch.tokens
-            with span("llm.model.h2d"):
+            with host_span("llm.model.h2d"):
                 operands = self._hand_off(
                     "decode", self._layouts["decode"],
                     host_tokens=host_tokens, use_host=use_host,
                     block_tables=block_tables, positions=positions,
                     temps=temps, topks=topks, topps=topps, seeds=seeds)
-            with span("llm.model.launch"):
+            with host_span("llm.model.launch"):
                 out, self._cache, *aux = self._decode(
                     self.params, self._cache, prev_batch, operands)
             # the tick's device counts travel with its token batch and

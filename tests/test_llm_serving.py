@@ -630,6 +630,243 @@ def test_prefill_failure_fails_stream_not_scheduler():
         eng.stop()
 
 
+# ---------------------------------- a landing's wake-up, after the dispatch
+
+class _LoggedCond(threading.Condition):
+    """A stream's condition that logs each ``notify_all`` with the
+    tokens and the outcome the woken reader would find."""
+
+    def __init__(self, log, h):
+        super().__init__()
+        self._log, self._h = log, h
+
+    def notify_all(self):
+        self._log.append(("notify", len(self._h.tokens), self._h.outcome))
+        super().notify_all()
+
+
+class _DispatchLogged(_FakeModel):
+    """Logs each ``decode_step`` that returned; the ``fail_at``-th
+    raises instead."""
+
+    def __init__(self, log, fail_at=None, **kw):
+        super().__init__(**kw)
+        self.log, self.fail_at, self.calls = log, fail_at, 0
+
+    def decode_step(self, *args):
+        self.calls += 1
+        if self.calls == self.fail_at:
+            raise RuntimeError("injected dispatch failure")
+        out = super().decode_step(*args)
+        self.log.append(("dispatch",))
+        return out
+
+
+def _watched(eng, log, prompt, n):
+    h = eng.submit(prompt, n)
+    h._cond = _LoggedCond(log, h)
+    return h
+
+
+def _wakes_after_dispatch():
+    from zoo_tpu.obs.metrics import counter
+    return counter("zoo_llm_wakes_after_dispatch_total").value
+
+
+def test_a_landed_tick_wakes_its_stream_after_the_next_dispatch():
+    """A landing appends its tokens at once and wakes nobody; the next
+    pass wakes the stream once its dispatch has returned, and counts
+    the landing. The prompt's first token is woken at once, and the
+    landing that ends a stream wakes it at once, with its last token
+    in the final frame."""
+    log = []
+    eng = LLMEngine(_DispatchLogged(log, num_blocks=32,
+                                    max_blocks_per_seq=8))
+    h = _watched(eng, log, [3, 5], 4)
+    ref = _reference([3, 5], 4)
+    counted = _wakes_after_dispatch()
+    assert tick(eng)
+    assert log == [("notify", 1, None), ("dispatch",)]
+    assert h.tokens == ref[:2]          # landed, nobody woken for it
+    assert tick(eng)
+    assert log[2:] == [("dispatch",), ("notify", 2, None)]
+    assert h.tokens == ref[:3]
+    assert tick(eng)                    # the landing that ends it
+    assert log[4:] == [("dispatch",), ("notify", 3, None),
+                       ("notify", 4, "ok")]
+    assert h.wait_new(3, 0.0) == (ref[3:], True)
+    assert eng.stats()["wakes_after_dispatch"] == 2
+    assert _wakes_after_dispatch() - counted == 2
+    assert not tick(eng)
+    assert log[7:] == []                # an ended stream is not woken again
+    eng.stop()
+
+
+def test_a_pass_that_dispatches_nothing_wakes_before_it_idles():
+    """Two ticks in flight, the first lands, and the next pass finds
+    every token dispatched: it wakes the landing's stream before it
+    parks in ``llm.tick.idle``, and counts nothing."""
+    from zoo_tpu.obs import tracing
+    log = []
+    eng = LLMEngine(_DispatchLogged(log, num_blocks=32,
+                                    max_blocks_per_seq=8))
+    h = _watched(eng, log, [3, 5], 3)
+    assert eng._pass() and eng._pass()  # two ticks in flight
+    first, second = eng._rbq.get_nowait(), eng._rbq.get_nowait()
+    eng._land(first)
+    assert len(h.tokens) == 2 and log[-1] == ("dispatch",)
+    counted = _wakes_after_dispatch()
+    t0 = time.perf_counter()
+    assert not tick(eng)
+    assert log[-1] == ("notify", 2, None)
+    (wake,) = tracing.recent_spans("llm.tick.wake", since=t0)
+    (idle,) = tracing.recent_spans("llm.tick.idle", since=t0)
+    assert wake[4] == {"streams": 1}
+    assert wake[1] + wake[2] <= idle[1]
+    assert _wakes_after_dispatch() == counted
+    assert eng.stats()["wakes_after_dispatch"] == 0
+    eng._land(second)
+    assert h.outcome == "ok" and h.tokens == _reference([3, 5], 3)
+    eng.stop()
+
+
+def test_a_tick_that_lands_during_a_last_chunk_sync_wakes_at_once():
+    """While the scheduler waits for a prompt's last chunk it launches
+    nothing, so a tick that lands then wakes its streams at once (and
+    counts), not after the pass's dispatch."""
+    log = []
+
+    class _Model(_DispatchLogged):
+        land = None
+
+        def prefill_chunk(self, chunk, start, total_len, row,
+                          sampling=None):
+            tok = super().prefill_chunk(chunk, start, total_len, row,
+                                        sampling)
+            land = self.land
+
+            class _Token:
+                def __int__(self):
+                    if land is not None:
+                        land()
+                    return tok
+            return _Token()
+
+    model = _Model(log, num_slots=2, num_blocks=32, max_blocks_per_seq=8,
+                   max_prompt_len=16, prefill_chunk=4)
+    eng = LLMEngine(model)
+    a = _watched(eng, log, [3, 5], 10)
+    assert tick(eng) and tick(eng)
+    assert eng._pass()                  # a's next tick, not landed yet
+    eng._wake_landed(True)
+    model.land = lambda: eng._land(eng._rbq.get_nowait())
+    b = eng.submit([1, 2, 3, 4], 3)     # one chunk, so the pass waits
+    counted = _wakes_after_dispatch()
+    del log[:]
+    assert tick(eng)
+    assert log[:2] == [("notify", 4, None), ("dispatch",)]
+    assert _wakes_after_dispatch() - counted == 1
+    model.land = None
+    for _ in range(20):
+        tick(eng)
+    assert a.tokens == _reference([3, 5], 10)
+    assert b.tokens == _reference([1, 2, 3, 4], 3)
+    eng.stop()
+
+
+@pytest.mark.parametrize("way_out", ["failed_dispatch", "stop"])
+def test_every_way_out_of_a_pass_wakes_what_has_landed(way_out):
+    """A pass whose dispatch fails, and ``stop()``, wake the streams of
+    what has landed before anything else ends them; neither counts."""
+    log = []
+    eng = LLMEngine(_DispatchLogged(
+        log, fail_at=3 if way_out == "failed_dispatch" else None,
+        num_blocks=32, max_blocks_per_seq=8))
+    a = _watched(eng, log, [3, 5], 3)
+    b = eng.submit([7], 10)
+    assert eng._pass() and eng._pass()  # a is all dispatched now
+    first, second = eng._rbq.get_nowait(), eng._rbq.get_nowait()
+    eng._land(first)
+    assert len(a.tokens) == 2 and log[-1] == ("dispatch",)
+    counted = _wakes_after_dispatch()
+    if way_out == "failed_dispatch":
+        assert not tick(eng)            # b's lane alone, and it fails
+        assert b.outcome == "error"
+        assert log[-1] == ("notify", 2, None)
+        eng._land(second)
+        assert a.outcome == "ok" and a.tokens == _reference([3, 5], 3)
+        eng.stop()
+    else:
+        eng.stop()
+        assert log[-2:] == [("notify", 2, None),
+                            ("notify", 2, "cancelled")]
+    assert not eng._unwoken
+    assert _wakes_after_dispatch() == counted
+
+
+def test_running_engine_wakes_every_token_without_a_timeout():
+    """A running engine over a fake device that takes a few ms a tick
+    serves every token of every stream to a reader looping on
+    ``wait_new`` the way the server does, and no wait runs out while a
+    token it was waiting for had already landed: more readers than
+    cores, the interpreter switching threads every 10 us, so a wake-up
+    lost between the readback thread's append and the scheduler's
+    release would show."""
+
+    class _Watched(threading.Condition):
+        def __init__(self, h):
+            super().__init__()
+            self.h, self.stale = h, 0
+
+        def wait(self, timeout=None):
+            n = len(self.h.tokens)
+            woken = super().wait(timeout)
+            if not woken and len(self.h.tokens) > n:
+                self.stale += 1
+            return woken
+
+    eng = LLMEngine(_FakeModel(num_slots=3, num_blocks=64,
+                               max_blocks_per_seq=8, decode_delay=0.003))
+    prompts = [[1 + i % 90, 2 + i % 7] for i in range(
+        (os.cpu_count() or 8) + 4)]
+    handles = [eng.submit(p, 12) for p in prompts]
+    for h in handles:
+        h._cond = _Watched(h)
+    frames = [[] for _ in handles]
+
+    def read(h, out):
+        cursor = 0
+        while True:
+            toks, done = h.wait_new(cursor, 0.25)
+            cursor += len(toks)
+            out.extend(toks)
+            if done:
+                return
+
+    counted = _wakes_after_dispatch()
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    eng.start()
+    readers = [threading.Thread(target=read, args=(h, out), daemon=True)
+               for h, out in zip(handles, frames)]
+    try:
+        for t in readers:
+            t.start()
+        for t in readers:
+            t.join(60)
+            assert not t.is_alive(), "a reader never saw its stream end"
+    finally:
+        eng.stop()
+        sys.setswitchinterval(switch)
+    for p, h, got in zip(prompts, handles, frames):
+        assert h.outcome == "ok" and got == _reference(p, 12)
+    assert [h._cond.stale for h in handles] == [0] * len(handles)
+    st = eng.stats()
+    assert st["decode_steps"] // 2 <= st["wakes_after_dispatch"] \
+        <= st["decode_steps"]
+    assert _wakes_after_dispatch() - counted == st["wakes_after_dispatch"]
+
+
 # ------------------------------------------------------------- sampling
 
 def test_parse_sampling_defaults_env_and_errors(monkeypatch):

@@ -267,8 +267,8 @@ def _drive(overlap, new_tokens=55, tick_s=0.002):
 @pytest.mark.parametrize("overlap", [True], ids=["overlap"])
 def test_scheduler_leaf_spans_are_disjoint_and_cover_the_loop(overlap):
     # 200 ticks of 10 ms: what the loop spends between two leaves (its
-    # histograms, the hand-over to the readback thread: 50-90 us a pass)
-    # is 1% of such a tick and 0.2% of a real one
+    # histograms, the spans' own records: 50-90 us a pass) is 1% of
+    # such a tick and 0.2% of a real one
     _, sched, handles, (t0, t1) = _drive(overlap, new_tokens=101,
                                          tick_s=0.010)
     leaves = sorted((s[1], s[1] + s[2], s[0])
@@ -283,7 +283,7 @@ def test_scheduler_leaf_spans_are_disjoint_and_cover_the_loop(overlap):
     names = {n for _, _, n in leaves}
     want = {"llm.tick.lock_wait", "llm.tick.sweep_admit",
             "llm.tick.prefill", "llm.tick.grow_build",
-            "llm.tick.dispatch", "llm.tick.idle",
+            "llm.tick.dispatch", "llm.tick.wake", "llm.tick.idle",
             "llm.tick.inflight_wait"}
     assert want <= names, want - names
     # one complete span a tick, inside twins of the harness's

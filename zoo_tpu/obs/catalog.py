@@ -113,6 +113,7 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # -- LLM engine ---------------------------------------------------------
     "zoo_llm_tokens_total": ("counter", ("kind",)),
     "zoo_llm_decode_steps_total": ("counter", ()),
+    "zoo_llm_wakes_after_dispatch_total": ("counter", ()),
     "zoo_llm_ttft_seconds": ("histogram", ()),
     "zoo_llm_inter_token_seconds": ("histogram", ()),
     "zoo_llm_stream_ttft_seconds": ("histogram", ("outcome",)),

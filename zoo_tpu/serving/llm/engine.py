@@ -73,9 +73,13 @@ The model behind the engine is any adapter with the
 (``prefill`` / ``decode_step`` / ``read_tokens`` / shape attrs), so
 scheduler tests run against a pure-python fake without importing jax.
 The scheduler thread runs :meth:`LLMEngine._pass` and hands what it
-dispatched to the readback thread's :meth:`LLMEngine._land`; a test
-that wants a deterministic schedule calls the same two on its own
-thread, engine not started (``tests/llm_tick.py``).
+dispatched to the readback thread's :meth:`LLMEngine._land`, which
+appends the tokens to their streams; the scheduler wakes the streams'
+readers once its next dispatch has returned
+(:meth:`LLMEngine._wake_landed`), so the threads they wake run while the
+device computes and not while the scheduler launches. A test that wants
+a deterministic schedule calls the same three on its own thread, engine
+not started (``tests/llm_tick.py``).
 """
 
 from __future__ import annotations
@@ -117,6 +121,12 @@ _tokens = counter(
 _steps = counter(
     "zoo_llm_decode_steps_total",
     "Fixed-shape decode iterations executed")
+_wakes_after_dispatch = counter(
+    "zoo_llm_wakes_after_dispatch_total",
+    "Landed decode/verify batches whose streams were woken once the "
+    "scheduler's next device work was in flight (after the tick's "
+    "dispatch, or before or during a prompt's last-chunk sync); "
+    "batches woken by a pass that dispatched nothing are not counted")
 _ttft = histogram(
     "zoo_llm_ttft_seconds",
     "Time from stream submission to its first generated token")
@@ -236,7 +246,7 @@ _tenant_slots = gauge(
 TICK_LEAF_SPANS = (
     "llm.tick.lock_wait", "llm.tick.sweep_admit", "llm.tick.prefill",
     "llm.tick.grow_build", "llm.tick.inflight_wait", "llm.tick.dispatch",
-    "llm.tick.idle", "llm.tick.reseed",
+    "llm.tick.wake", "llm.tick.idle", "llm.tick.reseed",
     "llm.readback.device", "llm.readback.apply",
 )
 
@@ -396,6 +406,9 @@ class GenHandle:
         return self.outcome is not None
 
     def push(self, tok: int):
+        """Append a token. Nobody is woken: the caller does that with
+        :meth:`wake`, at once for a prompt's first token, after the
+        scheduler's next dispatch for a landed tick's."""
         with self._cond:
             self.tokens.append(int(tok))
             now = time.perf_counter()
@@ -409,6 +422,10 @@ class GenHandle:
                 # here, which is exactly the point)
                 _inter_token.observe(now - self.last_token_at)
             self.last_token_at = now
+
+    def wake(self):
+        """Wake this stream's readers to the tokens pushed so far."""
+        with self._cond:
             self._cond.notify_all()
 
     def finish(self, outcome: str, error: Optional[str] = None):
@@ -659,6 +676,17 @@ class LLMEngine:
         self._prev_batch = None
         self._busy_win: Deque[Tuple[float, float]] = \
             collections.deque(maxlen=64)
+        # the streams each landing gave tokens to, one list a landing,
+        # not yet woken: the readback thread appends, the scheduler
+        # thread wakes them once its next dispatch has returned
+        # (_wake_landed), so the threads they wake run while the device
+        # computes and not in front of the launch. _syncing: the
+        # scheduler waits for a prompt's last chunk and launches nothing
+        # until it has, so a landing then wakes its streams at once
+        self._unwoken: Deque[List[GenHandle]] = collections.deque()
+        self._syncing = False
+        self._wakes_after_dispatch = 0
+        self._wakes_lock = threading.Lock()
         # set (under the lock) when a dispatch or readback failed: the
         # on-device token chain references a failed computation and
         # must be re-seeded from host state before the next dispatch
@@ -691,6 +719,7 @@ class LLMEngine:
         self._wake.set()
         if self._thread is not None:
             self._thread.join(timeout)
+        self._wake_landed(dispatched=False)
         # everything still live is cancelled and its blocks freed — the
         # pool must account to zero on shutdown
         with self._lock:
@@ -1227,6 +1256,7 @@ class LLMEngine:
                    parent=h.parent_span, rid=h.id)
         owed = h.first_token_at is None
         h.push(first)
+        h.wake()
         if owed and h.admitted_at is not None:
             took = h.first_token_at - h.admitted_at
             emit_span("llm.prefill_total", time.time() - took, took,
@@ -1488,9 +1518,17 @@ class LLMEngine:
                     # path. A chunk before it is never waited for (the
                     # device stream orders it before whatever reads its
                     # rows): the device goes on with what is queued
-                    # behind it, this pass's decode tick next
-                    with span("llm.model.prefill_sync"):
-                        tok = int(tok)
+                    # behind it, this pass's decode tick next. What has
+                    # landed is woken first and what lands during the
+                    # wait at once (_land): their threads run while this
+                    # one waits, and the wait holds back no token
+                    self._syncing = True
+                    try:
+                        self._wake_landed(dispatched=True, leaf=False)
+                        with span("llm.model.prefill_sync"):
+                            tok = int(tok)
+                    finally:
+                        self._syncing = False
             except Exception as e:  # noqa: BLE001 — a prefill failure
                 # must end THIS stream loudly, not kill the scheduler
                 # thread with every stream hanging
@@ -1724,8 +1762,10 @@ class LLMEngine:
         """Apply one readback batch to its streams. A lane whose slot
         moved on (finish / expiry / preemption bumped the epoch) is
         discarded — its token is either unwanted or will be re-drawn
-        bit-identically by the resume."""
+        bit-identically by the resume. The streams are woken later
+        (``_unwoken``); a stream that ends here is woken by its finish."""
         eos = getattr(self.model, "eos_id", None)
+        touched = []
         for i, h, epoch in snapshot:
             slot = self._slots[i]
             if slot.handle is not h or slot.epoch != epoch or h.done:
@@ -1740,6 +1780,9 @@ class LLMEngine:
             if h.gen_count >= h.max_new or \
                     (eos is not None and tok == eos):
                 self._finish_slot(slot, "ok")
+            else:
+                touched.append(h)
+        self._unwoken.append(touched)
         self._publish()
 
     # -- speculative decoding ----------------------------------------------
@@ -1827,8 +1870,9 @@ class LLMEngine:
         dead weight the position mask hides until the next pass
         overwrites them (rollback = length reset). A lane whose slot
         moved on (epoch bumped) is discarded, exactly like a decode
-        lane."""
+        lane, and the streams are woken later, as there."""
         eos = getattr(self.model, "eos_id", None)
+        touched = []
         for i, h, epoch, draft in snapshot:
             slot = self._slots[i]
             if slot.handle is not h or slot.epoch != epoch or h.done:
@@ -1858,9 +1902,12 @@ class LLMEngine:
                         (eos is not None and tok == eos):
                     self._finish_slot(slot, "ok")
                     break
+            else:
+                touched.append(h)
         if self._spec_lanes:
             _spec_hit_rate.set(self._spec_drafted_lanes /
                                self._spec_lanes)
+        self._unwoken.append(touched)
         self._publish()
 
     # -- the loop's own spans (docs/observability.md) ----------------------
@@ -1945,8 +1992,11 @@ class LLMEngine:
 
     def _land(self, item):
         """A landing: one dispatched tick's tokens come to the host and
-        reach their streams. The readback thread runs it for every item
-        the scheduler put on ``_rbq``."""
+        are appended to their streams, which the scheduler thread wakes
+        once its next dispatch has returned (``_wake_landed``), or this
+        thread at once while the scheduler waits for a prompt's last
+        chunk. The readback thread runs it for every item the
+        scheduler put on ``_rbq``."""
         kind, batch, snapshot, t_dispatch = item
         try:
             with span("llm.readback.device"):
@@ -1972,6 +2022,10 @@ class LLMEngine:
                 self._apply_spec(snapshot, np.asarray(arr))
             else:
                 self._apply_tokens(snapshot, arr)
+        if self._syncing:
+            # the scheduler waits for a prompt's last chunk: no launch
+            # comes before that, so nothing is gained by holding these
+            self._wake_landed(dispatched=True, leaf=False)
         _tick_seconds.labels(phase="readback").observe(
             time.perf_counter() - t_ready)
         self._decode_steps += 1
@@ -1979,6 +2033,34 @@ class LLMEngine:
         self._tick_flight()
         self._inflight.release()
         self._wake.set()
+
+    def _wake_landed(self, dispatched: bool, leaf: bool = True):
+        """Wake the streams of every landing not yet woken, one
+        ``notify_all`` a stream, outside the engine lock; a
+        ``llm.tick.wake`` leaf (attr ``streams``) unless ``leaf`` is
+        False (around a last chunk's sync: the scheduler inside
+        ``llm.tick.prefill``, or the readback thread). ``dispatched``:
+        device work of this pass is in flight, and the landings count
+        in ``zoo_llm_wakes_after_dispatch_total``."""
+        if not self._unwoken:
+            return
+        sp = span("llm.tick.wake") if leaf else None
+        with sp or contextlib.nullcontext():
+            landed = []
+            try:
+                while True:
+                    landed.append(self._unwoken.popleft())
+            except IndexError:
+                pass
+            streams = dict.fromkeys(h for hs in landed for h in hs)
+            for h in streams:
+                h.wake()
+            if dispatched:
+                with self._wakes_lock:
+                    self._wakes_after_dispatch += len(landed)
+                _wakes_after_dispatch.inc(len(landed))
+            if sp is not None:
+                sp.note(streams=len(streams))
 
     def _readback_loop(self):
         while True:
@@ -1990,17 +2072,19 @@ class LLMEngine:
     def _pass(self):
         """A pass of the scheduler: sweep and admit, feed one budget of
         prompt chunks, grow or preempt, build and dispatch ONE decode
-        (or verify) batch. Returns what the landing needs — ``(kind,
-        batch, snapshot, t_dispatch)``, which the scheduler thread puts
-        on ``_rbq`` — or None when nothing was dispatched (no decodable
-        lane, a failed dispatch, or the engine is stopping).
+        (or verify) batch and hand what its landing needs — ``(kind,
+        batch, snapshot, t_dispatch)`` — to the readback thread on
+        ``_rbq``, inside the ``llm.tick.dispatch`` leaf. Returns False
+        when nothing was dispatched (no decodable lane, a failed
+        dispatch, or the engine is stopping).
 
         The pipeline is double-buffered: tick N+1's continuing lanes
         consume tick N's ON-DEVICE output batch (``_prev_batch``), so
         the steady-state hot path moves slots x 1 ids to the host and
         nothing to the device but block tables and positions, and the
         host schedules tick N+1 while the device executes tick N and
-        the readback thread streams tick N-1's tokens out."""
+        the readback thread lands tick N-1's tokens; their streams are
+        woken when this pass's dispatch has returned."""
         with span("llm.tick.lock_wait"), self._lock:
             broken = self._chain_broken
         if broken:
@@ -2026,7 +2110,7 @@ class LLMEngine:
                 for _ in range(grabbed):
                     self._inflight.release()
             if self._stop.is_set():
-                return None
+                return False
         # bound the pipeline depth: at most 2 ticks in flight.
         # The pass waits for its place BEFORE it feeds a prompt
         # chunk, so that the chunk queues behind one tick and
@@ -2034,7 +2118,7 @@ class LLMEngine:
         with span("llm.tick.inflight_wait"):
             while not self._inflight.acquire(timeout=0.5):
                 if self._stop.is_set():
-                    return None
+                    return False
         t0 = time.perf_counter()
         with self._held("llm.tick.sweep_admit"):
             self._sweep()
@@ -2058,10 +2142,11 @@ class LLMEngine:
             # only KV-gated; submit() sets _wake, so a fresh request
             # still admits at once
             self._prev_batch = None
+            self._wake_landed(dispatched=False)
             with span("llm.tick.idle"):
                 self._wake.wait(0.005)
             self._wake.clear()
-            return None
+            return False
         t_d = time.perf_counter()
         if self._spec:
             # verify batches are host-fed (the accept length
@@ -2081,20 +2166,23 @@ class LLMEngine:
                 with span("llm.tick.dispatch"):
                     batch = self.model.verify_step(
                         tokens, tables, positions, lanes)
+                    self._rbq.put(("spec", batch, snapshot, t_d))
             except Exception as e:  # noqa: BLE001
                 with self._lock:
                     self._fail_lanes([(i, h, ep) for i, h, ep,
                                       _ in snapshot], e)
                 self._inflight.release()
-                return None
+                return False
             self._prev_batch = None
-            return ("spec", batch, snapshot, t_d)
+            return True
         host, use, tables, positions, lanes, snapshot = built
         try:
             with span("llm.tick.dispatch"):
                 self._prev_batch = self.model.decode_step(
                     self._prev_batch, host, use, tables, positions,
                     lanes)
+                self._rbq.put(("decode", self._prev_batch, snapshot,
+                               t_d))
         except Exception as e:  # noqa: BLE001 — consuming a
             # poisoned prev batch / cache raises here; fail the
             # built lanes loudly and re-seed instead of letting
@@ -2102,21 +2190,26 @@ class LLMEngine:
             with self._lock:
                 self._fail_lanes(snapshot, e)
             self._inflight.release()
-            return None
-        return ("decode", self._prev_batch, snapshot, t_d)
+            return False
+        return True
 
     def _loop(self):
-        """The scheduler thread: a pass, and what it dispatched goes to
-        the readback thread for its landing."""
+        """The scheduler thread: a pass (what it dispatched goes to the
+        readback thread for its landing), then the wake-up of what has
+        landed."""
         self._rb_thread = threading.Thread(
             target=self._readback_loop, daemon=True,
             name="zoo-llm-readback")
         self._rb_thread.start()
         try:
             while not self._stop.is_set():
-                item = self._pass()
-                if item is not None:
-                    self._rbq.put(item)
+                dispatched = False
+                try:
+                    dispatched = self._pass()
+                finally:
+                    # every way out of a pass wakes what has landed: a
+                    # landing waits for one pass at most
+                    self._wake_landed(dispatched)
         finally:
             self._prev_batch = None
             self._rbq.put(None)
@@ -2178,6 +2271,9 @@ class LLMEngine:
                "active": sum(1 for s in self._slots if s.handle),
                "waiting": len(self._wait),
                "decode_steps": self._decode_steps,
+               # landings whose streams were woken after a dispatch
+               # (zoo_llm_wakes_after_dispatch_total)
+               "wakes_after_dispatch": self._wakes_after_dispatch,
                "overlap_ratio": self._window_ratio() or 0.0,
                "generated_tokens": self._generated,
                "qos": self.tenancy.enabled}

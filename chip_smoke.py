@@ -853,12 +853,59 @@ def _sparse_state_cases(sz: Sizes, interpret) -> List[KernelCase]:
     ]
 
 
+def _dsa_cases(sz: Sizes, interpret) -> List[KernelCase]:
+    """The token-sparse decode kernel at the widths of
+    ``keye-vl-2.0-30b-a3b-d4`` (32 query heads over 4 K/V heads of 128,
+    a token's K and V one (8, 128) row, pages of 64) and its list of
+    2,048 tokens; every slot another number of live tokens, one none,
+    one a list that ends inside a step. The tokens are scattered over
+    the whole pool, so every copy reads its own row."""
+    import numpy as np
+
+    import jax.numpy as jnp
+
+    from zoo_tpu.ops.pallas import dsa_paged_decode
+    from zoo_tpu.ops.pallas.dsa_decode import (
+        dsa_decode_reference,
+        kv_row_shape,
+    )
+
+    S, H, G, D, bs, nb, L = 16, 32, 4, 128, 64, 96, 2
+    K = 2048 if sz is FULL else 40
+
+    def make(kv):
+        def _make():
+            rs = np.random.RandomState(K)
+            q = rs.randn(S, H, D).astype(np.float32)
+            cache = rs.randn(L, nb, bs, *kv_row_shape(G, D)).astype(
+                np.float32)
+            rows = rs.randint(0, nb * bs, (S, K)).astype(np.int32)
+            n_live = rs.randint(0, K + 1, (S,)).astype(np.int32)
+            n_live[:3] = K, 0, K // 2 + 3
+            if kv == "bf16":
+                cache = jnp.asarray(cache, jnp.bfloat16)
+            return [q, cache, rows, n_live, np.int32(1)]
+        return _make
+
+    def dsa_fn(q, cache, rows, n_live, layer):
+        return dsa_paged_decode(q, cache, rows, n_live, layer=layer,
+                                interpret=interpret)
+
+    def dsa_ref(q, cache, rows, n_live, layer):
+        return dsa_decode_reference(q, cache.astype(jnp.float32), rows,
+                                    n_live, layer=layer)
+
+    return [KernelCase(f"dsa_paged_decode[{kv}]", make(kv), dsa_fn, dsa_ref,
+                       TOL_F32 if kv == "f32" else TOL_BF16)
+            for kv in ("f32", "bf16")]
+
+
 def kernel_cases(sz: Sizes, interpret) -> List[KernelCase]:
     """Every Pallas kernel ``zoo_tpu.ops.pallas`` exports, at the
     smoke's shapes. ``tests/test_tpu_lowering.py`` compiles the same
     list for a v5e without a chip."""
     return (_flash_cases(sz, interpret) + _paged_cases(sz, interpret)
-            + _sparse_state_cases(sz, interpret)
+            + _sparse_state_cases(sz, interpret) + _dsa_cases(sz, interpret)
             + _matmul_cases(sz, interpret) + _conv_cases(sz, interpret)
             + _optim_cases(sz, interpret))
 

@@ -82,7 +82,7 @@ def test_every_exported_kernel_is_covered():
     (the other exports are jnp helpers and dispatch rules)."""
     kernels = {"flash_attention", "paged_flash_decode",
                "paged_flash_prefill", "sparse_paged_decode",
-               "lightning_decode", "quantized_matmul",
+               "dsa_paged_decode", "lightning_decode", "quantized_matmul",
                "fused_quantized_matmul", "conv2d", "conv2d_int8",
                "fused_apply_sgd", "fused_apply_adam", "fused_bottleneck"}
     import zoo_tpu.ops.pallas as zp
@@ -448,6 +448,47 @@ def test_sparse_and_state_step_moves_no_cache(v5e, monkeypatch, which):
     # (at this test's 8 slots of 8 heads the compiler may prefetch the
     # 8 MB state into VMEM, one layout on both sides: no relayout; the
     # cell's 604 MB never fits)
+    assert cache_moves(text, [a.shape for a in cache.values()])[0] == []
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill_chunk"])
+def test_token_selection_step_moves_no_cache(v5e, monkeypatch, which):
+    """The learned indexer's block at the head widths and the cache
+    geometry of ``keye-vl-2.0.doc32k-closed`` (8,704 pages of 64 tokens,
+    a token's K and V of 4 heads of 128 one (8, 128) row, its index key
+    in a row of 128 lanes, 528 table entries, 16 slots; the experts'
+    widths, fewer of them): both leaves are aliased input to output,
+    and no operation copies, relays, slices out or writes back a whole
+    leaf. What this test has caught: with the index keys' 64 values as
+    the leaf's minor dimension the compiler laid the leaf out with its
+    blocks minor and relaid all of it for the table's gather, once a
+    layer and tick."""
+    import zoo_tpu.ops.pallas as zp
+    from zoo_tpu.analysis.hlo import input_output_aliases
+    from zoo_tpu.ops.pallas import dsa_decode, moe_gmm
+    from zoo_tpu.serving.llm.model import narrow_dot_weights
+    from zoo_tpu.serving.llm.model_keye import KeyeConfig, PagedKeyeModel
+
+    for mod in (dsa_decode, moe_gmm):
+        monkeypatch.setattr(mod, "_resolve_interpret", lambda i: False)
+    # the grouped product's kernel, as on the chip
+    monkeypatch.setattr(zp, "on_tpu", lambda: True)
+    cfg = KeyeConfig(vocab=512, hidden=512, n_block=2, n_experts=16)
+    model = PagedKeyeModel(
+        cfg, seed=0, num_slots=16, block_size=64, num_blocks=4,
+        max_blocks_per_seq=528, prefill_buckets=(512,), prefill_chunk=512,
+        kv_dtype="bf16", spec_k=0, decode_impl="flash")
+    one = SingleDeviceSharding(v5e[0])
+    cache = {name: jax.ShapeDtypeStruct((a.shape[0], 8704) + a.shape[2:],
+                                        a.dtype)
+             for name, a in model._cache.items()}
+    params = narrow_dot_weights(model.params, "tpu", model.DOT_LEAVES)
+    text = _step_hlo(model, which, params, cache, one)
+    assert "zoo_moe_gmm" in text
+    if which == "decode":
+        assert "zoo_dsa_decode" in text
+    assert model.donated_cache_leaves() == 2
+    assert len({p for _, p in input_output_aliases(text)}) == 2
     assert cache_moves(text, [a.shape for a in cache.values()])[0] == []
 
 

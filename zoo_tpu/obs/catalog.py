@@ -143,6 +143,9 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "zoo_llm_state_steps_total": ("counter", ()),
     "zoo_llm_state_resets_total": ("counter", ()),
     "zoo_llm_state_bytes": ("gauge", ()),
+    # -- a learned indexer's selection by token (serving/llm/model_keye.py)
+    "zoo_llm_dsa_tokens_scored_total": ("counter", ()),
+    "zoo_llm_dsa_tokens_selected_total": ("counter", ()),
     "zoo_llm_spec_proposed_tokens_total": ("counter", ()),
     "zoo_llm_spec_accepted_tokens_total": ("counter", ()),
     "zoo_llm_spec_accept_len": ("histogram", ()),

@@ -150,19 +150,33 @@ def moe_ffn(params: Dict, x: jnp.ndarray, *, top_k: int = 2,
 
 
 def route_topk(x: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
-               top_k: int, scale: float = 1.0,
-               norm_topk: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sigmoid routing with a selection bias (``noaux_tc``): scores
-    ``s = sigmoid(x . router)`` in float32 at full precision; the
-    ``top_k`` experts are those of largest ``s + bias``; the weights are
-    ``s`` of the chosen (the bias changes the choice, never the
-    weight), renormalised to sum to 1 if ``norm_topk``, times
-    ``scale``. ``x`` (N, H) → ``(idx (N, k) int32, w (N, k) f32)``."""
-    s = jax.nn.sigmoid(jnp.matmul(
+               top_k: int, scale: float = 1.0, norm_topk: bool = True,
+               routing: str = "sigmoid"
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Routing of tokens ``x`` (N, H) to ``top_k`` experts, the scores
+    ``x . router`` in float32 at full precision → ``(idx (N, k) int32,
+    w (N, k) f32)``. ``routing`` (fixed at trace time):
+
+    * ``"sigmoid"``, with a selection bias (``noaux_tc``): ``s =
+      sigmoid(x . router)``; the chosen experts are those of largest
+      ``s + bias``; the weights are ``s`` of the chosen (the bias changes
+      the choice, never the weight);
+    * ``"softmax"``: ``s = softmax(x . router)`` over all experts; the
+      chosen are those of largest ``s`` and ``bias`` is not read.
+
+    The weights are renormalised to sum to 1 if ``norm_topk``, times
+    ``scale``."""
+    logits = jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
-    w = jnp.take_along_axis(s, idx, axis=-1)
+        precision=jax.lax.Precision.HIGHEST)
+    if routing == "softmax":
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    elif routing == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+    else:
+        raise ValueError(f"unknown routing {routing!r} (sigmoid / softmax)")
     if norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), w * scale
@@ -190,12 +204,14 @@ def _grouped_dot(rows, weights, sizes):
 
 def moe_ffn_dropless(params: Dict, x: jnp.ndarray, *, top_k: int,
                      scale: float = 1.0, norm_topk: bool = True,
-                     valid: Optional[jnp.ndarray] = None
+                     valid: Optional[jnp.ndarray] = None,
+                     routing: str = "sigmoid"
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless routed SwiGLU experts plus the shared expert.
 
     ``x``: (N, H) tokens → ``(y (N, H) float32, counts (2,) int32)``.
-    ``params``: ``router`` (H, E) and ``bias`` (E,) in float32,
+    ``params``: ``router`` (H, E) and, for ``routing="sigmoid"``, ``bias``
+    (E,) in float32 (:func:`route_topk`),
     ``w_gate`` / ``w_up`` (E, H, F) and ``w_down`` (E, F, H) in the
     dtype they are held in, and, where the layer has a shared expert,
     ``ws_gate`` / ``ws_up`` (H, Fs) and ``ws_down`` (Fs, H), added once
@@ -212,8 +228,8 @@ def moe_ffn_dropless(params: Dict, x: jnp.ndarray, *, top_k: int,
     N, H = x.shape
     E = params["router"].shape[-1]
     with jax.named_scope("zoo.moe_route"):
-        idx, w = route_topk(x, params["router"], params["bias"], top_k,
-                            scale, norm_topk)
+        idx, w = route_topk(x, params["router"], params.get("bias"),
+                            top_k, scale, norm_topk, routing)
         flat = idx.reshape(N * top_k)
         order = jnp.argsort(flat)            # stable: rows of one expert
         inverse = jnp.argsort(order)         # keep their token order

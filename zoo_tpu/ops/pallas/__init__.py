@@ -65,6 +65,7 @@ from zoo_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
 from zoo_tpu.ops.pallas.paged_decode import paged_flash_decode  # noqa: E402
 from zoo_tpu.ops.pallas.paged_prefill import paged_flash_prefill  # noqa: E402
 from zoo_tpu.ops.pallas.sparse_decode import sparse_paged_decode  # noqa: E402
+from zoo_tpu.ops.pallas.dsa_decode import dsa_paged_decode  # noqa: E402
 from zoo_tpu.ops.pallas.lightning import lightning_decode  # noqa: E402
 from zoo_tpu.ops.pallas.quant import (  # noqa: E402
     quantize_int8, quantized_matmul, quantized_dense,
@@ -78,7 +79,7 @@ from zoo_tpu.ops.pallas.fused_block import fused_bottleneck  # noqa: E402
 
 __all__ = ["flash_attention", "paged_flash_decode",
            "paged_flash_prefill", "sparse_paged_decode",
-           "lightning_decode", "quantize_int8",
+           "dsa_paged_decode", "lightning_decode", "quantize_int8",
            "quantized_matmul", "fused_quantized_matmul",
            "resolve_int8_matmul",
            "quantized_dense", "quantize_conv_weights", "quantized_conv2d",

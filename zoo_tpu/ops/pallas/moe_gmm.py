@@ -106,6 +106,15 @@ def _kernel(starts_ref, ends_ref, gid_ref, tid_ref, n_ref, lhs_ref,
         out_ref[...] = jnp.where(mine, acc_ref[...], kept)
 
 
+def column_step(n: int) -> int:
+    """The widest column step of at most ``TN`` that cuts ``n`` into
+    whole steps of whole 128-lane tiles (``n`` itself under ``TN``):
+    512 for 1,536 and 2,048, 384 for 768."""
+    if n <= TN:
+        return n
+    return next((tn for tn in range(TN, 0, -128) if n % tn == 0), TN)
+
+
 def moe_gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
             *, tiling: Optional[Tuple[int, int, int]] = None,
             interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -120,7 +129,7 @@ def moe_gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
     g, k2, n = rhs.shape
     if k != k2:
         raise ValueError(f"lhs contracts {k}, rhs {k2}")
-    tm, tk, tn = tiling or (TM, min(k, TK_MAX), min(n, TN))
+    tm, tk, tn = tiling or (TM, min(k, TK_MAX), column_step(n))
     if k % tk or n % tn:
         raise ValueError(f"k={k} / n={n} are not whole steps of "
                          f"{tk} / {tn}")

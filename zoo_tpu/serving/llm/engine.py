@@ -615,6 +615,14 @@ class LLMEngine:
                 f"{type(model).__name__} keeps a per-slot recurrent "
                 "state: kv_migrate of a stateful sequence is not built, "
                 f"so the replica role must be mixed (got {role!r})")
+        # what an architecture declares it does not build, and why
+        self._refused = dict(getattr(model, "REFUSED", {}))
+        if self.prefix_cache and "prefix_cache" in self._refused:
+            raise ValueError(f"{type(model).__name__}: "
+                             f"{self._refused['prefix_cache']}")
+        if self.role != "mixed" and "kv_migrate" in self._refused:
+            raise ValueError(f"{type(model).__name__}: "
+                             f"{self._refused['kv_migrate']} (got {role!r})")
         self.max_waiting = max_waiting if max_waiting is not None else \
             env_int("ZOO_LLM_MAX_WAITING", 256)
         # multitenancy (docs/multitenancy.md): the QoS registry every
@@ -776,6 +784,9 @@ class LLMEngine:
                 "kv_migrate of a sequence with a per-slot recurrent "
                 "state is not built: no handoff from, no adoption into "
                 f"{type(self.model).__name__}")
+        if (handoff or adopt is not None) and "kv_migrate" in self._refused:
+            raise ValueError(f"{type(self.model).__name__}: "
+                             f"{self._refused['kv_migrate']}")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -2299,6 +2310,11 @@ class LLMEngine:
             # a live row and the rows computed, over all decode ticks
             out["moe_expert_visits"] = self.model.moe_expert_visits
             out["moe_rows"] = self.model.moe_rows
+        if hasattr(self.model, "dsa_tokens_selected"):
+            # a selection by token: what the decode ticks' indexer scored
+            # and their attention attended, over layers and live slots
+            out["dsa_tokens_scored"] = self.model.dsa_tokens_scored
+            out["dsa_tokens_selected"] = self.model.dsa_tokens_selected
         if self._stateful:
             # a recurrent state beside the paged cache, and (where the
             # model selects pages) what the decode ticks attended of

@@ -94,14 +94,14 @@ def attend_per_head(q, pos, firsts, keys, k_scales, values, *, scale, group,
         l_scr[rows, :] = jnp.broadcast_to(l_new, (group, l_scr.shape[1]))
 
 
-def load_kernel(checkout):
-    """``paged_flash_decode`` of another checkout's file."""
-    path = os.path.join(checkout, "zoo_tpu", "ops", "pallas",
-                        "paged_decode.py")
-    spec = importlib.util.spec_from_file_location("parent_paged_decode", path)
+def load_kernel(checkout, module="paged_decode", fn="paged_flash_decode"):
+    """Function ``fn`` of another checkout's ``ops/pallas/<module>.py``."""
+    path = os.path.join(checkout, "zoo_tpu", "ops", "pallas", module + ".py")
+    spec = importlib.util.spec_from_file_location(
+        module + "_" + checkout.strip("/").replace("/", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.paged_flash_decode
+    return getattr(mod, fn)
 
 
 def regime(table, name, seed):
@@ -143,8 +143,10 @@ def decode_call(kernel, layers, splits):
     return call
 
 
-def measure(call, args, calls):
-    """(kernel device seconds a call, wall seconds a call, result)."""
+def measure(call, args, calls, pattern="zoo_paged_decode"):
+    """(device seconds a call of the operations ``pattern`` names, wall
+    seconds a call, result); both nan off the TPU, the first nan where
+    no operation matched."""
     jax.block_until_ready(call(*args))
     result = np.asarray(call(*args))
     with tempfile.TemporaryDirectory() as d:
@@ -156,9 +158,11 @@ def measure(call, args, calls):
         wall = (time.perf_counter() - t0) / calls
         jax.profiler.stop_trace()
         trace = tr.read_xplane(tr.find_xplane(d))
-    busy, n_ops = tr.matched_time(trace.ops, "zoo_paged_decode")
-    if not n_ops or jax.default_backend() != "tpu":
+    busy, n_ops = tr.matched_time(trace.ops, pattern)
+    if jax.default_backend() != "tpu":
         busy = wall = float("nan")
+    elif not n_ops:
+        busy = float("nan")
     return busy / calls, wall, result
 
 

@@ -204,26 +204,48 @@ def test_every_token_below_the_selection_is_the_scored_selection(
 
 # -------------------------------------------------------------- (2) pieces
 
-@pytest.mark.parametrize("heads,dim,step", [(2, 16, 8), (4, 128, 16),
-                                            (4, 128, 128)])
-def test_dsa_decode_kernel_is_its_twin(heads, dim, step):
+@pytest.mark.parametrize("heads,dim,step,K,live", [
+    pytest.param(2, 16, 8, 40, (40, 17, 0), id="2-16-8"),
+    pytest.param(4, 128, 16, 40, (40, 17, 0), id="4-128-16"),
+    pytest.param(4, 128, 128, 40, (40, 17, 0), id="4-128-128"),
+    # every list ends on a step's last token
+    pytest.param(4, 128, 16, 48, (48, 32, 0), id="on-a-step-boundary"),
+    # two steps of 256, the second padded; a list of exactly one step
+    pytest.param(2, 16, 256, 300, (300, 256, 0), id="T256-K300"),
+    # a step that does not divide the list: 24 of a last step live
+    pytest.param(4, 128, 48, 120, (120, 119, 0), id="T48-K120"),
+])
+def test_dsa_decode_kernel_is_its_twin(heads, dim, step, K, live):
     """``zoo_dsa_decode`` (interpreted) against its plain gather: lists
-    of any length, a slot with none, a list that ends inside a step,
-    rows of bfloat16 (the kernel rounds its probabilities to the cache's
-    dtype before the second product, as the twin does)."""
-    S, H, L, nb, bs, K = 3, 2 * heads, 2, 10, 16, 40
+    of any length, a slot with none, a list that ends inside a step or
+    on its last token, a step of 256 tokens, a list its step does not
+    divide, rows of bfloat16 (the kernel rounds its probabilities to the
+    cache's dtype before the second product, as the twin does). The
+    interpreter completes a copy where it starts, so this holds the
+    issue loop's arithmetic and the slab's rows, not the wait."""
+    S, H, L, nb, bs = 3, 2 * heads, 2, 10, 16
     rs = np.random.RandomState(heads + step)
     q = rs.randn(S, H, dim).astype(np.float32)
     kv = jnp.asarray(rs.randn(L, nb, bs, *kv_row_shape(heads, dim)),
                      jnp.bfloat16)
     rows = rs.randint(0, nb * bs, (S, K)).astype(np.int32)
-    n = np.array([K, 17, 0], np.int32)
+    n = np.array(live, np.int32)
     got = dsa_paged_decode(q, kv, rows, n, layer=1, tokens_per_step=step,
                            interpret=True)
     want = dsa_decode_reference(jnp.asarray(q), kv, jnp.asarray(rows),
                                 jnp.asarray(n), layer=1)
     np.testing.assert_allclose(got, want, atol=1e-2)
     assert np.all(np.asarray(got)[2] == 0.0)
+
+
+def test_dsa_decode_refuses_a_page_of_no_power_of_two():
+    """The issue loop splits a row into page and offset by a shift and a
+    mask: a page of 24 tokens is refused, not misread."""
+    kv = jnp.zeros((1, 4, 24) + kv_row_shape(2, 16), jnp.bfloat16)
+    with pytest.raises(ValueError, match="power of two"):
+        dsa_paged_decode(jnp.zeros((1, 4, 16)), kv,
+                         jnp.zeros((1, 8), jnp.int32),
+                         jnp.ones((1,), jnp.int32), layer=0, interpret=True)
 
 
 def test_kth_largest_is_exact():

@@ -174,6 +174,50 @@ def test_moe_gmm_compiles_at_the_cell_widths(v5e, rows, k, n):
     assert not copies, copies[:2]
 
 
+def test_dsa_decode_retires_a_step_with_one_wait(v5e, monkeypatch):
+    """``zoo_dsa_decode`` at the sizes of ``keye-vl-2.0.doc32k-closed``
+    (16 slots, 32 query heads over 4 K/V heads of 128, 4 layers x 8,704
+    pages of 64, 2,048 rows a slot), at 128 and 256 tokens a step:
+    Mosaic takes it, and its lowered body waits on whole slabs, as
+    often at 256 tokens a step as at 128. A wait a token (a wait in a
+    loop, or unrolled) would wait on one ``(8, 128)`` row, or more
+    often at the longer step."""
+    from jax._src import tpu_custom_call
+
+    from zoo_tpu.ops.pallas.dsa_decode import dsa_paged_decode
+    one = SingleDeviceSharding(v5e[0])
+    bodies = []
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def keep(module, **kw):
+        bodies.append(str(module))
+        return serialize(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", keep)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    waits = {}
+    for T in (128, 256):
+        def call(q, kv, rows, n_live, layer, T=T):
+            return dsa_paged_decode(q, kv, rows, n_live, layer=layer,
+                                    tokens_per_step=T, interpret=False)
+
+        hlo = jax.jit(call).lower(
+            sds((16, 32, 128), jnp.float32),
+            sds((4, 8704, 64, 8, 128), jnp.bfloat16),
+            sds((16, 2048), jnp.int32), sds((16,), jnp.int32),
+            sds((), jnp.int32)).compile().as_text()
+        assert MOSAIC_CALL in hlo and "zoo_dsa_decode" in hlo
+        body = bodies.pop()
+        assert body.count("tpu.enqueue_dma") > 0, "no copy in the body"
+        waits[T] = [ln for ln in body.splitlines() if "tpu.wait_dma" in ln]
+        for ln in waits[T]:
+            assert f": memref<{T}x8x128xbf16" in ln[ln.index("dst("):], ln
+    assert len(waits[128]) == len(waits[256]) >= 1, waits
+
+
 @pytest.mark.parametrize("kv", ["f32", "int8"])
 def test_paged_kernels_compile_under_two_way_shard_map(v5e, kv):
     """The tp=2 serving layout: cache and query heads split over two

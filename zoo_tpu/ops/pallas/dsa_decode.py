@@ -25,10 +25,32 @@ row ``block * block_size + offset``; the grid is the slots; each K/V
 head's group of query heads is one ``(group, D) @ (D, tokens)`` product
 against that head's keys in the slab; the mask is the list's length.
 
+How a step's copies are started and retired. A step is
+``tokens_per_step`` (T) copies of one row each, so the scalar work
+around a copy, not the bytes, sets the kernel's pace. A step's copies
+are started by straight-line code, no loop between them: each reads
+its row from the prefetched table in SMEM and splits it into page and
+offset by a shift and a mask (``block_size`` is a power of two). They
+are started right behind the previous step's wait, in one block with
+that step's products (the last step is peeled off the loop, so no
+condition splits the block), and their scalar work shares bundles with
+the vector work. All T copies of a step signal one semaphore,
+``sem[buf]``, and a DMA semaphore counts the bytes that land, so the
+step is retired by ONE wait whose descriptor names the whole
+``(T, R, C)`` slab of ``buf`` (it is never started; it only says how
+many bytes to wait for). That count is exact: every one of the T
+copies is started every step, a dead entry included (it copies
+whatever row it names and the mask hides it), and each moves one
+``(R, C)`` row of the slab's dtype, T of them the slab's size. The
+other buffer's semaphore carries the next step's copies, so the wait
+never takes bytes that belong to them.
+
 The same warning as there: the interpreter completes a DMA where it is
 started, so a read ahead of its wait passes every CPU test and races on
 the chip; ``chip_smoke.py --legs kernels`` holds it against
-:func:`dsa_decode_reference` on the chip.
+:func:`dsa_decode_reference` on the chip, and
+``tests/test_tpu_lowering.py`` holds the lowered body to waits on
+whole slabs.
 """
 
 from __future__ import annotations
@@ -93,7 +115,7 @@ def _kernel(row_ref, n_ref, lay_ref, q_ref, kv_hbm, o_ref, m_scr, l_scr,
             a_scr, buf_ref, sem, *, tokens_per_step, block_size, groups,
             scale):
     """One slot's program: a loop over its live steps of
-    ``tokens_per_step`` listed tokens."""
+    ``tokens_per_step`` listed tokens, the last one peeled off."""
     s = pl.program_id(0)
     n = n_ref[s]
     lay = lay_ref[0]
@@ -101,44 +123,30 @@ def _kernel(row_ref, n_ref, lay_ref, q_ref, kv_hbm, o_ref, m_scr, l_scr,
     H, D = q_ref.shape[1], q_ref.shape[2]
     Hg = H // groups
     steps = (n + T - 1) // T
-
-    def copy(step, buf, t, routed):
-        # a dead entry names whatever row it holds: copied, then masked
-        r = row_ref[s, step * T + t] if routed else 0
-        return pltpu.make_async_copy(
-            kv_hbm.at[lay, r // block_size, r % block_size],
-            buf_ref.at[buf, t], sem.at[buf])
+    shift = block_size.bit_length() - 1
 
     def start(step, buf):
-        def one(t, c):
-            copy(step, buf, t, True).start()
-            return c
-        jax.lax.fori_loop(0, T, one, 0)
+        # straight-line code: a copy's scalar work is one load from the
+        # table, a shift and a mask; a dead entry names whatever row it
+        # holds: copied, then masked
+        for t in range(T):
+            r = row_ref[s, step * T + t]
+            pltpu.make_async_copy(
+                kv_hbm.at[lay, r >> shift, r & (block_size - 1)],
+                buf_ref.at[buf, t], sem.at[buf]).start()
 
     def wait(buf):
-        def one(t, c):
-            copy(0, buf, t, False).wait()
-            return c
-        jax.lax.fori_loop(0, T, one, 0)
+        # the step's T copies add their bytes to sem[buf]; one wait
+        # sized as the whole slab takes exactly those
+        slab = buf_ref.at[buf]
+        pltpu.make_async_copy(slab, slab, sem.at[buf]).wait()
 
     m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
     l_scr[...] = jnp.zeros_like(l_scr)
     a_scr[...] = jnp.zeros_like(a_scr)
-
-    @pl.when(steps > 0)
-    def _first():
-        start(0, 0)
-
     col = jax.lax.broadcasted_iota(jnp.int32, (Hg, T), 1)
 
-    def step(i, carry):
-        buf = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < steps)
-        def _next():
-            start(i + 1, 1 - buf)
-
-        wait(buf)
+    def attend(i, buf):
         tok = buf_ref[buf]                                 # (T, R, C)
         live = col < n - i * T
         for g in range(groups):
@@ -161,9 +169,27 @@ def _kernel(row_ref, n_ref, lay_ref, q_ref, kv_hbm, o_ref, m_scr, l_scr,
             a_scr[rows, :] = a_scr[rows, :] * corr + jax.lax.dot_general(
                 p.astype(tok.dtype), vals, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+    @pl.when(steps > 0)
+    def _first():
+        start(0, 0)
+
+    def step(i, carry):
+        buf = jax.lax.rem(i, 2)
+        wait(buf)
+        start(i + 1, 1 - buf)
+        attend(i, buf)
         return carry
 
-    jax.lax.fori_loop(0, steps, step, 0)
+    # every step but the last starts its successor's copies
+    jax.lax.fori_loop(0, steps - 1, step, 0)
+
+    @pl.when(steps > 0)
+    def _last():
+        buf = jax.lax.rem(steps - 1, 2)
+        wait(buf)
+        attend(steps - 1, buf)
+
     l_end = l_scr[:, :1]
     o_ref[0] = a_scr[...] / jnp.where(l_end == 0.0, 1.0, l_end)
 
@@ -189,6 +215,9 @@ def dsa_paged_decode(q, kv, rows, n_live, *, layer,
     if 2 * groups * D != R * C or H % groups:
         raise ValueError(f"a cached token of {R} x {C} values does not "
                          f"hold K and V heads of {D} for {H} query heads")
+    if block_size & (block_size - 1):
+        raise ValueError(f"a page of {block_size} tokens: the kernel splits "
+                         f"a row by a shift and a mask, a power of two")
     K = rows.shape[-1]
     if scale is None:
         scale = 1.0 / float(D) ** 0.5
